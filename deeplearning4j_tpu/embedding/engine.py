@@ -177,7 +177,7 @@ class ShardedEmbeddingEngine:
                     + (self._batch_spec,) * n_batch + (P(),))
         out_specs = (self._table_spec,) * n_tables + (P(),)
         fn = shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
         return jax.jit(fn, donate_argnums=tuple(range(n_tables)))
 
     def _build_sgns(self, batch: int, k: int):
@@ -358,7 +358,7 @@ class ShardedEmbeddingEngine:
 
             wrapped = shard_map(
                 body, mesh=self.mesh, in_specs=(self._table_spec, P()),
-                out_specs=P(), check_rep=False)
+                out_specs=P(), check_vma=False)
             fn = jax.jit(wrapped)
             with self._mu:
                 fn = self._lookups.setdefault(n, fn)

@@ -110,10 +110,14 @@ def build_vocab_distributed(client, local_sequences: Iterable[List[str]],
     counts, n_seq = parallel_count(local_sequences, n_workers=n_workers)
     client.set_config(f"{key}/counts/{client.worker_id}",
                       {"counts": dict(counts), "n_sequences": n_seq})
+    # membership is read BEFORE the barrier: a peer that finishes first
+    # deregisters on close, and a slower worker reading it afterwards
+    # would merge a smaller fleet's counts
+    members = sorted(client.workers())
     client.barrier(f"{key}/counted")
     merged: Counter = Counter()
     total_seq = 0
-    for wid in sorted(client.workers()):
+    for wid in members:
         shard = client.get_config(f"{key}/counts/{wid}")
         if shard is None:
             continue  # worker died between counting and the barrier

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import optax
 
 from deeplearning4j_tpu.nn.updater import normalize_gradients
+from deeplearning4j_tpu.ops.partition import kernel_mesh
 
 
 def zero1_opt_shardings(opt_state, mesh, axis: str = "data"):
@@ -135,9 +136,11 @@ def make_train_step(loss_fn, tx, layer_confs_by_name, mesh=None,
             return params, opt_state, new_state, loss, {}
     else:
         def step(params, opt_state, state, rng, batch):
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, state, rng, batch
-            )
+            # GSPMD cannot partition a Pallas kernel: the kernels run
+            # per device over this mesh (ops/partition.py)
+            with kernel_mesh(mesh):
+                (loss, aux), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, state, rng, batch)
             new_state, extras = aux if isinstance(aux, tuple) else (aux, {})
             grads = normalize_gradients(grads, layer_confs_by_name)
             updates, opt_state = tx.update(grads, opt_state, params)
@@ -331,8 +334,11 @@ def fit_steps(net, batch_for_step, total_steps, *, on_step=None):
     `distributed/elastic.py` hangs its checkpoint cadence and the fault
     harness its kill/hang triggers (between one finished collective and
     the next, the same spot a real preemption lands). Emits one
-    telemetry ``step`` event per step (no host sync: the device score is
-    not read here).
+    telemetry ``step`` event per COMPLETED step: dispatch is
+    asynchronous, so the step's params are waited on first (no
+    transfer — the device score is not read here) and a collective
+    that failed on a dead peer raises here, before the step is
+    declared done.
     """
     from deeplearning4j_tpu.telemetry import get_default as _telemetry
 
@@ -348,6 +354,7 @@ def fit_steps(net, batch_for_step, total_steps, *, on_step=None):
                 f"{net.iteration_count - step + 1} optimizer passes — "
                 "fit_steps needs exactly one DataSet per step (check "
                 "`iterations` in the net config)")
+        jax.block_until_ready(net.params)
         rec.step(step)
         if on_step is not None:
             on_step(step)

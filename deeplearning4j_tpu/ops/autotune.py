@@ -235,6 +235,7 @@ def validate_table(table) -> list[str]:
 _lock = threading.Lock()
 _cache: dict = {"path": None, "table": None}
 _overrides: list[dict] = []  # innermost last; each {key -> params}
+_resolved: dict[str, str] = {}  # key -> source of the last lookup
 
 
 def load_table(path: str | None = None) -> dict:
@@ -313,13 +314,22 @@ def lookup(kernel: str, T: int, D: int, *, causal: bool = False,
     key = config_key(kernel, T, D, causal=causal, dropout=dropout,
                      masked=masked)
     for mapping in reversed(_overrides):
-        if key in mapping:
-            return mapping[key]
-        if kernel in mapping:
-            return mapping[kernel]
-    if not table_active():
-        return None
-    return load_table()["entries"].get(key)
+        forced = mapping.get(key, mapping.get(kernel))
+        if forced is not None:
+            _resolved[key] = "override"
+            return forced
+    entry = (load_table()["entries"].get(key) if table_active()
+             else None)
+    _resolved[key] = "table" if entry else "default"
+    return entry
+
+
+def resolved_keys() -> dict:
+    """Every config key a kernel has looked up in this process -> where
+    its params came from ("override", "table", or "default" = the swept
+    heuristics). What a run on the chip prints to say which tuning-table
+    entries it really used."""
+    return dict(_resolved)
 
 
 # ------------------------------------------------------------- resolution

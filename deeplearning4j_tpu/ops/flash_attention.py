@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from deeplearning4j_tpu.ops import autotune
+from deeplearning4j_tpu.ops.partition import rows_per_device
 from deeplearning4j_tpu.util.compat import tpu_compiler_params
 
 BLOCK = autotune.BLOCK
@@ -406,6 +407,7 @@ def _flash_fwd(q, k, v, kmask, sm_scale, causal, dropout=0.0, seed=None,
             jax.ShapeDtypeStruct((BH, 1, T), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        name="flash_fwd",
         interpret=_use_interpret(),
     )(*args)
     return o, lse[:, 0, :]
@@ -656,6 +658,7 @@ def _flash_bwd_fused(q, k, v, do, o, lse, kmask, sm_scale, causal,
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
         compiler_params=tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        name="flash_bwd_fused",
         interpret=_use_interpret(),
     )(*args)
 
@@ -711,6 +714,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, kmask, sm_scale, causal,
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+        name="flash_bwd_dq",
         interpret=_use_interpret(),
     )(*dq_args)
 
@@ -744,6 +748,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, kmask, sm_scale, causal,
             jax.ShapeDtypeStruct((BH, T, D), k.dtype),
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
+        name="flash_bwd_dkv",
         interpret=_use_interpret(),
     )(*dkv_args)
     return dq, dk, dv
@@ -1029,6 +1034,7 @@ def _flash_fwd_qkv_pair(qkv, H, kmask, sm_scale, causal, dropout=0.0,
             jax.ShapeDtypeStruct((B, H, 1, T), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        name="flash_fwd_qkv_pair",
         interpret=_use_interpret(),
     )(*args)
     return o, lse
@@ -1069,6 +1075,7 @@ def _flash_bwd_qkv_pair(qkv, o, lse, do, H, kmask, sm_scale, causal,
         out_specs=[col, col, col],
         out_shape=[jax.ShapeDtypeStruct((B, T, n), qkv.dtype)] * 3,
         compiler_params=tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        name="flash_bwd_qkv_pair",
         interpret=_use_interpret(),
     )(*args)
     return jnp.concatenate([dq, dk, dv], axis=-1)
@@ -1115,6 +1122,7 @@ def _flash_fwd_qkv(qkv, H, kmask, sm_scale, causal, dropout=0.0, seed=None):
             jax.ShapeDtypeStruct((B, H, 1, T), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        name="flash_fwd_qkv",
         interpret=_use_interpret(),
     )(*args)
     return o, lse
@@ -1162,6 +1170,7 @@ def _flash_bwd_qkv(qkv, o, lse, do, H, kmask, sm_scale, causal,
         out_specs=[col, col, col],
         out_shape=[jax.ShapeDtypeStruct((B, T, n), qkv.dtype)] * 3,
         compiler_params=tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT),
+        name="flash_bwd_qkv",
         interpret=_use_interpret(),
     )(*args)
     return jnp.concatenate([dq, dk, dv], axis=-1)
@@ -1271,10 +1280,14 @@ def flash_attention_qkv(qkv, n_heads, *, causal=True, sm_scale=None,
         return _flash_qkv_core_drop(qkv, kmask, ctx, n_heads, sm_scale,
                                     bool(causal), float(dropout))
     if mask is None:
-        return _flash_qkv_core(qkv, n_heads, sm_scale, bool(causal))
+        return rows_per_device(
+            lambda x: _flash_qkv_core(x, n_heads, sm_scale, bool(causal)),
+            (qkv,))
     kmask = jnp.asarray(mask, jnp.float32)[:, None, :]      # [B, 1, T]
-    return _flash_qkv_core_masked(qkv, kmask, n_heads, sm_scale,
-                                  bool(causal))
+    return rows_per_device(
+        lambda x, km: _flash_qkv_core_masked(x, km, n_heads, sm_scale,
+                                             bool(causal)),
+        (qkv, kmask))
 
 
 # Below this sequence length XLA's fused dense attention wins on TPU (the
@@ -1686,8 +1699,14 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None, mask=None,
         o = _flash_core_drop(qf, kf, vf, kmask, ctx, sm_scale,
                              bool(causal), float(dropout))
     elif mask is None:
-        o = _flash_core(qf, kf, vf, sm_scale, bool(causal))
+        o = rows_per_device(
+            lambda q_, k_, v_: _flash_core(q_, k_, v_, sm_scale,
+                                           bool(causal)),
+            (qf, kf, vf))
     else:
         kmask = _broadcast_kmask(mask, B, H, T)
-        o = _flash_core_masked(qf, kf, vf, kmask, sm_scale, bool(causal))
+        o = rows_per_device(
+            lambda q_, k_, v_, km: _flash_core_masked(
+                q_, k_, v_, km, sm_scale, bool(causal)),
+            (qf, kf, vf, kmask))
     return o.reshape(B, H, T, D)

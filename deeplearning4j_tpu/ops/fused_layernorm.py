@@ -113,6 +113,7 @@ def _ln_fwd(x2d, gamma, beta, eps):
             jax.ShapeDtypeStruct((1, N), jnp.float32),
             jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
+        name="fused_layer_norm_fwd",
         interpret=_use_interpret(),
     )(x2d, gamma, beta)
     return y, mu, rstd
@@ -146,6 +147,7 @@ def _ln_bwd(x2d, gamma, mu, rstd, dy):
             jax.ShapeDtypeStruct((N // bn, 1, C), jnp.float32),
             jax.ShapeDtypeStruct((N // bn, 1, C), jnp.float32),
         ],
+        name="fused_layer_norm_bwd",
         interpret=_use_interpret(),
     )(x2d, gamma, mu, rstd, dy)
     return dx, dgp[:, 0].sum(0), dbp[:, 0].sum(0)

@@ -11,13 +11,14 @@ every-kernel-benchmarked discipline (Dragon-Alpha, arXiv:2305.08819):
 registered in the ``neg_softmax`` autotune family, swept by
 tools/kerneltune.py, resolved through the tuning table.
 
-Dispatch follows the fused_sampling idiom: a shared math body
-(`_score_body`) runs EXACTLY in both the kernel and the pure-jnp
-reference, so off-TPU (interpret mode) and outside the `supports()`
-envelope the results are bit-identical by construction. The reference
-expressions are verbatim the legacy dense path's (nlp/lookup.sgns_step),
-which is what makes the engine's ep=1 bit-parity contract hold on the
-tiny-vocab shapes the envelope excludes.
+Dispatch follows the fused_sampling idiom: outside the `supports()`
+envelope the pure-jnp reference (`_score_body`) runs. Its expressions
+are verbatim the legacy dense path's (nlp/lookup.sgns_step), which is
+what makes the engine's ep=1 bit-parity contract hold on the tiny-vocab
+shapes the envelope excludes. The kernel computes the same dot products
+as multiply + lane reduction in f32 (the chip's compiler refuses the
+reference's free-dimension-less batched dots), equal to the reference
+within float rounding (tests/test_embedding.py, atol 1e-6).
 
 The [B, K] negative-score output is padded to a [B, LANES] lane tile in
 kernel (K is a handful; the last dimension must tile) and sliced back by
@@ -65,8 +66,14 @@ def _score_body(c, pos, neg):
 
 
 def _neg_softmax_kernel(c_ref, pos_ref, neg_ref, pos_out_ref, neg_out_ref):
-    pos_score, neg_score = _score_body(c_ref[...], pos_ref[...],
-                                       neg_ref[...])
+    # the same dot products as _score_body, spelled as multiply + lane
+    # reduction: Mosaic refuses a batched dot with no free dimension
+    # ("bd,bd->b" / "bd,bkd->bk" have none on the lhs)
+    c = c_ref[...].astype(jnp.float32)
+    pos_score = jax.nn.sigmoid(
+        jnp.sum(c * pos_ref[...].astype(jnp.float32), axis=-1))
+    neg_score = jax.nn.sigmoid(
+        jnp.sum(c[:, None, :] * neg_ref[...].astype(jnp.float32), axis=-1))
     pos_out_ref[...] = pos_score.reshape(pos_out_ref.shape)
     bn, k = neg_score.shape
     neg_out_ref[...] = jnp.pad(neg_score,
@@ -94,6 +101,7 @@ def _neg_softmax_pallas(c, pos, neg):
             jax.ShapeDtypeStruct((1, B), c.dtype),
             jax.ShapeDtypeStruct((B, autotune.LANES), c.dtype),
         ],
+        name="neg_softmax",
         interpret=_use_interpret(),
     )(c, pos, neg)
     return pos_score[0], neg_pad[:, :K]

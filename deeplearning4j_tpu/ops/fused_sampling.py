@@ -132,6 +132,7 @@ def _sample_pallas(logits, noise, temperature, top_k, top_p):
         ],
         out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
+        name="fused_sample",
         interpret=_use_interpret(),
     )(logits, noise)
     return out[0]

@@ -35,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops import autotune
+from deeplearning4j_tpu.ops.partition import rows_per_device
 from deeplearning4j_tpu.util.compat import tpu_compiler_params
 
 LANES = autotune.LANES
@@ -144,6 +145,7 @@ def _fused_fwd(x, w, b, labels, bn, bv):
         ],
         compiler_params=tpu_compiler_params(
             vmem_limit_bytes=32 * 1024 * 1024),
+        name="softmax_xent_fwd",
         interpret=_use_interpret(),
     )(x, w, b2, lab2)
     return loss[:, 0], lse[:, 0]
@@ -237,6 +239,7 @@ def _fused_bwd(bn, bv, res, dloss):
         scratch_shapes=[pltpu.VMEM((bn, d), jnp.float32)],
         compiler_params=tpu_compiler_params(
             vmem_limit_bytes=32 * 1024 * 1024),
+        name="softmax_xent_dx",
         interpret=_use_interpret(),
     )(x, w, b2, lab2, lse2, g2)
 
@@ -271,6 +274,7 @@ def _fused_bwd(bn, bv, res, dloss):
         # swept (faster) block sizes
         compiler_params=tpu_compiler_params(
             vmem_limit_bytes=32 * 1024 * 1024),
+        name="softmax_xent_dwdb",
         interpret=_use_interpret(),
     )(x, w, b2, lab2, lse2, g2)
 
@@ -306,11 +310,18 @@ def softmax_xent_head(x, w, b, labels):
     out-of-range gathers; here they would silently hit column V-1).
     """
     lead = x.shape[:-1]
-    d = x.shape[-1]
-    V = w.shape[-1]
     n = int(np.prod(lead)) if lead else 1
-    xf = x.reshape(n, d)
-    lf = labels.reshape(n)
+    loss = rows_per_device(
+        _head_rows, (x.reshape(n, x.shape[-1]), labels.reshape(n)), (w, b))
+    return loss.reshape(lead)
+
+
+def _head_rows(xf, lf, w, b):
+    """softmax_xent_head on flat rows: xf [n, d], lf [n] -> loss [n].
+    Under a device-spanning jit this is the per-device body (n = this
+    device's tokens; blocks resolve against the local row count)."""
+    n, d = xf.shape
+    V = w.shape[-1]
     n_pad = (n + 127) // 128 * 128
     if n_pad != n:
         # ragged row counts (e.g. a final partial batch): pad tokens to the
@@ -330,5 +341,4 @@ def softmax_xent_head(x, w, b, labels):
         vp = (V + bv - 1) // bv * bv
         w = jnp.pad(w, ((0, 0), (0, vp - V)))
         b = jnp.pad(b, (0, vp - V), constant_values=NEG_INF)
-    loss = _fused_head(xf, w, b, lf, bn, bv)[:n]
-    return loss.reshape(lead)
+    return _fused_head(xf, w, b, lf, bn, bv)[:n]

@@ -317,16 +317,14 @@ def sparse_bucket_reduce(indices, values, axis_name: str, *,
 def reduce_gradients(grads, axis_names, *, mean: bool = True):
     """Unbucketed cross-replica gradient mean over one or more bound
     axes — the blessed routing for manual-collective train steps that do
-    not bucket (sequence parallelism). Per-axis tree-level pmean, same
-    primitive sequence the SP step always issued (frozen stage-3
-    signature unchanged)."""
+    not bucket (sequence parallelism). Per-axis tree-level pmean: one
+    psum eqn per leaf in tree order (the frozen stage-3 signature),
+    merged by XLA's all-reduce combiner."""
     from jax import lax
 
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
     for ax in axis_names:
-        # whole-tree pmean: ONE multi-operand psum eqn per axis — the
-        # exact eqn sequence the callers always issued
         grads = lax.pmean(grads, ax) if mean else lax.psum(grads, ax)
     return grads
 

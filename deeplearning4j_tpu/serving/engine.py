@@ -63,6 +63,12 @@ from deeplearning4j_tpu.telemetry.memstat import (MemoryLedger,
                                                   MemorySampler)
 
 
+def _peak_fields(peak) -> dict:
+    """The device's published peak as an event/stats field — absent
+    off-TPU (costbook.peak_flops gives None there), never invented."""
+    return {} if peak is None else {"peak_flops": peak}
+
+
 class QueueFullError(RuntimeError):
     """Generation admission refused: the page pool and the pending queue
     are both full — the front door's graceful 503, never a crash."""
@@ -321,7 +327,7 @@ class InferenceEngine:
         ledger.register("params", lambda: self.weights.current.params)
         self.memsampler = MemorySampler(recorder, ledger)
         self.costbook = CostBook(recorder)
-        self.peak_flops = 0.0  # set at warmup from the device kind
+        self.peak_flops = None  # set at warmup; stays None off-TPU
         self.lattice = lattice or BucketLattice()
         self.batcher = Batcher(self.lattice, max_wait_ms,
                                sequence=sequence, recorder=recorder)
@@ -367,9 +373,9 @@ class InferenceEngine:
             # gets its device-peak denominator
             import jax
 
-            self.peak_flops = peak_flops(
-                getattr(jax.devices()[0], "device_kind", ""))
-            self.memsampler.sample("warmup", peak_flops=self.peak_flops)
+            self.peak_flops = peak_flops(jax.devices()[0])
+            self.memsampler.sample("warmup",
+                                   **_peak_fields(self.peak_flops))
         return compiles
 
     def _warm_replica(self, replica: _Replica) -> int:
@@ -635,7 +641,7 @@ class InferenceEngine:
             "fleet": fleet,
             "weights": self.weights.describe(),
             "memory": self.memsampler.last,
-            "peak_flops": self.peak_flops,
+            **_peak_fields(self.peak_flops),
         }
 
 
@@ -1321,7 +1327,7 @@ class GenerationEngine:
         ledger.register("kv_pages",
                         lambda: [w.cache for w in self._workers])
         self.memsampler = MemorySampler(recorder, ledger)
-        self.peak_flops = 0.0  # set at warmup from the device kind
+        self.peak_flops = None  # set at warmup; stays None off-TPU
         self._rr = 0
         self._started = False
         recorder.meta(role="generation-engine",
@@ -1341,9 +1347,9 @@ class GenerationEngine:
         if compiles:
             import jax
 
-            self.peak_flops = peak_flops(
-                getattr(jax.devices()[0], "device_kind", ""))
-            self.memsampler.sample("warmup", peak_flops=self.peak_flops)
+            self.peak_flops = peak_flops(jax.devices()[0])
+            self.memsampler.sample("warmup",
+                                   **_peak_fields(self.peak_flops))
         return compiles
 
     # ------------------------------------------------------------ serving
@@ -1458,7 +1464,7 @@ class GenerationEngine:
             "generate": True,
             "speculative": self._speculative_stats(),
             "memory": self.memsampler.last,
-            "peak_flops": self.peak_flops,
+            **_peak_fields(self.peak_flops),
         }
 
     def _speculative_stats(self) -> dict:

@@ -242,7 +242,9 @@ def validate_checkpoint_shapes(current_params, checkpoint_dir: str,
         raise WeightSwapError(
             f"checkpoint step {step} is unreadable (truncated or "
             f"corrupt): {exc}") from exc
-    recorded = meta.get("params") if isinstance(meta, dict) else None
+    # orbax 0.11: StepMetadata, the recorded tree under item_metadata
+    tree = getattr(getattr(meta, "item_metadata", None), "tree", None)
+    recorded = tree.get("params") if isinstance(tree, dict) else None
     if recorded is None:
         raise WeightSwapError(
             f"checkpoint step {step} records no params tree")

@@ -43,27 +43,35 @@ from deeplearning4j_tpu.telemetry.recorder import NullRecorder, Recorder
 
 DEFAULT_DRIFT_FACTOR = 8.0
 
-# Peak dense bf16 FLOP/s per device kind — the MFU denominator. The
-# fallback (1e12) keeps off-TPU MFU informational (a tiny number),
-# never a crash.
-PEAK_BF16_FLOPS = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
+# Published peaks per device kind, as `jax.devices()[0].device_kind`
+# names it — the ONE table (bench.py and serving/engine.py read it).
+# Only the chip this installation runs on is listed; a TPU whose kind is
+# not here is an error, never a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes": 16e9,
+        "hbm_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip",
+    },
 }
-DEFAULT_PEAK_FLOPS = 1e12
 
 
-def peak_flops(device_kind: str | None) -> float:
-    """Peak FLOP/s for a device kind string (substring match so
-    platform-version suffixes don't miss)."""
-    kind = device_kind or ""
-    for name, peak in PEAK_BF16_FLOPS.items():
-        if name in kind:
-            return peak
-    return DEFAULT_PEAK_FLOPS
+def peak_flops(device) -> float | None:
+    """Peak dense bf16 FLOP/s of a jax device — the MFU denominator.
+    Off-TPU there is no peak to divide by: None, and callers leave the
+    field out. A TPU kind missing from DEVICE_PEAKS raises."""
+    if getattr(device, "platform", None) != "tpu":
+        return None
+    kind = getattr(device, "device_kind", "")
+    if kind not in DEVICE_PEAKS:
+        raise KeyError(
+            f"no published peak for TPU device kind {kind!r}; add it to "
+            f"telemetry/costbook.DEVICE_PEAKS with its source "
+            f"(known: {sorted(DEVICE_PEAKS)})")
+    return DEVICE_PEAKS[kind]["bf16_flops"]
 
 
 def _first(analysis):

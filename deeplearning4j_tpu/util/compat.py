@@ -1,61 +1,27 @@
-"""jax version compatibility shims.
-
-The repo runs on two jax generations: the TPU driver container (jax >=
-0.5, where `jax.shard_map` and `pltpu.CompilerParams` are public) and the
-CPU test container (jax 0.4.x, where they live at
-`jax.experimental.shard_map.shard_map` / `pltpu.TPUCompilerParams`). The
-r5 `transformer_large` bench crash was this exact failure class — a
-binary that ran in the author's session died under driver capture with an
-AttributeError before emitting its metric — so every version-moved symbol
-is resolved HERE, once, instead of at each call site.
+"""Single import point for the jax symbols the repo's sharding and Pallas
+code depends on (graftlint G007 routes callers here), spelled for the one
+installed jax (0.9.0): `jax.shard_map` with `check_vma` / `axis_names`,
+`pltpu.CompilerParams`, `lax.pcast`.
 """
 
 from __future__ import annotations
 
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map
-
-    _SHARD_MAP_VMA_KW = True
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_VMA_KW = False
+import jax as _jax
+from jax import shard_map as _shard_map
+from jax.experimental.pallas import tpu as _pltpu
 
 
 def shard_map(f, **kwargs):
-    """jax.shard_map across versions: 0.4.x spells the replication-check
-    opt-out `check_rep` (>= 0.5: `check_vma`) and the partial-manual
-    selector `auto` = non-manual axes (>= 0.5: `axis_names` = manual
-    axes). Callers use the new spellings; this translates down."""
-    if not _SHARD_MAP_VMA_KW:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            manual = set(kwargs.pop("axis_names"))
-            mesh_axes = set(kwargs["mesh"].axis_names)
-            if manual != mesh_axes:
-                kwargs["auto"] = frozenset(mesh_axes - manual)
+    """jax.shard_map (`check_vma` opts out of the varying-axis check,
+    `axis_names` selects the manual axes)."""
     return _shard_map(f, **kwargs)
-
-import jax as _jax
-from jax.experimental.pallas import tpu as _pltpu
-
-# renamed TPUCompilerParams -> CompilerParams in jax 0.5
-_COMPILER_PARAMS_CLS = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams")
 
 
 def tpu_compiler_params(**kwargs):
-    """pltpu CompilerParams across the rename (vmem_limit_bytes etc.)."""
-    return _COMPILER_PARAMS_CLS(**kwargs)
+    """pltpu.CompilerParams (vmem_limit_bytes etc.)."""
+    return _pltpu.CompilerParams(**kwargs)
 
 
 def pcast_varying(x, axis_names):
-    """lax.pcast(x, axis_names, to="varying") where it exists (the vma
-    varying-axis type system of newer jax); identity on 0.4.x, whose
-    shard_map (check_rep=False) has no varying-axis types to cast
-    between — the cast is purely a type-system annotation there."""
-    pcast = getattr(_jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axis_names, to="varying")
+    """lax.pcast(x, axis_names, to="varying") — the vma type-system cast."""
+    return _jax.lax.pcast(x, axis_names, to="varying")

@@ -445,3 +445,32 @@ class TestBenchdiffTables:
             [sys.executable, BENCHDIFF, op, str(bench_art)], cwd=ROOT,
             capture_output=True, text=True, timeout=120)
         assert proc.returncode == 2
+
+
+# ------------------------------------------------ what a run resolved
+
+def test_resolved_keys_name_where_each_lookup_came_from(monkeypatch):
+    """chip_smoke.py prints autotune.resolved_keys(): every key a kernel
+    looked up and whether its params came from the table, an override,
+    or the default heuristics."""
+    monkeypatch.setattr(autotune, "_resolved", {})
+    key_hit = "flash_fwd|T512|D128|c1|d0|m0"      # in the checked-in table
+    key_miss = "flash_fwd_qkv|T512|D128|c1|d0|m0"  # not in it
+
+    monkeypatch.setenv(autotune.ENV_TUNING, "off")
+    autotune.flash_blocks(512, 128, causal=True, dropout=False,
+                          masked=False)
+    assert autotune.resolved_keys() == {key_hit: "default"}
+
+    monkeypatch.setenv(autotune.ENV_TUNING, "force")
+    autotune.flash_blocks(512, 128, causal=True, dropout=False,
+                          masked=False)
+    autotune.flash_g("flash_fwd_qkv", 32, 512, 128, causal=True,
+                     dropout=False, masked=False)
+    assert autotune.resolved_keys() == {key_hit: "table",
+                                        key_miss: "default"}
+
+    with autotune.override({"flash_fwd_qkv": {"g": 2}}):
+        autotune.flash_g("flash_fwd_qkv", 32, 512, 128, causal=True,
+                         dropout=False, masked=False)
+    assert autotune.resolved_keys()[key_miss] == "override"

@@ -12,6 +12,8 @@ must trace through the chunked flash dispatch — in r5 that config raised
 chunked_unsupported_reason.
 """
 
+import os
+
 import numpy as np
 
 import jax
@@ -291,3 +293,43 @@ def test_embed_mode_registered_and_smoke_runs():
                    "embed_brute_force_queries_per_sec",
                    "embed_ann_speedup_vs_brute"):
         assert family in names, family
+
+
+def test_failed_backend_probe_is_an_error_not_a_skippable_backend(
+        monkeypatch):
+    """`_run_all` turns crashed modes into rc-0 'skipped-env' lines when
+    the probed backend is not tpu; a probe that FAILS must therefore
+    raise, never read as some other backend."""
+    import subprocess
+
+    class _Out:
+        returncode, stdout, stderr = 1, "", "RuntimeError: no backend"
+
+    monkeypatch.setattr(subprocess, "run", lambda *a, **k: _Out())
+    with pytest.raises(RuntimeError, match="backend probe failed"):
+        bench._probe_backend()
+
+    class _Ok:
+        returncode, stdout, stderr = 0, "cpu\n", ""
+
+    monkeypatch.setattr(subprocess, "run", lambda *a, **k: _Ok())
+    assert bench._probe_backend() == "cpu"
+
+
+def test_bench_parent_imports_do_not_initialize_a_backend():
+    """A chip belongs to one process: the sweep parent imports bench and
+    the telemetry package, and must leave the backend to its children."""
+    import subprocess
+    import sys
+
+    code = ("import bench\n"
+            "from deeplearning4j_tpu.telemetry import Recorder, set_default\n"
+            "from deeplearning4j_tpu.telemetry.artifact import "
+            "build_summary\n"
+            "from jax._src import xla_bridge as xb\n"
+            "print(xb.backends_are_initialized())\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120,
+                         cwd=os.path.dirname(os.path.abspath(bench.__file__)))
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "False"

@@ -1,0 +1,331 @@
+"""Compile the main path's kernels for the real chip, without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is
+described, not attached (`topologies.get_topology_desc`). Interpret mode
+cannot show what these compiles show: a block not aligned to the tiling,
+a kernel over its VMEM limit, a program that cannot be partitioned. The
+shapes are the ones `chip_smoke.py` runs: the d_model-1024 Transformer
+LM (8 heads of 128, vocab 10000) at batch 32 x seq 512, and its
+/generate serving shapes (4 slots, page size 16).
+
+The code under test asks `jax.default_backend()` (here: cpu) to choose
+interpret mode and to ignore the tuning table; each test steers both the
+way the chip would (compiled kernels, table active) through the
+`as_on_chip` fixture — in the test, not through an option of the program.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, and under xdist every
+worker imports this file. Nothing runs; a compile that passes is not a
+chip run.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+B, T, D_MODEL, HEADS, VOCAB = 32, 512, 1024, 8, 10000
+N_TOK = B * T
+SLOTS, PAGE = 4, 16
+
+OPS_WITH_INTERPRET = ("flash_attention", "fused_layernorm",
+                      "fused_softmax_xent", "fused_sampling",
+                      "fused_neg_softmax")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip; keep it off around these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch, no_persistent_cache):
+    """Compiled kernels and an active tuning table, as on the chip."""
+    import importlib
+
+    for name in OPS_WITH_INTERPRET:
+        mod = importlib.import_module(f"deeplearning4j_tpu.ops.{name}")
+        monkeypatch.setattr(mod, "_use_interpret", lambda: False)
+    monkeypatch.setenv("DL4J_TPU_TUNING", "force")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+def _kernels(text):
+    """{kernel name: count}, read the way chip_smoke.py reads the
+    compiled train step on the chip."""
+    from chip_smoke import kernel_counts
+
+    return kernel_counts(text)
+
+
+def test_flash_packed_qkv_forward(as_on_chip, one_chip):
+    """The attention layer's path at head_dim 128: packed [B, T, 3n]."""
+    from deeplearning4j_tpu.ops.flash_attention import (
+        flash_attention_qkv, supports_qkv)
+
+    assert supports_qkv(B, T, D_MODEL, HEADS, dropout=0.0)
+    qkv = _sds((B, T, 3 * D_MODEL), jnp.bfloat16, one_chip)
+    _, text = _compile(
+        lambda x: flash_attention_qkv(x, HEADS, causal=True), qkv)
+    assert _kernels(text) == {"flash_fwd_qkv": 1}
+
+
+def test_flash_packed_qkv_backward(as_on_chip, one_chip):
+    from deeplearning4j_tpu.ops.flash_attention import flash_attention_qkv
+
+    qkv = _sds((B, T, 3 * D_MODEL), jnp.bfloat16, one_chip)
+
+    def loss(x):
+        return flash_attention_qkv(x, HEADS, causal=True).astype(
+            jnp.float32).sum()
+
+    _, text = _compile(jax.grad(loss), qkv)
+    assert _kernels(text) == {"flash_fwd_qkv": 1, "flash_bwd_qkv": 1}
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_flash_flat_layout_forward_backward(as_on_chip, one_chip, masked):
+    """[B, H, T, D] layout — the serving prefill's within-chunk attention
+    and the layer's fallback. With the table active this resolves the
+    flash_fwd/flash_bwd|T512|D128 entries (512x512 blocks, g=4)."""
+    from deeplearning4j_tpu.ops.flash_attention import flash_attention
+
+    q = _sds((B, HEADS, T, D_MODEL // HEADS), jnp.bfloat16, one_chip)
+    args = [q, q, q]
+    if masked:
+        args.append(_sds((B, T), jnp.float32, one_chip))
+
+    def loss(q, k, v, mask=None):
+        return flash_attention(q, k, v, causal=True, mask=mask).astype(
+            jnp.float32).sum()
+
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    assert _kernels(text) == {"flash_fwd": 1, "flash_bwd_fused": 1}
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_fused_layernorm(as_on_chip, one_chip, backward):
+    from deeplearning4j_tpu.ops.fused_layernorm import (
+        fused_layer_norm, supports)
+
+    assert supports((N_TOK, D_MODEL))
+    x = _sds((N_TOK, D_MODEL), jnp.bfloat16, one_chip)
+    g = _sds((D_MODEL,), jnp.bfloat16, one_chip)
+
+    def fwd(x, g, b):
+        return fused_layer_norm(x, g, b)
+
+    def loss(x, g, b):
+        return fused_layer_norm(x, g, b).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    _, text = _compile(fn, x, g, g)
+    want = {"fused_layer_norm_fwd": 1}
+    if backward:
+        want["fused_layer_norm_bwd"] = 1
+    assert _kernels(text) == want
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_softmax_xent_head(as_on_chip, one_chip, backward):
+    """V=10000 is not a multiple of 128: the head pads the vocab to whole
+    chunks. Backward = forward + dx kernel + dW/db kernel."""
+    from deeplearning4j_tpu.ops.fused_softmax_xent import (
+        softmax_xent_head, supports)
+
+    assert supports(N_TOK, D_MODEL, VOCAB)
+    x = _sds((N_TOK, D_MODEL), jnp.bfloat16, one_chip)
+    w = _sds((D_MODEL, VOCAB), jnp.bfloat16, one_chip)
+    b = _sds((VOCAB,), jnp.bfloat16, one_chip)
+    lab = _sds((N_TOK,), jnp.int32, one_chip)
+
+    def loss(x, w, b, lab):
+        return softmax_xent_head(x, w, b, lab).mean()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else loss
+    _, text = _compile(fn, x, w, b, lab)
+    want = {"softmax_xent_fwd": 1}
+    if backward:
+        want.update(softmax_xent_dx=1, softmax_xent_dwdb=1)
+    assert _kernels(text) == want
+
+
+@pytest.mark.parametrize("rows,vocab", [
+    (8, 10112),    # the serving vocab padded to the 128-lane tile
+    (128, 2048),   # the tuning table's sample|T128|D2048 shape
+])
+def test_fused_sampling(as_on_chip, one_chip, rows, vocab):
+    from deeplearning4j_tpu.ops.fused_sampling import fused_sample, supports
+
+    assert supports(rows, vocab)
+    lg = _sds((rows, vocab), jnp.float32, one_chip)
+    _, text = _compile(
+        lambda lg, nz: fused_sample(lg, nz, temperature=0.8, top_k=40,
+                                    top_p=0.95), lg, lg)
+    assert _kernels(text) == {"fused_sample": 1}
+
+
+def test_fused_neg_softmax_at_table_shape(as_on_chip, one_chip):
+    """neg_softmax|T256|D128 (B=256, K=5, D=128): the embedding engine's
+    sampled-softmax scores."""
+    from deeplearning4j_tpu.ops.fused_neg_softmax import (
+        neg_softmax_scores, supports)
+
+    assert supports(256, 5, 128)
+    c = _sds((256, 128), jnp.float32, one_chip)
+    neg = _sds((256, 5, 128), jnp.float32, one_chip)
+    _, text = _compile(neg_softmax_scores, c, c, neg)
+    assert _kernels(text) == {"neg_softmax": 1}
+
+
+@pytest.fixture(scope="module")
+def lm_shapes():
+    """The d1024 LM at depth 2 (width is what the kernels see), params
+    as shapes only."""
+    from deeplearning4j_tpu.models.transformer import transformer_lm
+
+    net = transformer_lm(vocab_size=VOCAB, d_model=D_MODEL, n_heads=HEADS,
+                         n_layers=2, d_ff=4 * D_MODEL, max_length=T,
+                         dtype="bfloat16")
+    net.init()
+    return net
+
+
+def _on(tree, sharding):
+    return jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, sharding), tree)
+
+
+def test_decode_step_over_paged_cache(as_on_chip, one_chip, lm_shapes):
+    """nn/decode.py single-query step: 4 slots against a cache of
+    256 + 32 positions on the page grid."""
+    net = lm_shapes
+    capacity = (256 + 32 + PAGE - 1) // PAGE * PAGE
+    step = net.incremental_decode_fn("f32", PAGE)
+    cache = jax.eval_shape(
+        lambda: net.init_kv_cache(SLOTS, capacity, "f32", PAGE))
+    tok = _sds((SLOTS,), jnp.int32, one_chip)
+    compiled, _ = _compile(
+        step, _on(net.params, one_chip), _on(net.state, one_chip),
+        _on(cache, one_chip), tok, tok)
+    assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("bucket", [64, 256, 512])
+def test_prefill_chunk(as_on_chip, one_chip, lm_shapes, bucket):
+    """nn/decode.py prefill at the serving buckets; 512 is inside the
+    flash envelope and must carry the masked flash kernel."""
+    net = lm_shapes
+    capacity = (512 + 32 + PAGE - 1) // PAGE * PAGE
+    prefill = net.prefill_fn("f32", PAGE)
+    cache = jax.eval_shape(
+        lambda: net.init_kv_cache(SLOTS, capacity, "f32", PAGE))
+    toks = _sds((1, bucket), jnp.int32, one_chip)
+    km = _sds((1, bucket), jnp.float32, one_chip)
+    one = _sds((1,), jnp.int32, one_chip)
+    _, text = _compile(
+        prefill, _on(net.params, one_chip), _on(net.state, one_chip),
+        _on(cache, one_chip), toks, km, one, one, one)
+    # depth 2: one masked flash forward per layer, inside the envelope
+    assert _kernels(text) == ({"flash_fwd": 2} if bucket >= 512 else {})
+
+
+# ------------------------------------------------- four chips: the 2x2 mesh
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(topo.devices[:4]).reshape(2, 2),
+                ("data", "model"))
+
+
+def test_mosaic_kernel_under_a_mesh_needs_the_row_shard_map(
+        as_on_chip, mesh_2x2):
+    """What stopped set_mesh on real chips: GSPMD cannot partition a
+    Mosaic kernel, so a jit over four devices refuses to lower it —
+    unless the train step names its mesh (ops/partition.kernel_mesh) and
+    the kernel runs per device over batch rows."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.ops.flash_attention import flash_attention_qkv
+    from deeplearning4j_tpu.ops.partition import kernel_mesh
+
+    qkv = _sds((B, T, 3 * D_MODEL), jnp.bfloat16,
+               NamedSharding(mesh_2x2, P("data", None, "model")))
+
+    def attn(x):
+        return flash_attention_qkv(x, HEADS, causal=True)
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(attn).lower(qkv)
+
+    def attn_per_device(x):
+        with kernel_mesh(mesh_2x2):
+            return attn(x)
+
+    compiled, text = _compile(attn_per_device, qkv)
+    assert _kernels(text) == {"flash_fwd_qkv": 1}
+    # batch 32 over all four devices: each program sees 8 rows
+    assert "bf16[8,512,3072]" in text
+
+
+def test_xent_head_under_a_mesh_splits_tokens_over_every_axis(
+        as_on_chip, mesh_2x2):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.ops.fused_softmax_xent import softmax_xent_head
+    from deeplearning4j_tpu.ops.partition import kernel_mesh
+
+    def sh(*spec):
+        return NamedSharding(mesh_2x2, P(*spec))
+
+    x = _sds((B, T, D_MODEL), jnp.bfloat16, sh("data"))
+    w = _sds((D_MODEL, VOCAB), jnp.bfloat16, sh(None, "model"))
+    b = _sds((VOCAB,), jnp.bfloat16, sh("model"))
+    lab = _sds((B, T), jnp.int32, sh("data"))
+
+    def loss(x, w, b, lab):
+        with kernel_mesh(mesh_2x2):
+            return softmax_xent_head(x, w, b, lab).mean()
+
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, w, b, lab)
+    assert _kernels(text) == {"softmax_xent_fwd": 1, "softmax_xent_dx": 1,
+                              "softmax_xent_dwdb": 1}
+    assert f"bf16[{N_TOK // 4},{D_MODEL}]" in text

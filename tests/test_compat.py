@@ -1,16 +1,13 @@
-"""Direct unit tests for util/compat.py — the jax 0.4/0.5 shim layer.
-
-It fixed 60+ seed tests in PR 1 but had no coverage of its own: both
-version branches are exercised here by monkeypatching the module-level
-probe results, with a recording fake standing in for the real
-jax.shard_map so the kwarg translation is asserted exactly."""
+"""Direct unit tests for util/compat.py — the single import point for
+`shard_map`, `tpu_compiler_params` and `pcast_varying` on the installed
+jax. A recording fake stands in for jax.shard_map so the pass-through is
+asserted exactly; the rest runs against the real jax."""
 
 import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from deeplearning4j_tpu.util import compat
 
@@ -26,10 +23,9 @@ def _record(calls):
     return fake_shard_map
 
 
-def test_new_jax_passes_kwargs_through(monkeypatch):
+def test_shard_map_passes_kwargs_through(monkeypatch):
     calls = []
     monkeypatch.setattr(compat, "_shard_map", _record(calls))
-    monkeypatch.setattr(compat, "_SHARD_MAP_VMA_KW", True)
     fn = lambda x: x  # noqa: E731
     compat.shard_map(fn, mesh=_FakeMesh(), check_vma=False,
                      axis_names=("seq",))
@@ -40,42 +36,8 @@ def test_new_jax_passes_kwargs_through(monkeypatch):
     assert "check_rep" not in kwargs and "auto" not in kwargs
 
 
-def test_old_jax_translates_check_vma_to_check_rep(monkeypatch):
-    calls = []
-    monkeypatch.setattr(compat, "_shard_map", _record(calls))
-    monkeypatch.setattr(compat, "_SHARD_MAP_VMA_KW", False)
-    compat.shard_map(lambda x: x, mesh=_FakeMesh(), check_vma=False)
-    (_f, kwargs), = calls
-    assert kwargs["check_rep"] is False
-    assert "check_vma" not in kwargs
-
-
-def test_old_jax_translates_axis_names_to_auto(monkeypatch):
-    calls = []
-    monkeypatch.setattr(compat, "_shard_map", _record(calls))
-    monkeypatch.setattr(compat, "_SHARD_MAP_VMA_KW", False)
-    # manual over seq only -> auto = the other mesh axes
-    compat.shard_map(lambda x: x, mesh=_FakeMesh(),
-                     axis_names=("seq",))
-    (_f, kwargs), = calls
-    assert "axis_names" not in kwargs
-    assert kwargs["auto"] == frozenset({"data", "model"})
-
-
-def test_old_jax_fully_manual_drops_auto(monkeypatch):
-    calls = []
-    monkeypatch.setattr(compat, "_shard_map", _record(calls))
-    monkeypatch.setattr(compat, "_SHARD_MAP_VMA_KW", False)
-    compat.shard_map(lambda x: x, mesh=_FakeMesh(),
-                     axis_names=("data", "model", "seq"))
-    (_f, kwargs), = calls
-    # manual == all mesh axes: no partial-manual selector at all
-    assert "auto" not in kwargs and "axis_names" not in kwargs
-
-
 def test_shard_map_runs_for_real_on_this_jax():
-    """Not a fake: the translated call must be accepted by whichever jax
-    generation this container ships."""
+    """Not a fake: the call must be accepted by the installed jax."""
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("d",))
     out = compat.shard_map(
@@ -84,33 +46,12 @@ def test_shard_map_runs_for_real_on_this_jax():
     np.testing.assert_allclose(np.asarray(out), [0.0, 2.0, 4.0, 6.0])
 
 
-def test_tpu_compiler_params_maps_to_available_class(monkeypatch):
-    recorded = {}
-
-    class FakeParams:
-        def __init__(self, **kw):
-            recorded.update(kw)
-
-    monkeypatch.setattr(compat, "_COMPILER_PARAMS_CLS", FakeParams)
-    obj = compat.tpu_compiler_params(vmem_limit_bytes=1 << 20)
-    assert isinstance(obj, FakeParams)
-    assert recorded == {"vmem_limit_bytes": 1 << 20}
-
-
 def test_tpu_compiler_params_real_class_accepts_vmem_limit():
     obj = compat.tpu_compiler_params(vmem_limit_bytes=64 * 1024 * 1024)
     assert obj.vmem_limit_bytes == 64 * 1024 * 1024
 
 
-def test_pcast_varying_identity_when_pcast_missing(monkeypatch):
-    fake_lax = types.SimpleNamespace()  # no .pcast attribute -> 0.4 path
-    monkeypatch.setattr(compat, "_jax",
-                        types.SimpleNamespace(lax=fake_lax))
-    x = jnp.ones((3,))
-    assert compat.pcast_varying(x, ("seq",)) is x
-
-
-def test_pcast_varying_calls_pcast_when_present(monkeypatch):
+def test_pcast_varying_calls_pcast(monkeypatch):
     calls = {}
 
     def fake_pcast(x, axis_names, to):
@@ -125,20 +66,10 @@ def test_pcast_varying_calls_pcast_when_present(monkeypatch):
     assert calls["args"] == (x, ("seq",), "varying")
 
 
-def test_module_resolved_a_shard_map_at_import():
-    """Whichever generation: the probe must have bound SOME shard_map and
-    a compiler-params class, or the whole parallel/ layer is dead."""
-    assert callable(compat._shard_map)
-    assert compat._COMPILER_PARAMS_CLS is not None
-    assert isinstance(compat._SHARD_MAP_VMA_KW, bool)
-
-
-@pytest.mark.parametrize("bad_kw", [{"check_vma": True},
-                                    {"axis_names": ("nope",)}])
-def test_old_jax_translation_never_leaks_new_spellings(monkeypatch, bad_kw):
-    calls = []
-    monkeypatch.setattr(compat, "_shard_map", _record(calls))
-    monkeypatch.setattr(compat, "_SHARD_MAP_VMA_KW", False)
-    compat.shard_map(lambda x: x, mesh=_FakeMesh(), **bad_kw)
-    (_f, kwargs), = calls
-    assert not set(kwargs) & {"check_vma", "axis_names"}
+def test_module_binds_the_installed_spellings():
+    """One installation, no version probe: the module binds jax.shard_map
+    and pltpu.CompilerParams directly."""
+    from jax.experimental.pallas import tpu as pltpu
+    assert compat._shard_map is jax.shard_map
+    assert isinstance(compat.tpu_compiler_params(), pltpu.CompilerParams)
+    assert not hasattr(compat, "_SHARD_MAP_VMA_KW")

@@ -228,11 +228,21 @@ def test_mfu_is_clamped_and_guarded():
     assert CostBook.mfu(1e12, 1.0, 0.0) == 0.0
 
 
-def test_peak_flops_matches_device_kind_substring():
-    assert costbook_mod.peak_flops("TPU v4") == 275e12
-    assert costbook_mod.peak_flops("TPU v5p pod") == 459e12
-    assert costbook_mod.peak_flops("cpu") == costbook_mod.DEFAULT_PEAK_FLOPS
-    assert costbook_mod.peak_flops(None) == costbook_mod.DEFAULT_PEAK_FLOPS
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_peak_flops_reads_the_one_table_by_device_kind():
+    assert costbook_mod.peak_flops(_Dev("tpu", "TPU v5 lite")) == 197e12
+    assert "source" in costbook_mod.DEVICE_PEAKS["TPU v5 lite"]
+
+
+def test_peak_flops_is_absent_off_tpu_and_raises_on_unknown_tpu():
+    assert costbook_mod.peak_flops(_Dev("cpu", "cpu")) is None
+    with pytest.raises(KeyError, match="TPU v9"):
+        costbook_mod.peak_flops(_Dev("tpu", "TPU v9"))
+    assert not hasattr(costbook_mod, "DEFAULT_PEAK_FLOPS")
 
 
 # --------------------------------------------------------------- reconcile

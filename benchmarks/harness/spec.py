@@ -1,0 +1,69 @@
+"""BENCHMARK.json and the files it names: cells, configurations, traffic
+mixes and per-layer metric readers are all found by name, so a later PR
+adds a cell by adding files and an entry, never by editing these."""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH_DIR)
+
+
+def load_benchmark(root: str = ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        return json.load(fh)
+
+
+def load_json(path: str) -> dict:
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def cell_of(bench: dict, name: str) -> dict:
+    for cell in bench["workloads"]:
+        if cell["name"] == name:
+            return cell
+    raise SystemExit(f"benchmark: no workload {name!r} in BENCHMARK.json; "
+                     f"known: {[c['name'] for c in bench['workloads']]}")
+
+
+def config_of(bench: dict, cell: dict, root: str = ROOT) -> dict:
+    for cfg in bench["configs"]:
+        if cfg["name"] == cell["config"]:
+            return load_json(os.path.join(root, cfg["file"]))
+    raise SystemExit(f"benchmark: cell {cell['name']!r} names configuration "
+                     f"{cell['config']!r}, which BENCHMARK.json lacks")
+
+
+def traffic_of(cell: dict) -> dict:
+    """The cell's traffic mix: benchmarks/traffic/<traffic>.json."""
+    return load_json(os.path.join(BENCH_DIR, "traffic",
+                                  cell["traffic"] + ".json"))
+
+
+def metrics_for(bench: dict, group: str, cell_name: str) -> list:
+    """The entries of `end_to_end` or `per_layer` this cell reports: those
+    with no `workloads` key, or with the cell in it."""
+    return [m for m in bench[group]
+            if "workloads" not in m or cell_name in m["workloads"]]
+
+
+def layer_reader(name: str):
+    """The reader of one per-layer metric:
+    benchmarks/layer_metrics/<name>.py, a module with `read(facts)` that
+    returns a number, or None where it finds nothing to read. A quantity
+    split by the end-to-end metric it moves (`device_idle_share.train`,
+    `device_idle_share.serve`) has one reader, named without the part
+    after the last dot, unless a file of the whole name is there."""
+    path = os.path.join(BENCH_DIR, "layer_metrics", name + ".py")
+    if not os.path.exists(path) and "." in name:
+        path = os.path.join(BENCH_DIR, "layer_metrics",
+                            name.rsplit(".", 1)[0] + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "layer_metric_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read

@@ -170,7 +170,10 @@ class GenRequest:
     """One admitted generation request: the raw prompt tokens, the
     output budget, timing marks, the emitted-token record, and a
     per-request stream queue the HTTP handler drains (None-terminated)
-    so tokens flow to the client as they decode."""
+    so tokens flow to the client as they decode. Each stream item is
+    `(token, now)`: the engine's clock reading of the step that made
+    the token rides beside it, so the handler can say how long the
+    token took from the step to the socket."""
 
     tokens: np.ndarray            # [L] int prompt
     max_new_tokens: int = 16
@@ -195,7 +198,7 @@ class GenRequest:
         if not self.emitted:
             self.t_first_token = now
         self.emitted.append(int(token))
-        self.stream.put(int(token))
+        self.stream.put((int(token), now))
 
     def finish(self, now: float, error: str | None = None) -> None:
         self.error = error

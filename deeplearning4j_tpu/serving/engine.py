@@ -41,6 +41,7 @@ graftlint AST stubs and costs tools nothing.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -67,6 +68,20 @@ def _peak_fields(peak) -> dict:
     """The device's published peak as an event/stats field — absent
     off-TPU (costbook.peak_flops gives None there), never invented."""
     return {} if peak is None else {"peak_flops": peak}
+
+
+def _engine_recorder(recorder):
+    """The recorder an engine emits through: the one it was given, else
+    the process default — and where that is the shared NullRecorder, a
+    NullRecorder of the engine's own, so that the sinks on it (its
+    server's /metrics registry) hear this engine's `request` events and
+    no other engine's."""
+    if recorder is not None:
+        return recorder
+    from deeplearning4j_tpu.telemetry import NullRecorder, get_default
+
+    recorder = get_default()
+    return recorder if recorder.live else NullRecorder()
 
 
 class QueueFullError(RuntimeError):
@@ -298,11 +313,7 @@ class InferenceEngine:
                  replicas: int = 1, max_wait_ms: float = 5.0,
                  sequence: bool = False, checkpoint: str | None = None,
                  faults=None, recorder=None):
-        if recorder is None:
-            from deeplearning4j_tpu.telemetry import get_default
-
-            recorder = get_default()
-        self.recorder = recorder
+        self.recorder = recorder = _engine_recorder(recorder)
         self.sequence = sequence
         if net.params is None:
             net.init()
@@ -680,7 +691,20 @@ class _GenWorker:
 
     kv_dtype="int8" swaps every cache entry for the quantized paged
     form ({"k","k_scale","v","v_scale"}) through the same three step
-    fns — shapes still lattice/page-grid points, ~4x less HBM/slot."""
+    fns — shapes still lattice/page-grid points, ~4x less HBM/slot.
+
+    THE LOOP IS NAMED WHOLE (telemetry/recorder.py): each pass is an
+    `admit` span, then per model step `step_prepare`, the step's own
+    span (`prefill_chunk` / `decode_step` / `verify_step`, from just
+    before the jit call to the fetched tokens) with its children
+    `dispatch` and `fetch`, then `emit`; or `idle_wait` when there is
+    nothing to run. These six are leaves, and all but `dispatch` start
+    where the region before them ended (`follows=True`), so the
+    recorder's own emission lies inside them and a device idle gap laid
+    over them says what the host was doing. A plain decode pass emits six
+    events; a request costs one `admit` event, a `page_pool` event at
+    each end and its `request` event on top — nothing per token. With
+    telemetry off every span is one shared no-op object."""
 
     def __init__(self, index: int, net, lattice: BucketLattice,
                  plan: CachePlan, prefill_chunk: int, max_queue: int,
@@ -880,22 +904,44 @@ class _GenWorker:
             self._cv.notify_all()
 
     def _admit(self, clock) -> None:
-        with self._cv:
-            while self.pending:
-                idx = self.slots.free_index()
-                if idx is None:
-                    return
-                req = self.pending[0]
-                pages = self.plan.request_pages(
-                    self.lattice.seq_bucket(req.prompt_len),
-                    req.max_new_tokens)
-                if not self.pool.try_reserve(pages):
-                    return  # pool exhausted: stays queued, not dropped
-                self.pending.popleft()
-                req.t_admitted = clock()
-                self.slots.admit(idx, req, pages)
-                self.recorder.event("page_pool", replica=self.index,
-                                    **self.pool.describe())
+        """One admission pass, under an `admit` span: bind queued
+        requests to free slots while the pool has their pages. The span
+        says why the head of the queue stayed (`blocked`: "slots" /
+        "pages" / None), each admission leaves an `admit` event that
+        ties the request's id to its slot — the join key for the
+        `decode_step` spans' `slots` — and the records are emitted once
+        `_cv` is released, so a submitter never waits on a sink."""
+        rec = self.recorder
+        admitted: list = []
+        blocked = None
+        with rec.span("admit", follows=True, replica=self.index) as sp:
+            with self._cv:
+                while self.pending:
+                    idx = self.slots.free_index()
+                    if idx is None:
+                        blocked = "slots"
+                        break
+                    req = self.pending[0]
+                    pages = self.plan.request_pages(
+                        self.lattice.seq_bucket(req.prompt_len),
+                        req.max_new_tokens)
+                    if not self.pool.try_reserve(pages):
+                        blocked = "pages"  # stays queued, not dropped
+                        break
+                    self.pending.popleft()
+                    req.t_admitted = clock()
+                    self.slots.admit(idx, req, pages)
+                    if rec.live:
+                        admitted.append((req, idx, self.pool.describe()))
+                pending = len(self.pending)
+            for req, idx, pool in admitted:
+                rec.event("admit", id=req.request_id,
+                          trace_id=req.request_id, slot=idx,
+                          replica=self.index,
+                          queue_s=round(req.t_admitted - req.t_enqueue, 6))
+                rec.event("page_pool", replica=self.index, **pool)
+            sp.update(admitted=len(admitted), pending=pending,
+                      blocked=blocked)
 
     # ----------------------------------------------------------- compute
     def _run_prefill_chunk_bucketed(self, slot_idx: int, clock) -> None:
@@ -914,81 +960,96 @@ class _GenWorker:
         visible: the jit sees only padded bucket arrays, and the only
         host fetch is the one batch-boundary np.asarray of the
         next-token id."""
-        slot = self.slots.slots[slot_idx]
-        req = slot.request
-        L = req.prompt_len
-        Tc = self._next_chunk_len(L - slot.start)
-        n_real = min(Tc, L - slot.start)
-        padded_tokens = np.zeros((1, Tc), np.int32)
-        padded_tokens[0, :n_real] = req.tokens[slot.start:slot.start
-                                               + n_real]
-        bucket_kmask = np.zeros((1, Tc), np.float32)
-        bucket_kmask[0, :n_real] = 1.0
-        final = slot.start + n_real >= L
-        key = ("prefill", Tc)
-        first = key not in self._seen_shapes
-        ws = self.weights.current
+        rec = self.recorder
+        with rec.span("step_prepare", follows=True, replica=self.index,
+                      kind="prefill"):
+            slot = self.slots.slots[slot_idx]
+            req = slot.request
+            L = req.prompt_len
+            Tc = self._next_chunk_len(L - slot.start)
+            n_real = min(Tc, L - slot.start)
+            padded_tokens = np.zeros((1, Tc), np.int32)
+            padded_tokens[0, :n_real] = req.tokens[slot.start:slot.start
+                                                   + n_real]
+            bucket_kmask = np.zeros((1, Tc), np.float32)
+            bucket_kmask[0, :n_real] = 1.0
+            final = slot.start + n_real >= L
+            key = ("prefill", Tc)
+            first = key not in self._seen_shapes
+            ws = self.weights.current
+            args = (ws.params, ws.state, self.cache,
+                    padded_tokens, bucket_kmask,
+                    np.asarray([slot_idx], np.int32),
+                    np.asarray([slot.start], np.int32),
+                    np.asarray([n_real - 1], np.int32))
+        compiling = (rec.span("compile", kind="prefill", bucket=[1, Tc],
+                              replica=self.index)
+                     if first else contextlib.nullcontext())
         try:
-            with self.recorder.span("prefill_chunk", bucket=[1, Tc],
-                                    start=slot.start, replica=self.index,
-                                    final=final):
-                args = (ws.params, ws.state, self.cache,
-                        padded_tokens, bucket_kmask,
-                        np.asarray([slot_idx], np.int32),
-                        np.asarray([slot.start], np.int32),
-                        np.asarray([n_real - 1], np.int32))
-                if first:
-                    with self.recorder.span("compile", kind="prefill",
-                                            bucket=[1, Tc],
-                                            replica=self.index):
-                        tok, cache = self._prefill_jit(*args)
-                        toks = np.asarray(tok)  # batch-boundary fetch
-                    self._seen_shapes.add(key)
-                else:
+            with rec.span("prefill_chunk", bucket=[1, Tc],
+                          start=slot.start, replica=self.index,
+                          final=final, n_real=n_real), compiling:
+                with rec.span("dispatch"):
                     tok, cache = self._prefill_jit(*args)
+                with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # batch-boundary fetch
         except Exception as exc:
             self._fail_slot(slot_idx, exc, clock)
             return
-        self.cache = cache
-        slot.start += n_real
-        if final:
-            # the prompt's last forward row IS the first generated
-            # token: TTFT is this chunk's completion
-            slot.pos = L
-            slot.last_token = int(toks[0])
-            now = clock()
-            req.emit(slot.last_token, now)
-            with self._mu:
-                self.tokens_out += 1
-            self._maybe_complete(slot_idx, clock)
+        with rec.span("emit", follows=True, replica=self.index,
+                      tokens=int(final)) as sp:
+            if first:
+                self._seen_shapes.add(key)
+            self.cache = cache
+            slot.start += n_real
+            if final:
+                # the prompt's last forward row IS the first generated
+                # token: TTFT is this chunk's completion
+                slot.pos = L
+                slot.last_token = int(toks[0])
+                now = clock()
+                req.emit(slot.last_token, now)
+                with self._mu:
+                    self.tokens_out += 1
+                self._maybe_complete(slot_idx, clock)
+            # `args` holds the last reference to the cache the chunk read
+            t_release = time.perf_counter()
+            del tok, args
+            sp["release_s"] = round(time.perf_counter() - t_release, 6)
 
     def _decode_batch_step(self, active: list, clock) -> None:
         """One fixed-shape decode step over every slot row; `active`
         names the rows whose outputs are real. One np.asarray for the
         whole [n_slots] next-token vector — the batch-boundary fetch —
-        then host-side distribution to the slots."""
-        B = self.plan.n_slots
-        padded_tokens = np.zeros(B, np.int32)
-        pos = np.full(B, self.plan.capacity - 1, np.int32)  # scratch
-        for i in active:
-            slot = self.slots.slots[i]
-            padded_tokens[i] = slot.last_token
-            pos[i] = slot.pos
-        ws = self.weights.current
-        with self._mu:
-            self.decode_steps_run += 1
-        self.current_batch = list(active)
+        then host-side distribution to the slots. `decode_step.slots`
+        names the rows: with the `admit` events (id -> slot) a request's
+        decode steps join to its id."""
+        rec = self.recorder
+        with rec.span("step_prepare", follows=True, replica=self.index,
+                      kind="decode"):
+            B = self.plan.n_slots
+            padded_tokens = np.zeros(B, np.int32)
+            pos = np.full(B, self.plan.capacity - 1, np.int32)  # scratch
+            for i in active:
+                slot = self.slots.slots[i]
+                padded_tokens[i] = slot.last_token
+                pos[i] = slot.pos
+            ws = self.weights.current
+            with self._mu:
+                self.decode_steps_run += 1
+            self.current_batch = list(active)
         try:
-            with self.recorder.span("decode_step", replica=self.index,
-                                    n_active=len(active)):
+            with rec.span("decode_step", replica=self.index,
+                          n_active=len(active), slots=self.current_batch):
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
-                tok, cache = self._decode_jit(
-                    ws.params, ws.state, self.cache,
-                    padded_tokens, pos)
-                toks = np.asarray(tok)  # batch-boundary fetch
+                with rec.span("dispatch"):
+                    tok, cache = self._decode_jit(
+                        ws.params, ws.state, self.cache,
+                        padded_tokens, pos)
+                with rec.span("fetch", follows=True):
+                    toks = np.asarray(tok)  # batch-boundary fetch
         except ReplicaKilled as exc:
             # injected mid-decode death: every active slot fails (pages
             # released by _fail_slot), the thread dies; the supervisor
@@ -1006,17 +1067,26 @@ class _GenWorker:
                 self._fail_slot(i, exc, clock)
             self.current_batch = None
             return
-        self.current_batch = None
-        self.cache = cache
-        now = clock()
-        for i in active:
-            slot = self.slots.slots[i]
-            slot.pos += 1
-            slot.last_token = int(toks[i])
-            slot.request.emit(slot.last_token, now)
-            with self._mu:
-                self.tokens_out += 1
-            self._maybe_complete(i, clock)
+        with rec.span("emit", follows=True, replica=self.index,
+                      tokens=len(active)) as sp:
+            self.current_batch = None
+            self.cache = cache
+            now = clock()
+            for i in active:
+                slot = self.slots.slots[i]
+                slot.pos += 1
+                slot.last_token = int(toks[i])
+                slot.request.emit(slot.last_token, now)
+                with self._mu:
+                    self.tokens_out += 1
+                self._maybe_complete(i, clock)
+            # dropping the step's device outputs blocks (about a
+            # millisecond for the token vector on a v5e: PERF.md section
+            # 5), so it happens here, inside a named span and with a
+            # field of its own, not in the frame's teardown after it
+            t_release = time.perf_counter()
+            del tok
+            sp["release_s"] = round(time.perf_counter() - t_release, 6)
 
     def _speculative_batch_step(self, active: list, clock) -> None:
         """One fixed-shape VERIFY step over every slot row: each active
@@ -1029,36 +1099,42 @@ class _GenWorker:
         (`draft_overhead_us`) and the per-step `draft` telemetry event
         is what the replay's accepted_tokens_per_step headline
         reconstructs from."""
-        B, K = self.plan.n_slots, self.speculative_k
-        padded_windows = np.zeros((B, K), np.int32)
-        pos = np.full(B, self.plan.capacity - 1, np.int32)  # scratch
-        t_draft = time.perf_counter()
-        drafts: dict = {}
-        for i in active:
-            slot = self.slots.slots[i]
-            req = slot.request
-            d = self.proposer.propose(
-                list(req.tokens) + list(req.emitted), K - 1)
-            drafts[i] = d
-            padded_windows[i, 0] = slot.last_token
-            padded_windows[i, 1:] = d
-            pos[i] = slot.pos
-        draft_s = time.perf_counter() - t_draft
-        ws = self.weights.current
-        with self._mu:
-            self.decode_steps_run += 1
-            self.verify_steps_run += 1
-        self.current_batch = list(active)
+        rec = self.recorder
+        with rec.span("step_prepare", follows=True, replica=self.index,
+                      kind="verify"):
+            B, K = self.plan.n_slots, self.speculative_k
+            padded_windows = np.zeros((B, K), np.int32)
+            pos = np.full(B, self.plan.capacity - 1, np.int32)  # scratch
+            t_draft = time.perf_counter()
+            drafts: dict = {}
+            for i in active:
+                slot = self.slots.slots[i]
+                req = slot.request
+                d = self.proposer.propose(
+                    list(req.tokens) + list(req.emitted), K - 1)
+                drafts[i] = d
+                padded_windows[i, 0] = slot.last_token
+                padded_windows[i, 1:] = d
+                pos[i] = slot.pos
+            draft_s = time.perf_counter() - t_draft
+            ws = self.weights.current
+            with self._mu:
+                self.decode_steps_run += 1
+                self.verify_steps_run += 1
+            self.current_batch = list(active)
         try:
-            with self.recorder.span("verify_step", replica=self.index,
-                                    n_active=len(active), k=K):
+            with rec.span("verify_step", replica=self.index,
+                          n_active=len(active), k=K,
+                          slots=self.current_batch):
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
-                tok, cache = self._verify_jit(
-                    ws.params, ws.state, self.cache,
-                    padded_windows, pos)
-                toks = np.asarray(tok)  # [B, k] batch-boundary fetch
+                with rec.span("dispatch"):
+                    tok, cache = self._verify_jit(
+                        ws.params, ws.state, self.cache,
+                        padded_windows, pos)
+                with rec.span("fetch", follows=True):
+                    toks = np.asarray(tok)  # [B, k] batch-boundary fetch
         except ReplicaKilled as exc:
             # same containment contract as the plain decode step
             self.current_batch = None
@@ -1072,36 +1148,41 @@ class _GenWorker:
                 self._fail_slot(i, exc, clock)
             self.current_batch = None
             return
-        self.current_batch = None
-        self.cache = cache
-        now = clock()
-        step_emitted = 0
-        step_accepted = 0
-        for i in active:
-            slot = self.slots.slots[i]
-            req = slot.request
-            budget = req.max_new_tokens - len(req.emitted)
-            _n_acc, emitted = accept_greedy(drafts[i], toks[i])
-            take = min(len(emitted), budget)
-            for t in emitted[:take]:
-                req.emit(int(t), now)
-                with self._mu:
-                    self.tokens_out += 1
-            slot.pos += take
-            slot.last_token = int(emitted[take - 1])
-            step_emitted += take
-            step_accepted += take - 1  # drafts accepted (bonus aside)
-            self._maybe_complete(i, clock)
-        with self._mu:
-            self.accepted_tokens += step_emitted
-            self.drafted_tokens += (K - 1) * len(active)
-            self.slot_steps += len(active)
-            self.draft_overhead_s += draft_s
-        self.recorder.event("draft", replica=self.index, k=K,
-                            n_active=len(active), emitted=step_emitted,
-                            accepted=step_accepted,
-                            drafted=(K - 1) * len(active),
-                            overhead_us=round(draft_s * 1e6, 2))
+        with rec.span("emit", follows=True, replica=self.index) as sp:
+            self.current_batch = None
+            self.cache = cache
+            now = clock()
+            step_emitted = 0
+            step_accepted = 0
+            for i in active:
+                slot = self.slots.slots[i]
+                req = slot.request
+                budget = req.max_new_tokens - len(req.emitted)
+                _n_acc, emitted = accept_greedy(drafts[i], toks[i])
+                take = min(len(emitted), budget)
+                for t in emitted[:take]:
+                    req.emit(int(t), now)
+                    with self._mu:
+                        self.tokens_out += 1
+                slot.pos += take
+                slot.last_token = int(emitted[take - 1])
+                step_emitted += take
+                step_accepted += take - 1  # drafts accepted (bonus aside)
+                self._maybe_complete(i, clock)
+            with self._mu:
+                self.accepted_tokens += step_emitted
+                self.drafted_tokens += (K - 1) * len(active)
+                self.slot_steps += len(active)
+                self.draft_overhead_s += draft_s
+            rec.event("draft", replica=self.index, k=K,
+                      n_active=len(active), emitted=step_emitted,
+                      accepted=step_accepted,
+                      drafted=(K - 1) * len(active),
+                      overhead_us=round(draft_s * 1e6, 2))
+            sp["tokens"] = step_emitted
+            t_release = time.perf_counter()
+            del tok  # as in the plain decode step
+            sp["release_s"] = round(time.perf_counter() - t_release, 6)
 
     # -------------------------------------------------------- lifecycle
     def _maybe_complete(self, slot_idx: int, clock) -> None:
@@ -1110,12 +1191,16 @@ class _GenWorker:
         if len(req.emitted) < req.max_new_tokens:
             return
         self.pool.release(self.slots.release(slot_idx))
-        self.recorder.event("page_pool", replica=self.index,
-                            **self.pool.describe())
+        self._page_pool_event()
         req.finish(clock())
         with self._mu:
             self.served += 1
         self._request_event(req, ok=True)
+
+    def _page_pool_event(self) -> None:
+        if self.recorder.live:  # else describe() builds what nobody keeps
+            self.recorder.event("page_pool", replica=self.index,
+                                **self.pool.describe())
 
     def _fail_slot(self, slot_idx: int, exc: Exception, clock) -> None:
         """Mid-decode death containment: the slot's request fails
@@ -1124,8 +1209,7 @@ class _GenWorker:
         slot = self.slots.slots[slot_idx]
         req = slot.request
         self.pool.release(self.slots.release(slot_idx))
-        self.recorder.event("page_pool", replica=self.index,
-                            **self.pool.describe())
+        self._page_pool_event()
         self.recorder.error(f"gen-replica:{self.index}", exc=exc)
         err = "".join(traceback.format_exception_only(type(exc),
                                                       exc)).strip()
@@ -1177,14 +1261,20 @@ class _GenWorker:
                     return  # dead: the fleet supervisor respawns
                 if progressed:
                     continue
-                with self._cv:
-                    if self._closed and not self.pending \
-                            and not self.slots.busy():
-                        if self.lifecycle != "dead":
-                            self.lifecycle = "retired"
-                        return
-                    if not self.pending or self.slots.free_index() is None:
-                        self._cv.wait(timeout=0.05)
+                # nothing to run: an idle device under this span has an
+                # idle engine, not a slow one (opened outside `_cv`: no
+                # recorder call runs under the queue lock)
+                with self.recorder.span("idle_wait", follows=True,
+                                        replica=self.index):
+                    with self._cv:
+                        if self._closed and not self.pending \
+                                and not self.slots.busy():
+                            if self.lifecycle != "dead":
+                                self.lifecycle = "retired"
+                            return
+                        if not self.pending \
+                                or self.slots.free_index() is None:
+                            self._cv.wait(timeout=0.05)
 
         self.lifecycle = "serving"
         self._thread = threading.Thread(target=loop, daemon=True,
@@ -1273,11 +1363,7 @@ class GenerationEngine:
                  replicas: int = 1, checkpoint: str | None = None,
                  speculative_k: int = 0, kv_dtype: str = "f32",
                  faults=None, recorder=None):
-        if recorder is None:
-            from deeplearning4j_tpu.telemetry import get_default
-
-            recorder = get_default()
-        self.recorder = recorder
+        self.recorder = recorder = _engine_recorder(recorder)
         if lattice.seq_lens is None:
             raise ValueError("generation needs a sequence lattice "
                              "(BucketLattice with seq_lens)")
@@ -1312,7 +1398,10 @@ class GenerationEngine:
         self.plan = CachePlan(lattice.max_seq, max_new_tokens,
                               max(1, int(slots)), page_size,
                               pool_pages=pool_pages, kv_dtype=kv_dtype)
-        self._clock = time.monotonic
+        # every timing mark of a request (t_enqueue .. t_done, the stamp
+        # beside each streamed token) is read on this clock; the front
+        # door reads it too, for the stream's lag
+        self.clock = self._clock = time.monotonic
         self.costbook = CostBook(recorder)
         self._workers = [
             _GenWorker(i, net, lattice, self.plan, chunk, max_queue,

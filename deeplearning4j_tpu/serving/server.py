@@ -49,15 +49,16 @@ Endpoints (all JSON):
                         (accepted tokens/step, draft acceptance rate)
                         when the engine decodes speculatively,
                         published weight generation/step, per-replica
-                        liveness and heartbeat age, and the memory/MFU
+                        liveness and heartbeat age, and the memory
                         surface: `serving_hbm_live_bytes`,
                         `serving_hbm_limit_bytes` + per-device
                         `serving_hbm_headroom_ratio` (TPU only),
-                        `serving_memory_ledger_bytes{subsystem=...}`
-                        (telemetry/memstat.py ledger), and
-                        `serving_mfu_live` (cost-book flops over
-                        measured forward time; telemetry/costbook.py)
-                        — the fleet's pager surface
+                        and `serving_memory_ledger_bytes{subsystem=...}`
+                        (telemetry/memstat.py ledger) — the fleet's
+                        pager surface. The request counter and the
+                        three request histograms count with telemetry
+                        off too: the NullRecorder still hands `request`
+                        events to its sinks
     GET  /healthz       {"status", "replicas", "lattice", "served", ...,
                         "fleet": [per-replica {index, state (warming/
                         serving/draining/dead/retired), alive, counters,
@@ -81,6 +82,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
@@ -201,7 +203,15 @@ class _Handler(BaseHTTPRequestHandler):
         close-delimited, so plain urllib readers see each line as it
         flushes). The summary line carries the full token list and the
         TTFT/total timing so a client that only reads the tail still
-        gets everything."""
+        gets everything.
+
+        With telemetry on, the handler keeps one float a token — the
+        engine's clock after the line is flushed, less the stamp the
+        engine put beside the token — and emits ONE `stream` event for
+        the request, just before the summary line (a client that has
+        read `done` finds the record complete): no per-token event and
+        no span on this thread."""
+        t_arrived = time.perf_counter()
         engine = self.serving.engine
         if not hasattr(engine, "submit_generate"):
             self._json({"error": "this engine does not serve "
@@ -219,6 +229,7 @@ class _Handler(BaseHTTPRequestHandler):
         except (KeyError, ValueError, TypeError) as exc:
             self._json({"error": f"bad request body: {exc!r}"}, 400)
             return
+        parse_s = time.perf_counter() - t_arrived
         from deeplearning4j_tpu.serving.engine import QueueFullError
 
         try:
@@ -234,17 +245,26 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_response(200)
         self.send_header("Content-Type", "application/x-ndjson")
         self.end_headers()
+        recorder, clock = engine.recorder, engine.clock
+        lags = [] if recorder.live else None
         i = 0
         while True:
             try:
-                tok = req.stream.get(timeout=REQUEST_TIMEOUT_S)
+                item = req.stream.get(timeout=REQUEST_TIMEOUT_S)
             except Exception:
                 self._line({"id": req.request_id, "error": "timed out"})
                 return
-            if tok is None:
+            if item is None:
                 break
-            self._line({"token": int(tok), "i": i})
+            tok, t_step = item
+            self._line({"token": tok, "i": i})
+            if lags is not None:
+                lags.append(round(clock() - t_step, 6))
             i += 1
+        if lags is not None:
+            recorder.event("stream", id=req.request_id,
+                           trace_id=req.request_id, n=i, lag_s=lags,
+                           parse_s=round(parse_s, 6))
         summary = {"done": True, "id": req.request_id,
                    "tokens": list(req.emitted),
                    "timing": {
@@ -397,10 +417,6 @@ class ServingMetrics:
             "serving_memory_ledger_bytes",
             "live bytes attributed per subsystem (params/opt_state/"
             "kv_pages/prefetch/activations/other)")
-        self.mfu_live = self.registry.gauge(
-            "serving_mfu_live",
-            "model FLOPs utilization over recent forwards: cost-book "
-            "flops / measured forward seconds / device peak FLOPs")
         # the embedding-engine data-movement surface: one latency
         # histogram per span kind (gather / scatter_add / ann_probe —
         # the registered recorder spans) plus a bytes-moved counter,
@@ -414,10 +430,6 @@ class ServingMetrics:
         self.embed_bytes = self.registry.counter(
             "serving_embedding_bytes_total",
             "bytes moved by embedding-engine spans, by span kind")
-        # recent per-forward MFU samples, fed by on_event (cheap append);
-        # the gauge publishes their mean at collection time
-        from collections import deque
-        self._mfu_window = deque(maxlen=64)
         self.registry.add_collector(self._collect)
 
     # ------------------------------------------------------- live events
@@ -438,8 +450,6 @@ class ServingMetrics:
                                       float(ev["queue_s"]))
             if "ttft_s" in ev:
                 self.registry.observe(self.ttft, float(ev["ttft_s"]))
-            if "forward_s" in ev and "bucket" in ev:
-                self._observe_mfu(ev)
         elif kind == "anomaly":
             self.registry.inc(self.anomalies, 1.0,
                               kind=str(ev.get("kind", "unknown")))
@@ -451,20 +461,6 @@ class ServingMetrics:
             if ev.get("bytes"):
                 self.registry.inc(self.embed_bytes, float(ev["bytes"]),
                                   span=str(name))
-
-    def _observe_mfu(self, ev: dict) -> None:
-        """Per-forward MFU sample: the warmed cost book's flops for the
-        request's bucket over the measured forward wall time and the
-        device's peak. Dict lookups only — no analysis on this path."""
-        book = getattr(self.engine, "costbook", None)
-        peak = float(getattr(self.engine, "peak_flops", 0.0) or 0.0)
-        if book is None or peak <= 0.0:
-            return
-        flops = book.flops("forward", ev["bucket"])
-        seconds = float(ev["forward_s"] or 0.0)
-        if flops <= 0.0 or seconds <= 0.0:
-            return
-        self._mfu_window.append(book.mfu(flops, seconds, peak))
 
     # ---------------------------------------------------------- scraping
     def _collect(self) -> None:
@@ -523,9 +519,6 @@ class ServingMetrics:
                     self.hbm_headroom.set(
                         1.0 - float(row.get("bytes_in_use", 0)) / limit,
                         device=str(dev))
-        if self._mfu_window:
-            window = list(self._mfu_window)
-            self.mfu_live.set(sum(window) / len(window))
 
     def render(self) -> str:
         return self.registry.render()

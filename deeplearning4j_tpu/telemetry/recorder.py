@@ -17,7 +17,7 @@ Event schema — one JSON object per line, every event carrying
 |---|---|
 | `meta`   | run header: argv, platform, pid, free-form fields |
 | `step`   | per-iteration training metrics: `iteration`, `score`, throughput fields (fed by `TelemetryListener` without hot-path host syncs) |
-| `span`   | a timed region: `name` ("compile", "step", "mode:vgg16", ...), `seconds` wall-clock, `ok`, caller fields |
+| `span`   | a timed region: `name` ("compile", "step", "mode:vgg16", ...), `seconds` wall-clock, `ok`, `t0` / `t1` (`time.perf_counter()` at entry and exit: the process's monotonic clock, the one a profiler trace is laid over; `ts` is the wall clock at the span's END, rounded to 1 ms), caller fields |
 | `metric` | a bench metric line verbatim (same dict `bench._emit` prints) |
 | `eval`   | evaluation results (accuracy/f1/stats dict) |
 | `memory` | device-memory snapshot: `live_array_bytes`, `live_array_count`, per-device `memory_stats` when the backend exposes them (`bytes_in_use`, `peak_bytes_in_use`, `bytes_limit`; CPU backends return None — live-array accounting only). Ledger-attributed snapshots (telemetry/memstat.py) additionally carry `ledger` (per-subsystem `{params, opt_state, kv_pages, prefetch, activations, other}` byte map summing to `ledger_total_bytes`) and `source` ("fit" / "stats_tick" / "sampler") — emitted strictly at batch boundaries or on the sampler thread, never inside a jitted region (G029) |
@@ -27,6 +27,8 @@ Event schema — one JSON object per line, every event carrying
 | `kernel_tune` | one kernel-autotune micro-bench measurement (tools/kerneltune.py): `kernel`, `key` (the ops/autotune.py config key), `params` (the candidate block sizes), `seconds` (per-call wall clock), `role` ("default" / "candidate" / "chosen"), free-form fields — the provenance trail behind every tuning_table.json entry |
 | `request` | one served inference request (serving/engine.py): `id`, `ok`, `bucket` ([batch, seq]), `replica`, `queue_s` (enqueue -> batch cut), `batch_assemble_s` (host-side padding), `forward_s` (jitted forward incl. batch-boundary fetch), `total_s` (enqueue -> result), `seq_len`/`padded_seq` for sequence models, `weight_gen` (the published weight generation the batch served against — serving/fleet.py), `error` on a failed batch — the ONLY record serving/replay.py reconstructs p50/p99/QPS from. Generation requests carry `kind: "generate"` plus `prompt_len`, `prompt_bucket`, `new_tokens`, and `ttft_s` (enqueue -> first token, i.e. the prefill's final chunk) — the rows tokens/sec and TTFT percentiles reconstruct from |
 | `page_pool` | KV-cache page accounting snapshot (serving/kvcache.py), emitted on every reserve/release: `replica`, `pages_total`, `page_size`, `pages_in_use`, `pages_peak` — the cache-occupancy headline's only source |
+| `admit` | one generation request bound to a decode slot (serving/engine.py `_admit`): `id`, `slot`, `replica`, `queue_s` (enqueue -> admission) — with it a request's decode steps are the `decode_step` spans whose `slots` hold its slot between this event and its `request` event: queue, prefill chunks, decode steps and stream of one request join on one id with no per-token field |
+| `stream` | one `/generate` response as the front door wrote it (serving/server.py), emitted once at the request's end by the handler thread: `id`, `n` (token lines written), `lag_s` (per token: the line flushed, on the engine's clock, minus the engine's stamp of the step that produced it — the per-slot `queue.put`, the handler thread's wake-up, the JSON line and the socket flush), `parse_s` (body read + JSON parse before `submit_generate`) |
 | `draft` | one speculative verify step's draft accounting (serving/engine.py): `replica`, `k` (window width), `n_active`, `emitted` (tokens emitted this step across slots), `accepted` (accepted drafts = emitted minus the per-slot bonus token), `drafted` ((k-1) * n_active proposals offered), `overhead_us` (host-side proposer wall clock) — the `accepted_tokens_per_step` and `draft_overhead_us` bench rows reconstruct from exactly these |
 | `reshard_plan` | a portable-resharding plan (reshard/) put on the record BEFORE any transfer: `path` ("live" / "checkpoint"), `src`/`dst` placement descriptions, `n_leaves`, per-action counts, `bytes_total`, `bytes_moved`, `bytes_lower_bound`; the transfer itself runs inside a `span` named `reshard` carrying the same byte fields |
 | `placement_search` | one automatic-placement-search run (reshard/search.py) put on the record BEFORE any mesh is built: `path` ("cli" = the `plan` dry-run, "elastic" = a worker's per-generation re-plan, "reform" = the supervisor's pre-relaunch search, "bench" = the placement_search bench), `fleet` ("2x4"), `profile`, `candidates_considered` / `candidates_feasible` / `pruned`, `winner` (the placement description), the winner's score breakdown (`winner_score`, `winner_memory_bytes`, `winner_collective_bytes`, `winner_bubble_cost`, `winner_idle_cost`), and `search_ms` — the elastic timeline test asserts one per worker per generation |
@@ -60,16 +62,40 @@ export) never meets a name it cannot classify. Dynamic names
 the static check and parse as opaque spans.
 
 Generation serving adds three hot-loop span names: `prefill_chunk` (one
-bucket-shaped prompt chunk — `bucket`, `start`, `final`, `replica`),
+bucket-shaped prompt chunk — `bucket`, `start`, `final`, `replica`,
+`n_real` = the prompt tokens in the bucket, the rest is padding),
 `decode_step` (one fixed-shape step over every decode slot — `replica`,
-`n_active`), and `verify_step` (one fixed-shape speculative
-verification over every slot's k-token draft window — `replica`,
-`n_active`, `k`; it REPLACES decode_step when the engine runs with
+`n_active`, `slots` = the active slot indices), and `verify_step`
+(one fixed-shape speculative verification over every slot's k-token
+draft window — `replica`, `n_active`, `k`, `slots`; it REPLACES
+decode_step when the engine runs with
 `speculative_k >= 2`, and each one pairs with a `draft` event carrying
 the acceptance accounting); their first execution per shape nests a
 `compile` span exactly like the predict path, and the
 flat-across-prompt-buckets property of the decode_step timings is the
 "decode cost independent of prompt length" gate in tier-1.
+
+The engine thread's host loop (serving/engine.py `_GenWorker`) is named
+whole: every instant between two model steps lies in one of six LEAF
+spans, so a device idle gap laid over them names what the host was
+doing. Per pass of the loop: `admit` (one admission pass under the
+queue lock — `admitted`, `pending` = left waiting, `blocked` =
+"slots" / "pages" / null, why the head of the queue stayed), then per
+model step `step_prepare` (the numpy build of the step's arguments —
+`kind` "prefill" / "decode" / "verify"), inside the
+`prefill_chunk` / `decode_step` / `verify_step` span its two children
+`dispatch` (until the jit call returns: argument flattening and the
+enqueue) and `fetch` (the one batch-boundary `np.asarray`, which waits
+for the device), and `emit` (from the fetch's return to the step's last
+completion: the release of the step's device outputs, the per-slot
+stream puts, completions, `request` events — `tokens`, `release_s` =
+the part spent dropping the fetched device arrays); or, with nothing
+to run, `idle_wait` (the `_cv.wait`: an idle
+device under it has an idle engine, not a slow one). `admit`,
+`step_prepare`, `fetch`, `emit` and `idle_wait` are opened with
+`follows=True`: each starts where the region before it ended, so the
+recorder's own emission lies inside a named leaf; the model step's span
+and its `dispatch` keep their own start, just before the jit call.
 
 The input pipeline (data/pipeline.py) names an ``input_wait`` span
 around EVERY batch dequeue in the fit loops: `pipelined` (false = the
@@ -128,7 +154,7 @@ ENV_VAR = "DL4J_TPU_TELEMETRY"
 EVENT_KINDS = frozenset({
     "meta", "step", "span", "metric", "eval", "memory", "error", "fault",
     "bucket_plan", "kernel_tune", "request", "page_pool", "draft",
-    "reshard_plan",
+    "admit", "stream", "reshard_plan",
     "placement_search", "host_gather", "weight_swap", "autoscale",
     "anomaly", "cost", "cost_drift",
 })
@@ -139,6 +165,8 @@ SPAN_NAMES = frozenset({
     # serving batch pipeline (serving/batcher.py, engine.py)
     "queue", "batch_assemble", "forward", "prefill_chunk", "decode_step",
     "verify_step", "drain",
+    # the generation engine's host loop, leaf spans (serving/engine.py)
+    "admit", "step_prepare", "dispatch", "fetch", "emit", "idle_wait",
     # input pipeline (data/pipeline.py)
     "input_wait",
     # resharding + placement (reshard/)
@@ -161,6 +189,10 @@ class Recorder:
     """Appends typed JSONL events to a per-run file (and an in-memory
     ring buffer, inspectable as `.events`). `path=None` records in
     memory only — the unit-test and interactive mode."""
+
+    # False on the NullRecorder: a call site asks before it builds fields
+    # nobody keeps (`page_pool`'s `describe()`, the per-token stream lags)
+    live = True
 
     def __init__(self, path: str | None = None, run_id: str | None = None,
                  keep: int = DEFAULT_KEEP):
@@ -252,11 +284,7 @@ class Recorder:
             sinks = list(self._sinks)
         # fan out AFTER releasing: a sink acquiring its own lock (the
         # /metrics histogram update) must not run under `_lock`
-        for sink in sinks:
-            try:
-                sink(rec)
-            except Exception:
-                pass  # a broken sink must never break recording
+        _fan_out(sinks, rec)
         return rec
 
     def _write(self, rec: dict) -> None:
@@ -411,47 +439,125 @@ class Recorder:
                           source=source, **fields)
 
     # -------------------------------------------------------------- spans
-    @contextlib.contextmanager
-    def span(self, name: str, **fields):
+    def span(self, name: str, *, follows: bool = False,
+             **fields) -> "_Span":
         """Time a region: `with rec.span("compile"): ...` emits a `span`
-        event with wall-clock `seconds` on exit. The yielded dict can be
-        mutated to attach result fields. An exception inside the span
+        event on exit with wall-clock `seconds` and the region's two
+        ends `t0` / `t1` on `time.perf_counter()`. The yielded dict can
+        be mutated to attach result fields. An exception inside the span
         emits an `error` event (full traceback) plus the span with
         `ok: false`, then re-raises.
+
+        `follows=True` starts the region where the last region closed on
+        this thread ended, not at the `with` statement: for a loop whose
+        passes are tiled by spans (the serving engine's), so that what
+        lies between two of them — the recorder's own emission of the
+        first, the loop's glue — is counted into the second and no
+        instant of the thread is left unnamed.
 
         Correlation: the region gets a fresh `span_id`, its `parent_id`
         is the enclosing open span on this thread (or the foreign parent
         a `trace()` context seeded), and events emitted INSIDE the
         region — nested spans, errors, page_pool snapshots — parent to
-        it automatically."""
-        stack = self._stack()
+        it automatically.
+
+        Where jax is loaded the region is also a
+        `jax.profiler.TraceAnnotation(name)`: while a profiler trace
+        runs, the program's spans lie in the trace's own host plane
+        beside `PjitFunction(...)`, over the device's ops, with no clock
+        arithmetic."""
+        return _Span(self, name, follows, fields)
+
+
+class _Span:
+    """One `Recorder.span()` region: a plain context manager (no
+    generator frame on the serving loop's six spans a step)."""
+
+    __slots__ = ("_rec", "_name", "_follows", "_fields", "_ids", "_t0",
+                 "_ann")
+
+    def __init__(self, rec: Recorder, name: str, follows: bool,
+                 fields: dict):
+        self._rec, self._name, self._follows = rec, name, follows
+        self._fields = fields
+
+    def __enter__(self) -> dict:
+        rec, fields = self._rec, self._fields
+        stack = rec._stack()
         parent = fields.pop("parent_id", None) or (stack[-1] if stack
                                                    else None)
-        sid = fields.pop("span_id", None) or self.new_span_id()
-        ids = {"span_id": sid}
+        sid = fields.pop("span_id", None) or rec.new_span_id()
+        self._ids = {"span_id": sid}
         if parent is not None:
-            ids["parent_id"] = parent
-        t0 = time.perf_counter()
+            self._ids["parent_id"] = parent
         stack.append(sid)
+        self._ann = _trace_annotation(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        if self._follows:
+            self._t0 = getattr(rec._tloc, "last_t1", self._t0)
+        return fields
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        rec = self._rec
+        rec._tloc.last_t1 = t1
+        if exc is not None:
+            # emitted while the span is still open: the error parents to it
+            rec.error(f"span:{self._name}", exc=exc)
+        rec._stack().pop()
+        rec.event("span", name=self._name, ok=exc is None,
+                  seconds=round(t1 - self._t0, 6), t0=round(self._t0, 6),
+                  t1=round(t1, 6), **self._ids, **self._fields)
+        return False
+
+
+def _trace_annotation(name: str):
+    """`jax.profiler.TraceAnnotation(name)` where jax is already loaded,
+    else None: this module imports no jax, so the no-jax tools and the
+    lint's package stubs pay nothing and miss nothing."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    cls = getattr(profiler, "TraceAnnotation", None)
+    return cls(name) if cls is not None else None
+
+
+def _fan_out(sinks, rec: dict) -> None:
+    for sink in sinks:
         try:
-            yield fields
-        except BaseException as exc:
-            self.error(f"span:{name}", exc=exc)
-            stack.pop()
-            self.event("span", name=name, ok=False,
-                       seconds=round(time.perf_counter() - t0, 6),
-                       **ids, **fields)
-            raise
-        stack.pop()
-        self.event("span", name=name, ok=True,
-                   seconds=round(time.perf_counter() - t0, 6),
-                   **ids, **fields)
+            sink(rec)
+        except Exception:
+            pass  # a broken sink must never break recording
+
+
+class _NullSpan:
+    """What every `NullRecorder.span()` returns, one object for all:
+    entering it costs a dict for the caller's result fields and nothing
+    else (no generator, no clock, no ids)."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> dict:
+        return {}
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+
+_NULL_SPAN = _NullSpan()
 
 
 class NullRecorder(Recorder):
     """Telemetry disabled: every emit is a no-op so hooks threaded
     through hot loops (fused_fit, listeners) cost one attribute lookup.
-    ``span`` still runs the body, recording nothing."""
+    ``span`` still runs the body, recording nothing. One kind still
+    reaches the sinks, `request` — one event a request, none a token or
+    a step — so that a server's `/metrics` counts requests in a default
+    deployment."""
+
+    live = False
 
     def __init__(self):
         super().__init__(path=None, run_id="null", keep=1)
@@ -459,15 +565,27 @@ class NullRecorder(Recorder):
     def event(self, kind: str, /, **fields) -> dict:  # noqa: D102
         return {}
 
+    def request(self, request_id: str, *, ok: bool = True,
+                **fields) -> dict:
+        with self._lock:
+            sinks = list(self._sinks)
+        if not sinks:
+            return {}
+        rec = {"event": "request", "ts": round(time.time(), 3),
+               "run": self.run_id, "id": request_id, "ok": bool(ok),
+               **fields}
+        _fan_out(sinks, rec)
+        return rec
+
     def eval(self, stats, **fields) -> dict:
         return {}  # skip the stats-dict materialization, not just the write
 
     def memory(self, **fields) -> dict:
         return {}  # skip the live-array walk
 
-    @contextlib.contextmanager
-    def span(self, name: str, **fields):
-        yield fields
+    def span(self, name: str, *, follows: bool = False,
+             **fields) -> _NullSpan:
+        return _NULL_SPAN
 
 
 def _jsonable(obj):

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import contextlib
 import queue
+import sys
 import threading
 import time
 import traceback
@@ -693,6 +694,23 @@ class _GenWorker:
     form ({"k","k_scale","v","v_scale"}) through the same three step
     fns — shapes still lattice/page-grid points, ~4x less HBM/slot.
 
+    THE CACHE IS DONATED to every step (`donate_argnums` on the cache
+    argument of the three jits): the scatter of nn/decode._cache_write
+    writes in place into the buffers the step was handed, so a step
+    neither copies the cache nor holds it twice. The worker owns the
+    buffers' lifetime. It rebinds `self.cache` to the step's output as
+    soon as the step is dispatched (the jit call has returned; nothing
+    has been waited for), in warmup() too, so `self.cache` names a
+    consumed tree only inside that call. Another thread (the memory
+    ledger behind /stats) may read the tree's metadata (`nbytes`,
+    shapes) and nothing else; reap(), on the supervisor's thread, fails
+    slots and never touches the tree. Two failure cases (`_fail_step`):
+    a step that failed before it ran (an injected fault, a trace-time
+    error) left the cache intact and fails only its own slots; a step
+    that consumed the cache and then failed costs EVERY occupied slot
+    its rows: all of them fail with their pages released, a fresh cache
+    is allocated, `cache_losses` counts it, and the queue is served on.
+
     THE LOOP IS NAMED WHOLE (telemetry/recorder.py): each pass is an
     `admit` span, then per model step `step_prepare`, the step's own
     span (`prefill_chunk` / `decode_step` / `verify_step`, from just
@@ -728,8 +746,7 @@ class _GenWorker:
         self.slots = DecodeSlots(plan.n_slots)
         self.kv_dtype = plan.kv_dtype
         self.speculative_k = int(speculative_k)
-        self.cache = net.init_kv_cache(plan.n_slots, plan.capacity,
-                                       plan.kv_dtype, plan.page_size)
+        self.cache = self._alloc_cache()
         # guards the stats counters below (worker-thread `+=` vs
         # describe()/stats() reads on the control plane — G025); never
         # held across a jit call or a queue wait, so it orders freely
@@ -738,6 +755,7 @@ class _GenWorker:
         self.trace_count = 0
         self.served = 0
         self.failed = 0
+        self.cache_losses = 0  # consumed caches rebuilt (_fail_step)
         self.tokens_out = 0
         self.decode_steps_run = 0
         self.verify_steps_run = 0
@@ -776,8 +794,10 @@ class _GenWorker:
                                     pos)
             return jnp.argmax(probs, axis=-1).astype(jnp.int32), cache
 
-        self._prefill_jit = jax.jit(counted_prefill)
-        self._decode_jit = jax.jit(counted_step)
+        # argument 2 is the cache: donated, so the steps' scatters write
+        # in place into the buffers they were handed (class docstring)
+        self._prefill_jit = jax.jit(counted_prefill, donate_argnums=2)
+        self._decode_jit = jax.jit(counted_step, donate_argnums=2)
         self._verify_jit = None
         if self.speculative_k >= 2:
             verify_raw = net.verify_decode_fn(plan.kv_dtype,
@@ -794,7 +814,7 @@ class _GenWorker:
                 return (jnp.argmax(probs, axis=-1).astype(jnp.int32),
                         cache)
 
-            self._verify_jit = jax.jit(counted_verify)
+            self._verify_jit = jax.jit(counted_verify, donate_argnums=2)
 
     # ---------------------------------------------------------- planning
     def chunk_buckets(self) -> list:
@@ -825,13 +845,12 @@ class _GenWorker:
             with self.recorder.span("compile", kind="prefill",
                                     bucket=[1, Tb], replica=self.index,
                                     warmup=True):
-                tok, cache = self._prefill_jit(
+                tok, self.cache = self._prefill_jit(
                     ws.params, ws.state, self.cache,
                     np.zeros((1, Tb), np.int32),
                     np.zeros((1, Tb), np.float32), rows, start,
                     np.asarray([Tb - 1], np.int32))
                 np.asarray(tok)  # batch-boundary fetch
-                self.cache = cache
             self._seen_shapes.add(key)
             compiles += 1
             # warmup-time cost harvest: lower() is a jaxpr-cache hit
@@ -852,11 +871,10 @@ class _GenWorker:
                 with self.recorder.span("compile", kind="verify",
                                         shape=[B, K, self.plan.capacity],
                                         replica=self.index, warmup=True):
-                    tok, cache = self._verify_jit(
+                    tok, self.cache = self._verify_jit(
                         ws.params, ws.state, self.cache,
                         np.zeros((B, K), np.int32), scratch)
                     np.asarray(tok)  # batch-boundary fetch
-                    self.cache = cache
                 self._seen_shapes.add("verify")
                 compiles += 1
                 self.costbook.record(
@@ -870,11 +888,10 @@ class _GenWorker:
             with self.recorder.span("compile", kind="decode",
                                     shape=[B, self.plan.capacity],
                                     replica=self.index, warmup=True):
-                tok, cache = self._decode_jit(
+                tok, self.cache = self._decode_jit(
                     ws.params, ws.state, self.cache,
                     np.zeros(B, np.int32), scratch)
                 np.asarray(tok)  # batch-boundary fetch
-                self.cache = cache
             self._seen_shapes.add("decode")
             compiles += 1
             self.costbook.record("decode", [B, self.plan.capacity],
@@ -977,11 +994,11 @@ class _GenWorker:
             key = ("prefill", Tc)
             first = key not in self._seen_shapes
             ws = self.weights.current
-            args = (ws.params, ws.state, self.cache,
-                    padded_tokens, bucket_kmask,
-                    np.asarray([slot_idx], np.int32),
-                    np.asarray([slot.start], np.int32),
-                    np.asarray([n_real - 1], np.int32))
+            handed = self.cache
+            inputs = (padded_tokens, bucket_kmask,
+                      np.asarray([slot_idx], np.int32),
+                      np.asarray([slot.start], np.int32),
+                      np.asarray([n_real - 1], np.int32))
         compiling = (rec.span("compile", kind="prefill", bucket=[1, Tc],
                               replica=self.index)
                      if first else contextlib.nullcontext())
@@ -990,17 +1007,17 @@ class _GenWorker:
                           start=slot.start, replica=self.index,
                           final=final, n_real=n_real), compiling:
                 with rec.span("dispatch"):
-                    tok, cache = self._prefill_jit(*args)
+                    tok, self.cache = self._prefill_jit(
+                        ws.params, ws.state, handed, *inputs)
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # batch-boundary fetch
         except Exception as exc:
-            self._fail_slot(slot_idx, exc, clock)
+            self._fail_step(handed, [slot_idx], exc, clock)
             return
         with rec.span("emit", follows=True, replica=self.index,
                       tokens=int(final)) as sp:
             if first:
                 self._seen_shapes.add(key)
-            self.cache = cache
             slot.start += n_real
             if final:
                 # the prompt's last forward row IS the first generated
@@ -1012,9 +1029,8 @@ class _GenWorker:
                 with self._mu:
                     self.tokens_out += 1
                 self._maybe_complete(slot_idx, clock)
-            # `args` holds the last reference to the cache the chunk read
             t_release = time.perf_counter()
-            del tok, args
+            del tok, handed  # as in the plain decode step
             sp["release_s"] = round(time.perf_counter() - t_release, 6)
 
     def _decode_batch_step(self, active: list, clock) -> None:
@@ -1035,6 +1051,7 @@ class _GenWorker:
                 padded_tokens[i] = slot.last_token
                 pos[i] = slot.pos
             ws = self.weights.current
+            handed = self.cache
             with self._mu:
                 self.decode_steps_run += 1
             self.current_batch = list(active)
@@ -1045,8 +1062,8 @@ class _GenWorker:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
                 with rec.span("dispatch"):
-                    tok, cache = self._decode_jit(
-                        ws.params, ws.state, self.cache,
+                    tok, self.cache = self._decode_jit(
+                        ws.params, ws.state, handed,
                         padded_tokens, pos)
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # batch-boundary fetch
@@ -1063,14 +1080,12 @@ class _GenWorker:
                 self._fail_slot(i, exc, clock)
             raise
         except Exception as exc:
-            for i in active:
-                self._fail_slot(i, exc, clock)
+            self._fail_step(handed, active, exc, clock)
             self.current_batch = None
             return
         with rec.span("emit", follows=True, replica=self.index,
                       tokens=len(active)) as sp:
             self.current_batch = None
-            self.cache = cache
             now = clock()
             for i in active:
                 slot = self.slots.slots[i]
@@ -1083,9 +1098,10 @@ class _GenWorker:
             # dropping the step's device outputs blocks (about a
             # millisecond for the token vector on a v5e: PERF.md section
             # 5), so it happens here, inside a named span and with a
-            # field of its own, not in the frame's teardown after it
+            # field of its own, not in the frame's teardown after it;
+            # the consumed tree's 2 x layers array handles go with it
             t_release = time.perf_counter()
-            del tok
+            del tok, handed
             sp["release_s"] = round(time.perf_counter() - t_release, 6)
 
     def _speculative_batch_step(self, active: list, clock) -> None:
@@ -1118,6 +1134,7 @@ class _GenWorker:
                 pos[i] = slot.pos
             draft_s = time.perf_counter() - t_draft
             ws = self.weights.current
+            handed = self.cache
             with self._mu:
                 self.decode_steps_run += 1
                 self.verify_steps_run += 1
@@ -1130,8 +1147,8 @@ class _GenWorker:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
                 with rec.span("dispatch"):
-                    tok, cache = self._verify_jit(
-                        ws.params, ws.state, self.cache,
+                    tok, self.cache = self._verify_jit(
+                        ws.params, ws.state, handed,
                         padded_windows, pos)
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # [B, k] batch-boundary fetch
@@ -1144,13 +1161,11 @@ class _GenWorker:
                 self._fail_slot(i, exc, clock)
             raise
         except Exception as exc:
-            for i in active:
-                self._fail_slot(i, exc, clock)
+            self._fail_step(handed, active, exc, clock)
             self.current_batch = None
             return
         with rec.span("emit", follows=True, replica=self.index) as sp:
             self.current_batch = None
-            self.cache = cache
             now = clock()
             step_emitted = 0
             step_accepted = 0
@@ -1181,7 +1196,7 @@ class _GenWorker:
                       overhead_us=round(draft_s * 1e6, 2))
             sp["tokens"] = step_emitted
             t_release = time.perf_counter()
-            del tok  # as in the plain decode step
+            del tok, handed  # as in the plain decode step
             sp["release_s"] = round(time.perf_counter() - t_release, 6)
 
     # -------------------------------------------------------- lifecycle
@@ -1202,10 +1217,14 @@ class _GenWorker:
             self.recorder.event("page_pool", replica=self.index,
                                 **self.pool.describe())
 
-    def _fail_slot(self, slot_idx: int, exc: Exception, clock) -> None:
+    def _fail_slot(self, slot_idx: int, exc: Exception, clock,
+                   cache_lost: bool = False) -> None:
         """Mid-decode death containment: the slot's request fails
         loudly, its PAGES ARE RELEASED, and the worker keeps serving —
-        mirror of the predict replica's worker-death contract."""
+        mirror of the predict replica's worker-death contract. Loudly
+        means on the server's own stderr too, whatever the recorder is:
+        with telemetry off `recorder.error` keeps nothing, and the
+        client's copy of the error dies with the client."""
         slot = self.slots.slots[slot_idx]
         req = slot.request
         self.pool.release(self.slots.release(slot_idx))
@@ -1213,10 +1232,51 @@ class _GenWorker:
         self.recorder.error(f"gen-replica:{self.index}", exc=exc)
         err = "".join(traceback.format_exception_only(type(exc),
                                                       exc)).strip()
+        print(f"[gen-replica {self.index}] request {req.request_id} "
+              f"failed in slot {slot_idx} after {len(req.emitted)} tokens "
+              f"(kv cache lost: {cache_lost}): {err}",
+              file=sys.stderr, flush=True)
         req.finish(clock(), error=err)
         with self._mu:
             self.failed += 1
         self._request_event(req, ok=False, error=err)
+
+    def _fail_occupied(self, exc: Exception, clock,
+                       cache_lost: bool = False) -> None:
+        for i, s in enumerate(self.slots.slots):
+            if s is not None:
+                self._fail_slot(i, exc, clock, cache_lost)
+
+    def _alloc_cache(self):
+        return self.net.init_kv_cache(self.plan.n_slots, self.plan.capacity,
+                                      self.plan.kv_dtype,
+                                      self.plan.page_size)
+
+    def _fail_step(self, handed, own: list, exc: Exception,
+                   clock) -> None:
+        """Containment of a failed step, by what became of the cache it
+        was handed. A step that raised before it ran left `handed` live:
+        only its `own` slots fail. One that ran has consumed it: the
+        donated buffers are deleted and the outputs poisoned, so the
+        rows of every occupied slot are gone, those of prefilling slots
+        too. All of them fail (pages released), a fresh cache is
+        allocated as in __init__, one `error` names the loss,
+        `cache_losses` counts it, and the worker serves on."""
+        import jax
+
+        if not any(leaf.is_deleted() for leaf in jax.tree.leaves(handed)):
+            for i in own:
+                self._fail_slot(i, exc, clock)
+            return
+        # drop the failed step's outputs first: beside a fresh cache
+        # they would hold the cache's bytes twice
+        self.cache = None
+        self._fail_occupied(exc, clock, cache_lost=True)
+        self.cache = self._alloc_cache()
+        with self._mu:
+            self.cache_losses += 1
+        self.recorder.error(f"gen-replica:{self.index}", exc=exc,
+                            lost="kv_cache")
 
     def _request_event(self, req: GenRequest, *, ok,
                        error: str | None = None) -> None:
@@ -1304,10 +1364,9 @@ class _GenWorker:
         self.alive = False
         self.lifecycle = "dead"
         self.current_batch = None
-        exc = RuntimeError(f"gen replica {self.index} reaped ({reason})")
-        for i, s in enumerate(self.slots.slots):
-            if s is not None:
-                self._fail_slot(i, exc, clock)
+        self._fail_occupied(
+            RuntimeError(f"gen replica {self.index} reaped ({reason})"),
+            clock)
         return 0
 
     def close(self) -> None:
@@ -1329,6 +1388,7 @@ class _GenWorker:
             out = {"index": self.index, "state": self.lifecycle,
                    "alive": self.alive, "served": self.served,
                    "failed": self.failed,
+                   "cache_losses": self.cache_losses,
                    "decode_steps_run": self.decode_steps_run}
             if self.speculative_k >= 2:
                 out["verify_steps_run"] = self.verify_steps_run

@@ -129,18 +129,12 @@ def replay_http(url: str, trace, *, make_features, time_scale: float = 1.0,
         req = urllib.request.Request(
             f"{url}/predict", data=body,
             headers={"Content-Type": "application/json"})
-        # one retry: a burst can race the ThreadingHTTPServer's accept
-        # backlog on a loaded host — a reset on first contact is the
-        # client environment, not a serving result
-        last = None
-        for _attempt in range(2):
-            try:
-                with urllib.request.urlopen(req, timeout=timeout_s) as resp:
-                    json.loads(resp.read())
-                    return None
-            except Exception as exc:
-                last = exc
-        return f"replay-{i}: {last!r}"
+        try:
+            with urllib.request.urlopen(req, timeout=timeout_s) as resp:
+                json.loads(resp.read())
+                return None
+        except Exception as exc:
+            return f"replay-{i}: {exc!r}"
 
     with concurrent.futures.ThreadPoolExecutor(_CLIENT_WORKERS) as pool:
         results = list(pool.map(one, enumerate(trace)))
@@ -305,25 +299,22 @@ def replay_generate_http(url: str, trace, *, make_prompt,
         req = urllib.request.Request(
             f"{url}/generate", data=body,
             headers={"Content-Type": "application/json"})
-        last = None
-        for _attempt in range(2):
-            try:
-                with urllib.request.urlopen(req, timeout=timeout_s) as resp:
-                    lines = [json.loads(l)
-                             for l in resp.read().splitlines() if l]
-                if not lines or not lines[-1].get("done"):
-                    return f"gen-{i}: stream ended without summary", None
-                if lines[-1].get("error"):
-                    return f"gen-{i}: {lines[-1]['error']}", None
-                return None, [int(t) for t in lines[-1].get("tokens", [])]
-            except urllib.error.HTTPError as exc:
-                # 503 = pool saturated + queue full: the graceful
-                # refusal contract, reported distinctly from transport
-                # errors
-                return f"gen-{i}: HTTP {exc.code}", None
-            except Exception as exc:
-                last = exc
-        return f"gen-{i}: {last!r}", None
+        try:
+            with urllib.request.urlopen(req, timeout=timeout_s) as resp:
+                lines = [json.loads(l)
+                         for l in resp.read().splitlines() if l]
+            if not lines or not lines[-1].get("done"):
+                return f"gen-{i}: stream ended without summary", None
+            if lines[-1].get("error"):
+                return f"gen-{i}: {lines[-1]['error']}", None
+            return None, [int(t) for t in lines[-1].get("tokens", [])]
+        except urllib.error.HTTPError as exc:
+            # 503 = pool saturated + queue full: the graceful
+            # refusal contract, reported distinctly from transport
+            # errors
+            return f"gen-{i}: HTTP {exc.code}", None
+        except Exception as exc:
+            return f"gen-{i}: {exc!r}", None
 
     with concurrent.futures.ThreadPoolExecutor(_CLIENT_WORKERS) as pool:
         results = list(pool.map(one, enumerate(trace)))

@@ -98,6 +98,19 @@ REQUEST_TIMEOUT_S = 60.0
 RETRY_AFTER_S = 5
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    """The listener. socketserver's accept queue holds 5 connections, and
+    one thread accepts: a burst of callers (a closed loop's 32 at the
+    same instant) overflows it, and the kernel then drops the SYNs (the
+    caller's connect comes back a second later) and resets some
+    (`ConnectionResetError` at the client, a failed request the engine
+    never saw). 128 is more than an engine holds (slots + max_queue,
+    68 by default) before it answers 503: the burst waits in the queue
+    for the accept thread, milliseconds."""
+
+    request_queue_size = 128
+
+
 class _Handler(BaseHTTPRequestHandler):
     server_version = "dl4jtpu-serve/1.0"
 
@@ -547,7 +560,7 @@ class ServingServer:
         recorder = getattr(engine, "recorder", None)
         if recorder is not None and hasattr(recorder, "add_sink"):
             recorder.add_sink(self.metrics.on_event)
-        self._httpd = ThreadingHTTPServer((host, port), _Handler)
+        self._httpd = _HTTPServer((host, port), _Handler)
         self._httpd.serving_server = self
         self._thread: Optional[threading.Thread] = None
 

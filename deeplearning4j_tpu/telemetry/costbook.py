@@ -8,7 +8,7 @@ has populated the jit cache, `jitted.lower(*args)` is a jaxpr-cache
 hit — it does NOT re-trace, so the trace counters the zero-retrace
 gates freeze stay frozen. Flops and bytes-accessed come straight off
 the lowered program (`Lowered.cost_analysis()`, no backend compile);
-`memory_analysis()` (peak temp, argument/output/code bytes) needs the
+`memory_analysis()` (peak temp, argument/output/alias/code bytes) needs the
 AOT executable, so `.compile()` runs once per UNIQUE lowered program
 per process (fingerprint cache — re-warmed replicas and respawns hit
 it), paid entirely at warmup; ZERO hot-path cost, by construction.
@@ -145,6 +145,9 @@ def harvest(jitted, *args, **kwargs) -> dict:
         for attr, key in (("temp_size_in_bytes", "peak_temp_bytes"),
                           ("argument_size_in_bytes", "argument_bytes"),
                           ("output_size_in_bytes", "output_bytes"),
+                          # argument bytes the outputs reuse in place:
+                          # what buffer donation bought (0 without it)
+                          ("alias_size_in_bytes", "alias_bytes"),
                           ("generated_code_size_in_bytes",
                            "generated_code_bytes")):
             val = getattr(ma, attr, None)

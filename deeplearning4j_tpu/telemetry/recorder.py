@@ -36,7 +36,7 @@ Event schema — one JSON object per line, every event carrying
 | `weight_swap` | one live hot-swap attempt (serving/fleet.hot_swap): `ok`, `step` (the checkpoint step restored), `restore_ms` (shadow-net restore + validation, all OFF the request path), `generation` (the WeightStore generation after a flip / still serving after a rejection), `error` on rejection — paired with the `weight_gen` field every serving `request` event carries, the flip's visibility in the traffic record |
 | `autoscale` | one fleet-supervisor autoscale tick (serving/fleet.FleetSupervisor): `n_serving`, `n_replicas`, `queue_depth`, `p99_ms` (the decision inputs), `action` (+1 grew / -1 drained / 0), `max_replicas` — the occupancy bench row's only source; replica self-healing rides `fault` events (`replica-kill`/`replica-hang` when an injected fault fires, `replica-dead` with the requeued count when the supervisor reaps, `replica-respawn` with `respawn_ms` on re-admission) |
 | `anomaly` | one detector finding (telemetry/trace.py) put on the record by whoever ran the detector — the elastic supervisor's straggler watch, `tracetool check`, or the bench sweep: `kind` ("straggler" / "retrace" / "input_wait_spike" / "queue_spike" / "leak" / "headroom" / "cost_drift"), `process`, and the kind's evidence fields (`step`+`skew_ms` for stragglers, the offending span's name/seconds for retraces and spikes, byte counts + growth/ratio fields for the memory kinds) |
-| `cost` | one compiled executable's cost-book entry (telemetry/costbook.py), harvested at warmup/compile time from XLA's own `cost_analysis()` / `memory_analysis()` — NEVER on the hot path (it rides the existing `compile` spans): `entry` (the jit wrapper's name: "forward", "prefill", "decode", "verify", "fit_scanned", ...), `shape` (the warmed shape key), `flops`, `bytes_accessed`, `peak_temp_bytes`, `argument_bytes`, `output_bytes`, `generated_code_bytes` — the denominators behind the MFU gauge and the capacity planner's measured-cost side |
+| `cost` | one compiled executable's cost-book entry (telemetry/costbook.py), harvested at warmup/compile time from XLA's own `cost_analysis()` / `memory_analysis()` — NEVER on the hot path (it rides the existing `compile` spans): `entry` (the jit wrapper's name: "forward", "prefill", "decode", "verify", "fit_scanned", ...), `shape` (the warmed shape key), `flops`, `bytes_accessed`, `peak_temp_bytes`, `argument_bytes`, `output_bytes`, `alias_bytes` (argument bytes the outputs reuse in place: at least the KV cache's bytes for the serving steps, which donate it; 0 for an executable that donates nothing), `generated_code_bytes` — the denominators behind the MFU gauge and the capacity planner's measured-cost side |
 | `cost_drift` | one predicted-vs-measured reconciliation of the placement cost model (reshard/search.py `winner_memory_bytes` vs a measured per-device peak from later `memory`/`cost` events): `predicted_bytes`, `measured_bytes`, `ratio` (measured/predicted), `factor` (the documented tolerance band — outside [1/factor, factor] is an anomaly), `source` — emitted once after the first real step, the calibration loop closing over the search's exact-rational predictions |
 
 **Correlation fields** (the fleet-timeline contract, tools/tracetool.py):

@@ -806,7 +806,8 @@ def memory_report(timeline: Timeline) -> dict:
         key = f"{ev.get('entry', '?')}::{ev.get('shape')}"
         book[key] = {k: ev[k] for k in
                      ("flops", "bytes_accessed", "peak_temp_bytes",
-                      "argument_bytes", "output_bytes") if k in ev}
+                      "argument_bytes", "output_bytes", "alias_bytes")
+                     if k in ev}
     drifts = [{k: ev.get(k) for k in
                ("process", "predicted_bytes", "measured_bytes",
                 "ratio", "factor", "source")}

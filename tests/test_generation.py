@@ -6,10 +6,16 @@ mixed prompt/output-length replay, decode-step cost independent of
 prompt length (telemetry span timings), and the generation scoreboard
 reconstruction behind tools/trafficreplay.py --generate."""
 
+import http.client
 import json
+import socket
+import threading
+import time
+import types
 import urllib.error
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -22,6 +28,7 @@ from deeplearning4j_tpu.serving.kvcache import (CachePlan, PagePool,
                                                 pages_for, quantize)
 from deeplearning4j_tpu.serving.server import ServingServer
 from deeplearning4j_tpu.telemetry import Recorder
+from deeplearning4j_tpu.telemetry.memstat import tree_bytes
 
 pytestmark = pytest.mark.serving
 
@@ -415,6 +422,283 @@ def test_benchdiff_inverts_generation_rows(tmp_path):
                          "serving_generate_page_occupancy"}
 
 
+# ------------------------------------------------- the donated KV cache
+
+_MIXED = ((3, 2), (8, 5), (11, 1), (16, 8), (5, 3), (1, 4), (13, 2),
+          (16, 1), (2, 6), (7, 8))
+
+
+def _prompt_dependent_lm(max_seq):
+    """The tiny LM with its weights scaled up: the untrained net answers
+    every prompt with one token, scaled its greedy stream depends on the
+    prompt, so a wrong cache row shows in the ids."""
+    net = replay._tiny_lm(max_seq)
+    net.params = jax.tree.map(lambda x: x * 8.0, net.params)
+    return net
+
+
+def _gen_engine(net, rec, **kw):
+    kw = {"slots": 2, "max_new_tokens": 8, "page_size": 8, **kw}
+    return GenerationEngine(
+        net, BucketLattice(batch_sizes=(1,), seq_lens=(8, 16)),
+        recorder=rec, **kw)
+
+
+def _consumed(tree):
+    return any(leaf.is_deleted() for leaf in jax.tree.leaves(tree))
+
+
+def _cost_events(rec):
+    return [e for e in rec.events if e.get("event") == "cost"]
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+def test_warmed_steps_alias_the_whole_cache(kv_dtype):
+    """The counter that says donation engaged: every warmed step's
+    `cost` event carries `alias_bytes` (XLA's own count of argument bytes
+    the outputs reuse in place), and it reads the cache tree's bytes —
+    all four leaves a layer in the int8 form."""
+    rec = Recorder(path=None)
+    engine = _gen_engine(replay._tiny_lm(24), rec, kv_dtype=kv_dtype)
+    assert engine.warmup() == 3
+    nbytes = tree_bytes(engine.fleet_workers()[0].cache)
+    costs = _cost_events(rec)
+    assert sorted(e["entry"] for e in costs) == ["decode", "prefill",
+                                                 "prefill"]
+    for e in costs:
+        assert e["alias_bytes"] == nbytes > 0, e
+
+
+def test_cache_is_rebound_at_dispatch_and_readers_see_it_whole():
+    """The ownership rule. On the engine thread: when a step's
+    `dispatch` span closes (the jit call has returned, nothing fetched
+    yet) `worker.cache` is the live output and the tree that step was
+    handed is consumed. From another thread, while the loop runs:
+    stats() / describe() never fail, the ledger reads the whole cache's
+    bytes every time, and a consumed tree is bound only inside the jit
+    call — it is replaced before a reader can see it twice."""
+    rec = Recorder(path=None)
+    engine = _gen_engine(replay._tiny_lm(24), rec)
+    engine.warmup()
+    worker = engine.fleet_workers()[0]
+    nbytes = tree_bytes(worker.cache)
+    wrong = []  # a sink's exception is swallowed: collect, assert below
+    seen = {"handed": worker.cache, "steps": 0}
+
+    def at_dispatch(ev):
+        if ev.get("event") != "span" or ev.get("name") != "dispatch":
+            return
+        if _consumed(worker.cache):
+            wrong.append("a consumed cache is bound after dispatch")
+        if worker.cache is seen["handed"] or not _consumed(seen["handed"]):
+            wrong.append("the tree the step was handed was not consumed")
+        seen["handed"] = worker.cache
+        seen["steps"] += 1
+
+    rec.add_sink(at_dispatch)
+    stop = threading.Event()
+    polls = []
+
+    def reader():
+        while not stop.is_set():
+            tree = worker.cache
+            engine.stats()
+            worker.describe()
+            polls.append(engine.memsampler.ledger.attributed()["kv_pages"])
+            if _consumed(tree):
+                deadline = time.monotonic() + 10.0
+                while worker.cache is tree and time.monotonic() < deadline:
+                    time.sleep(0)
+                if worker.cache is tree:
+                    wrong.append("a consumed cache stayed bound")
+
+    thread = threading.Thread(target=reader, daemon=True)
+    engine.start()
+    thread.start()
+    rng = np.random.default_rng(11)
+    tokens = 0
+    for plen, olen in _MIXED:
+        out = engine.generate(rng.integers(0, 64, plen).astype(np.int32),
+                              olen, timeout=60)
+        tokens += len(out)
+    stop.set()
+    thread.join(30)
+    assert not thread.is_alive()
+    engine.drain()
+    assert wrong == []
+    # one prefill chunk a request, then one decode step a further token
+    assert seen["steps"] == tokens
+    assert polls and set(polls) == {nbytes}
+    assert not _consumed(worker.cache)
+
+
+def test_donated_replay_is_bit_identical_to_undonated_jits():
+    """Donation changes where the scatter writes, not what: the mixed
+    replay through the donating worker emits the ids of the same replay
+    through un-donated jits of the very same step functions, and neither
+    retraces after warmup."""
+    net = _prompt_dependent_lm(24)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 64, plen).astype(np.int32)
+               for plen, _ in _MIXED]
+
+    def replay_through(donate):
+        rec = Recorder(path=None)
+        engine = _gen_engine(net, rec)
+        worker = engine.fleet_workers()[0]
+        if not donate:
+            worker._prefill_jit = jax.jit(worker._prefill_jit.__wrapped__)
+            worker._decode_jit = jax.jit(worker._decode_jit.__wrapped__)
+        assert engine.warmup() == 3
+        # all queued before the loop starts: slots refill while others
+        # decode, so prefill chunks and decode steps interleave
+        reqs = [engine.submit_generate(p, olen)
+                for p, (_, olen) in zip(prompts, _MIXED)]
+        engine.start()
+        for req in reqs:
+            assert req.wait(60) and req.error is None
+        assert engine.trace_count == 3, "a step retraced after warmup"
+        engine.drain()
+        return ([list(r.emitted) for r in reqs],
+                [e["alias_bytes"] for e in _cost_events(rec)])
+
+    donated, aliased = replay_through(True)
+    plain, unaliased = replay_through(False)
+    assert donated == plain
+    assert len({tuple(out) for out in donated}) > 3, \
+        "the replay's streams do not depend on the prompt"
+    assert [len(out) for out in donated] == [olen for _, olen in _MIXED]
+    assert min(aliased) > 0 and unaliased == [0, 0, 0]
+
+
+def _two_chunk_engine(net, rec, **kw):
+    """slots=2 over 8-token prefill chunks: a 16-token prompt holds its
+    slot through two chunks, with decode steps between them."""
+    engine = _gen_engine(net, rec, prefill_chunk=8, **kw)
+    engine.warmup()
+    worker = engine.fleet_workers()[0]
+    allocs = []
+    alloc = worker._alloc_cache
+    worker._alloc_cache = lambda: allocs.append(1) or alloc()
+    return engine, worker, allocs
+
+
+@pytest.mark.parametrize("speculative_k", [0, 2])
+def test_step_that_consumed_the_cache_fails_every_slot_and_serves_on(
+        speculative_k):
+    """A decode (or verify) step whose execution raises after the cache
+    was donated has lost every slot's rows: the decoding slot AND the
+    slot still in prefill fail with their pages released, one `error`
+    names the loss, a fresh cache of the same shapes takes over without
+    a retrace, and the request that was waiting in the queue is served
+    correctly."""
+    net = _prompt_dependent_lm(24)
+    rec = Recorder(path=None)
+    engine, worker, allocs = _two_chunk_engine(
+        net, rec, speculative_k=speculative_k)
+    shapes = jax.tree.map(lambda x: (x.shape, x.dtype), worker.cache)
+    warm = engine.trace_count
+    step = "_verify_jit" if speculative_k else "_decode_jit"
+    real, calls = getattr(worker, step), []
+
+    def consume_then_raise(params, state, cache, *inputs):
+        calls.append(1)
+        out = real(params, state, cache, *inputs)
+        if len(calls) == 1:
+            raise RuntimeError("device fault after the cache was donated")
+        return out
+
+    setattr(worker, step, consume_then_raise)
+    rng = np.random.default_rng(5)
+    decoding = engine.submit_generate(rng.integers(0, 64, 5), 6)
+    prefilling = engine.submit_generate(rng.integers(0, 64, 16), 4)
+    prompt = rng.integers(0, 64, 7).astype(np.int32)
+    queued = engine.submit_generate(prompt, 5)  # no slot left: it waits
+    engine.start()
+    for req in (decoding, prefilling):
+        assert req.wait(60)
+        assert req.error is not None and "device fault" in req.error
+    assert len(decoding.emitted) == 1 and prefilling.emitted == []
+    assert queued.wait(60) and queued.error is None
+    assert list(queued.emitted) == _greedy_full_forward(net, prompt, 5)
+    engine.drain()
+    assert allocs == [1]
+    assert not _consumed(worker.cache)
+    assert jax.tree.map(lambda x: (x.shape, x.dtype), worker.cache) == shapes
+    assert engine.trace_count == warm, "the fresh cache retraced a step"
+    assert worker.pool.describe()["pages_in_use"] == 0
+    assert (engine.failed, engine.served) == (2, 1)
+    assert worker.describe()["cache_losses"] == 1
+    lost = [e for e in rec.events if e.get("event") == "error"
+            and e.get("lost") == "kv_cache"]
+    assert len(lost) == 1 and "device fault" in lost[0]["error"]
+
+
+def test_fault_before_the_call_fails_only_its_slots_and_keeps_the_cache():
+    """An injected fault fires before the jit call: nothing was donated,
+    so containment stays narrow. The decoding slot fails; the slot whose
+    first prompt chunk was already in the cache finishes with the right
+    tokens (its rows survived), and no cache is allocated."""
+    net = _prompt_dependent_lm(24)
+    rec = Recorder(path=None)
+    engine, worker, allocs = _two_chunk_engine(net, rec)
+    handed = []
+
+    def check(index, unit, count):
+        if unit == "decode" and count == 2:
+            handed.append(worker.cache)
+            raise RuntimeError("injected before the call")
+
+    worker.faults = types.SimpleNamespace(check=check)
+    rng = np.random.default_rng(5)
+    decoding = engine.submit_generate(rng.integers(0, 64, 5), 6)
+    prompt = rng.integers(0, 64, 16).astype(np.int32)
+    prefilling = engine.submit_generate(prompt, 4)
+    engine.start()
+    assert decoding.wait(60) and "injected before" in decoding.error
+    assert len(decoding.emitted) == 2  # its prefill's and step 1's
+    assert prefilling.wait(60) and prefilling.error is None
+    assert list(prefilling.emitted) == _greedy_full_forward(net, prompt, 4)
+    engine.drain()
+    assert allocs == [] and len(handed) == 1
+    assert (engine.failed, engine.served) == (1, 1)
+    assert worker.pool.describe()["pages_in_use"] == 0
+    assert worker.describe()["cache_losses"] == 0
+    assert not [e for e in rec.events if e.get("lost") == "kv_cache"]
+
+
+@pytest.mark.parametrize("consumed", [False, True])
+def test_failed_slot_says_why_on_stderr_with_telemetry_off(consumed, capfd):
+    """An untraced server keeps no `error` event (NullRecorder) and the
+    client's copy of the error dies with the client: the failed slot's
+    one line on the server's own stderr is all that is left. It names
+    the request, the slot, the replica, the exception and whether the
+    cache went with it."""
+    engine = _gen_engine(replay._tiny_lm(24), None)
+    assert not engine.recorder.live
+    engine.warmup()
+    worker = engine.fleet_workers()[0]
+    real = worker._decode_jit
+
+    def fail(params, state, cache, *inputs):
+        if consumed:
+            real(params, state, cache, *inputs)
+        raise RuntimeError("no such device")
+
+    worker._decode_jit = fail
+    req = engine.submit_generate(np.arange(5, dtype=np.int32), 4,
+                                 request_id="r7.1")
+    engine.start()
+    assert req.wait(60) and "no such device" in req.error
+    engine.drain()
+    lines = [l for l in capfd.readouterr().err.splitlines()
+             if l.startswith("[gen-replica 0]")]
+    assert lines == [
+        "[gen-replica 0] request r7.1 failed in slot 0 after 1 tokens "
+        f"(kv cache lost: {consumed}): RuntimeError: no such device"]
+    assert worker.describe()["cache_losses"] == int(consumed)
+
+
 # ------------------------------------------------------- HTTP round trip
 
 @pytest.fixture(scope="module")
@@ -458,6 +742,90 @@ def test_generate_http_rejects_oversized_and_post_drain(gen_stack):
     with pytest.raises(urllib.error.HTTPError) as e:
         urllib.request.urlopen(req, timeout=30)
     assert e.value.code == 400
+
+
+def test_listener_holds_a_burst_of_callers_before_anyone_accepts():
+    """The accept queue: 32 callers that connect at the same instant (a
+    closed loop's first moment) all complete their handshake while the
+    accept thread has not run at all. At socketserver's default of 5 the
+    kernel drops the seventh SYN (that connect times out here) and
+    resets some: the front door failed requests the engine never saw."""
+    engine = _gen_engine(replay._tiny_lm(24), None)
+    server = ServingServer(engine, port=0)  # bound and listening, not started
+    host, port = server._httpd.server_address[:2]
+    conns = []
+    try:
+        for _ in range(32):
+            conns.append(socket.create_connection((host, port), timeout=0.5))
+    finally:
+        for c in conns:
+            c.close()
+        server._httpd.server_close()
+    assert len(conns) == 32
+
+
+def test_closed_loop_soak_over_http_fails_no_request():
+    """Twice as many callers as slots over real HTTP, all starting at
+    the same instant, each sending its next request the moment the last
+    completes (a failed one too, as the benchmark's load generator
+    does), for three seconds: slots turn over under a full queue, every
+    step donates the cache, and no request fails, at the front door or
+    in the engine. Each stream is the greedy decode of its prompt."""
+    net = _prompt_dependent_lm(24)
+    engine = _gen_engine(net, None, slots=4)
+    engine.warmup()
+    server = ServingServer(engine, port=0).start()
+    host, port = server._httpd.server_address[:2]
+    rng = np.random.default_rng(29)
+    work = [(rng.integers(0, 64, int(rng.integers(2, 17))).tolist(),
+             int(rng.integers(2, 9))) for _ in range(8)]
+    want = [_greedy_full_forward(net, p, n) for p, n in work]
+    n_callers, seconds = 8, 3.0
+    gate = threading.Barrier(n_callers)
+    lock = threading.Lock()
+    sent, failures = [0], []
+
+    def caller(k):
+        gate.wait(30)
+        t_end = time.monotonic() + seconds
+        while time.monotonic() < t_end:
+            prompt, n = work[k % len(work)]
+            status = got = None
+            try:
+                conn = http.client.HTTPConnection(host, port, timeout=30)
+                conn.request("POST", "/generate", body=json.dumps(
+                    {"tokens": prompt, "max_new_tokens": n}),
+                    headers={"Content-Type": "application/json"})
+                resp = conn.getresponse()
+                status = resp.status
+                got = [json.loads(l) for l in resp.read().splitlines()][-1]
+                conn.close()
+            except Exception as exc:  # a reset connection is a failure
+                got = f"{type(exc).__name__}: {exc}"
+            with lock:
+                sent[0] += 1
+                if not (isinstance(got, dict) and status == 200
+                        and got.get("error") is None
+                        and got.get("tokens") == want[k % len(work)]):
+                    failures.append((k, status, got))
+            k += 1
+
+    threads = [threading.Thread(target=caller, args=(k,), daemon=True)
+               for k in range(n_callers)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(seconds + 60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        server.stop()
+    assert failures == []
+    assert sent[0] >= 4 * n_callers
+    worker = engine.fleet_workers()[0]
+    assert (engine.failed, worker.describe()["cache_losses"]) == (0, 0)
+    assert engine.served == sent[0]
+    assert engine.trace_count == 3 and not _consumed(worker.cache)
 
 
 def test_end_to_end_generation_replay_artifact(tmp_path):

@@ -123,6 +123,23 @@ def _run_engine(net, k, kv_dtype):
     return outs, stats, rec
 
 
+def test_verify_step_aliases_the_whole_cache():
+    """The verify step donates its cache like the decode step it stands
+    in for: its warmed `cost` event's `alias_bytes` reads the tree's bytes."""
+    from deeplearning4j_tpu.telemetry.memstat import tree_bytes
+
+    rec = Recorder(path=None)
+    eng = GenerationEngine(
+        replay._tiny_lm(24),
+        BucketLattice(batch_sizes=(1,), seq_lens=(8, 16)), slots=2,
+        max_new_tokens=8, page_size=8, recorder=rec, speculative_k=2)
+    eng.warmup()
+    nbytes = tree_bytes(eng.fleet_workers()[0].cache)
+    costs = {e["entry"]: e for e in rec.events if e.get("event") == "cost"}
+    assert sorted(costs) == ["prefill", "verify"]  # no decode step warmed
+    assert costs["verify"]["alias_bytes"] == nbytes > 0
+
+
 def test_greedy_speculative_bit_identity():
     """The arc's headline gate: speculative greedy emits a token stream
     bit-identical to plain greedy decode — acceptance is a mask over

@@ -475,7 +475,8 @@ def test_memory_report_and_metric_rows(tmp_path):
         fh.write(json.dumps(
             {"event": "cost", "run": "runL", "seq": 99, "ts": 2000.0,
              "entry": "forward", "shape": [4, 16], "flops": 1e9,
-             "bytes_accessed": 2e6, "peak_temp_bytes": 4096}) + "\n")
+             "bytes_accessed": 2e6, "peak_temp_bytes": 4096,
+             "alias_bytes": 512}) + "\n")
     tl = trace_mod.load_timeline(base)
     report = trace_mod.memory_report(tl)
     proc = report["processes"]["main"]
@@ -483,6 +484,8 @@ def test_memory_report_and_metric_rows(tmp_path):
     assert proc["peak_bytes"] == 30 << 20  # the warmup spike
     assert proc["ledger"]["params"] > 0
     assert report["cost_book"]["forward::[4, 16]"]["flops"] == 1e9
+    # what donation bought rides the summary (0 where nothing is donated)
+    assert report["cost_book"]["forward::[4, 16]"]["alias_bytes"] == 512
     findings = trace_mod.detect_anomalies(tl)
     lines = trace_mod.metric_lines(tl, findings)
     by_name = {l["metric"]: l for l in lines}

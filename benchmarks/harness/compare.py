@@ -69,16 +69,13 @@ def norm_gap(prog_sq: dict, ref_sq: dict, keep=None) -> tuple:
     return float(gap[i]), names[i]
 
 
-def projection_gap(prog_proj: dict, ref_proj: dict, ref_sq: dict) -> tuple:
+def projection_gap(prog_proj: dict, ref_proj: dict, proj_sq: dict) -> tuple:
     """(root mean square gap, the worst leaf): |<g_prog, r> - <g_ref, r>|
     of a leaf against the larger of the reference's norm of that leaf's
-    gradient and of the median leaf's. The fused Wqkv / bqkv are projected
-    whole."""
-    whole = dict(ref_sq)
-    for w in ("W", "b"):
-        whole[f"blocks.{w}qkv"] = sum(
-            np.asarray(whole.pop(f"blocks.{w}{t}"), np.float64) for t in "qkv")
-    names, r_norm = _flat(whole)
+    gradient and of the median leaf's. `proj_sq`: the reference gradient's
+    squared norms under the projections' names (a leaf that is compared
+    in parts and projected whole is folded by the family)."""
+    names, r_norm = _flat(proj_sq)
     names_p, p = _flat_raw(prog_proj)
     names_r, r = _flat_raw(ref_proj)
     if not (names_p == names_r == ["proj." + n for n in names]):
@@ -98,8 +95,10 @@ def moving_leaves(ref_grad_sq: dict) -> np.ndarray:
 
 
 def train_checks(prog: dict, ref: dict, limits: dict) -> dict:
-    """prog / ref: {"losses": [..], "grad_sq": {...}, "change_sq": {...}}.
-    -> {name: [value, limit]} in the order they are printed."""
+    """prog / ref: {"losses": [..], "grad_sq": {...}, "grad_proj": {...},
+    "change_sq": {...}}, and ref also "proj_sq" (a family's
+    `reference_readings` gives all five; the names of the leaves are the
+    family's). -> {name: [value, limit]} in the order they are printed."""
     out = {}
     # the program returns its loss in bfloat16 (10.875 +- 0.03 here): the
     # gap says nothing, has no upper reading and is not compared (PERF.md)
@@ -109,7 +108,7 @@ def train_checks(prog: dict, ref: dict, limits: dict) -> dict:
     g, g_leaf = norm_gap(prog["grad_sq"], ref["grad_sq"])
     out["grad_norm"] = [g, limits["grad_norm"]]
     gp, gp_leaf = projection_gap(prog["grad_proj"], ref["grad_proj"],
-                                 ref["grad_sq"])
+                                 ref["proj_sq"])
     out["grad_proj"] = [gp, limits["grad_proj"]]
     c, c_leaf = norm_gap(prog["change_sq"], ref["change_sq"],
                          keep=moving_leaves(ref["grad_sq"]))

@@ -7,7 +7,8 @@ JAX, is started before the window and reaped after it. Once the window
 has closed and the peak memory is read, the server is stopped and freed,
 and the plain reference runs once over a seeded sample of the requests
 the window finished (the longest among them) with the tokens they were
-served.
+served. Which model is served, its seeded weights and its reference are the
+configuration's family's (`spec.family_of`).
 """
 
 from __future__ import annotations
@@ -21,51 +22,12 @@ import time
 
 import numpy as np
 
-from harness import device, traffic, weights
+from harness import device, spec, traffic
 from harness.spec import BENCH_DIR
 
-PAD = 256               # reference sequence lengths are multiples of this
 # what the client's clock gives; BENCHMARK.json names the ones a cell reports
 CLIENT_METRICS = ("serve_tokens_per_s", "tpot_ms_p95", "request_ms_mean",
                   "ttft_ms_p90")
-
-
-def param_shapes(net):
-    """The parameter tree `net.init()` would build, as shapes: serving
-    needs no optimizer, and init() would allocate Adam's two moments
-    (11 GB at 1.4 B parameters) beside the weights."""
-    import jax
-
-    def build(key):
-        out = {}
-        for name in sorted(net.layer_vertices):
-            out[name] = net.impls[name].init(
-                net.layer_vertices[name].layer, key, net.param_dtype)
-        return out
-
-    both = jax.eval_shape(build, jax.random.PRNGKey(0))
-    for name, (_p, s) in both.items():
-        if s:
-            raise ValueError(f"layer {name} keeps state; the benchmark's "
-                             f"weights know only stateless layers")
-    return {n: p for n, (p, _s) in both.items()}
-
-
-def build_net(config: dict, seed: int, dims: dict):
-    import jax
-
-    from deeplearning4j_tpu.models.transformer import transformer_lm
-
-    net = transformer_lm(
-        vocab_size=config["vocab_size"], d_model=config["n_embd"],
-        n_heads=config["n_head"], n_layers=config["n_layer"],
-        d_ff=config["n_inner"], max_length=config["n_positions"],
-        seed=int(seed) & 0x7FFFFFFF, dtype=config["compute_dtype"])
-    like = param_shapes(net)
-    net.params = jax.jit(lambda k: weights.fit_program_tree(
-        weights.program_params(k, dims), like))(weights.seed_key(seed))
-    net.state = {n: {} for n in like}
-    return net
 
 
 class Served:
@@ -76,20 +38,20 @@ class Served:
         from deeplearning4j_tpu.serving.engine import GenerationEngine
         from deeplearning4j_tpu.serving.server import ServingServer
 
-        self.dims = weights.dims_of(config)
-        dep = config["deployment"]
+        self.family = spec.family_of(config)
+        self.dims = self.family.dims_of(config)
+        # every key of `deployment` but the prefill buckets is an argument
+        # of GenerationEngine by that name
+        dep = dict(config["deployment"])
+        seq_lens = dep.pop("prefill_seq_lens")
         t = time.perf_counter()
-        self.net = build_net(config, seed, self.dims)
+        self.net = self.family.serving_net(config, seed, self.dims)
         import jax
 
         jax.block_until_ready(self.net.params)
         log(f"weights on the device in {time.perf_counter() - t:.1f} s")
         self.engine = GenerationEngine(
-            self.net, BucketLattice(batch_sizes=[1],
-                                    seq_lens=dep["prefill_seq_lens"]),
-            slots=dep["slots"], max_new_tokens=dep["max_new_tokens"],
-            page_size=dep["page_size"], kv_dtype=dep["kv_dtype"],
-            replicas=dep["replicas"], max_queue=dep["max_queue"])
+            self.net, BucketLattice(batch_sizes=[1], seq_lens=seq_lens), **dep)
         t = time.perf_counter()
         n = self.engine.warmup()
         log(f"{n} serving programs warm in {time.perf_counter() - t:.1f} s")
@@ -226,45 +188,6 @@ def prompts_of(mix: dict, seed: int, vocab: int, seconds: float) -> dict:
             for r in traffic.make_requests(mix, seed, vocab, seconds)}
 
 
-def reference_gaps(sample, prompts, seed, dims, lowprec=False):
-    """For each sampled request, the gap by which each served token's
-    reference logit lies below the reference's best, as one array per
-    request — or, for the control (`lowprec`), the gap of the token the
-    float8 reference puts first at each of the same positions. One jitted
-    program per padded length."""
-    import jax
-    import jax.numpy as jnp
-
-    from reference import gpt2_block as ref
-
-    W = jax.jit(lambda k: weights.reference_params(k, dims))(
-        weights.seed_key(seed))
-    served_fn = jax.jit(lambda W, t, at, s, v: ref.served_gap(W, t, at, s, v, dims))
-    low_fn = jax.jit(lambda W, t, at, v: ref.lowprec_gap(W, t, at, v, dims))
-    out = []
-    for r in sample:
-        prompt = prompts[r["id"].split(".")[0]]
-        served = list(r["tokens"])
-        L, n = len(prompt), len(served)
-        T = -(-(L + n) // PAD) * PAD
-        seq = np.zeros(T, np.int32)
-        seq[:L] = prompt
-        seq[L:L + n - 1] = served[:-1]
-        at = np.zeros(PAD, np.int32)
-        at[:n] = np.arange(L - 1, L - 1 + n)
-        valid = np.arange(PAD) < n
-        tok = np.zeros(PAD, np.int32)
-        tok[:n] = served
-        if lowprec:
-            g = low_fn(W, jnp.asarray(seq), jnp.asarray(at), jnp.asarray(valid))
-        else:
-            g = served_fn(W, jnp.asarray(seq), jnp.asarray(at),
-                          jnp.asarray(tok), jnp.asarray(valid))
-        out.append(np.asarray(g, np.float64)[:n])
-    del W
-    return out
-
-
 def gap_readings(gaps) -> dict:
     """The two numbers compared, from the sampled requests' per-token
     gaps: the widest (one altered token shows in it) and the mean over all
@@ -293,7 +216,7 @@ def serve_checks(sample, gaps, compiles, limits) -> dict:
 def run(ctx) -> dict:
     config, mix, seed = ctx.config, ctx.traffic, ctx.seed
     served = Served(config, seed, ctx.log)
-    dims = served.dims
+    family, dims = served.family, served.dims
     served.warm_request(dims["V"])
     trace = ctx.start_trace()
     marks = {}
@@ -323,7 +246,7 @@ def run(ctx) -> dict:
                          int(ctx.limits["min_sample_tokens"]))
     prompts = prompts_of(mix, seed, dims["V"], ctx.seconds)
     t = time.perf_counter()
-    gaps = reference_gaps(sample, prompts, seed, dims)
+    gaps = family.served_gaps(sample, prompts, seed, dims)
     ctx.log(f"reference over {len(sample)} requests, "
             f"{sum(len(r['tokens']) for r in sample)} served tokens, in "
             f"{time.perf_counter() - t:.1f} s; widest gaps "

@@ -3,7 +3,7 @@ the server process's clock, and the traced part of the window."""
 
 from __future__ import annotations
 
-from harness import flops, xplane
+from harness import spec, xplane
 
 DECODE_MODULE = r"counted_step"
 
@@ -42,10 +42,11 @@ def decode_steps_traced(facts):
 
 def work_flops(facts, a, b) -> float:
     """Model FLOPs of the prompt and generated tokens whose token came in
-    [a, b]: a request's first token pays its whole prefill."""
-    dims = facts["dims"]
+    [a, b], by the counts of the configuration's family: a request's
+    first token pays its whole prefill."""
+    family, dims = spec.family_of(facts["config"]), facts["dims"]
     total = 0.0
     for ctx, first, r in token_events(facts, a, b):
-        total += (flops.prefill_flops(dims, r["prompt_len"]) if first
-                  else flops.decode_flops(dims, ctx))
+        total += (family.prefill_flops(dims, r["prompt_len"]) if first
+                  else family.decode_flops(dims, ctx))
     return total

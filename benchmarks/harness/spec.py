@@ -1,15 +1,19 @@
 """BENCHMARK.json and the files it names: cells, configurations, traffic
-mixes and per-layer metric readers are all found by name, so a later PR
-adds a cell by adding files and an entry, never by editing these."""
+mixes, per-layer metric readers and model families are all found by name,
+so a later PR adds a cell, or a model of another architecture, by adding
+files and an entry, never by editing these."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+# where family modules are looked for, in this order (a test adds its own)
+FAMILY_DIRS = [os.path.join(BENCH_DIR, "families")]
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -49,6 +53,32 @@ def metrics_for(bench: dict, group: str, cell_name: str) -> list:
     with no `workloads` key, or with the cell in it."""
     return [m for m in bench[group]
             if "workloads" not in m or cell_name in m["workloads"]]
+
+
+@functools.lru_cache(maxsize=None)
+def _module_at(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family_of(config: dict):
+    """The module that knows this configuration's model:
+    benchmarks/families/<model_type>.py (its interface is the docstring of
+    benchmarks/families/__init__.py). Loaded by file path, once a process,
+    so that its jitted makers are made once."""
+    name = config.get("model_type")
+    if not name:
+        raise SystemExit("benchmark: the configuration file has no "
+                         "\"model_type\", so no family module can be found")
+    tried = [os.path.join(d, name + ".py") for d in FAMILY_DIRS]
+    for path in tried:
+        if os.path.exists(path):
+            return _module_at(path, "bench_family_" + "".join(
+                c if c.isalnum() else "_" for c in name))
+    raise SystemExit(f"benchmark: no family module for model_type {name!r}: "
+                     f"looked for {' and '.join(tried)}")
 
 
 def layer_reader(name: str):

@@ -6,7 +6,9 @@ its first three optimizer steps through that same call and feed (each
 batch new rows), reads what `correct` compares, and hands the same net
 to the window. The window feeds new batches until the time is up; the
 clock stops when the last step's parameters are ready. Then the net is
-freed and the plain reference follows the same three steps.
+freed and the plain reference follows the same three steps. The net, its
+seeded weights, the reference and the names of the leaves compared are
+the configuration's family's (`spec.family_of`).
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ from collections import deque
 
 import numpy as np
 
-from harness import compare, device, flops, weights
+from harness import compare, device, spec, weights
 
 CHECK_STEPS = 3
 
@@ -97,37 +99,6 @@ class Pace:
             self._wait(self.inflight.popleft())
 
 
-def build_net(config: dict, seed: int):
-    from deeplearning4j_tpu.models.transformer import transformer_lm
-
-    tr = config["training"]
-    if tr["updater"] != "adam" or abs(config["layer_norm_epsilon"] - 1e-5) > 0:
-        raise ValueError("the program's transformer_lm is Adam with "
-                         "LayerNorm eps 1e-5; the configuration asks otherwise")
-    net = transformer_lm(
-        vocab_size=config["vocab_size"], d_model=config["n_embd"],
-        n_heads=config["n_head"], n_layers=config["n_layer"],
-        d_ff=config["n_inner"], max_length=config["n_positions"],
-        dropout=tr.get("dropout", 0.0), seed=int(seed) & 0x7FFFFFFF,
-        learning_rate=tr["learning_rate"], dtype=config["compute_dtype"],
-        remat=bool(tr.get("remat", False)))
-    g = net.conf.conf
-    g.adam_mean_decay, g.adam_var_decay = tr["adam_b1"], tr["adam_b2"]
-    g.epsilon = tr["adam_eps"]
-    net.init()
-    return net
-
-
-def give_weights(net, seed: int, dims: dict, like=None) -> None:
-    """Replace the net's parameters by the benchmark's seeded ones, in the
-    tree `like` (shapes and dtypes; the net's own parameters by default)."""
-    import jax
-
-    like = net.params if like is None else like
-    net.params = jax.jit(lambda k: weights.fit_program_tree(
-        weights.program_params(k, dims), like))(weights.seed_key(seed))
-
-
 def proj_key(seed: int):
     """The key of the fixed +-1 vectors both sides project the first
     gradient on."""
@@ -136,28 +107,8 @@ def proj_key(seed: int):
     return jax.random.fold_in(weights.seed_key(seed), 0x70726f6a)
 
 
-def reference_readings(seed, dims, hp, batches, mm=None, rows=None):
-    """The plain reference's three steps on the same batches from the
-    same seeded weights: {"losses", "grad_sq", "change_sq"} as numpy."""
-    import jax
-    import jax.numpy as jnp
-
-    from reference import gpt2_block as ref
-
-    key = weights.seed_key(seed)
-    make = jax.jit(lambda k: weights.reference_params(k, dims))
-    dev = [(jnp.asarray(t), jnp.asarray(l)) for t, l in batches]
-    losses, g1, ch = ref.train_steps(lambda: make(key), dev, dims, hp,
-                                     proj_key(seed), mm=mm or ref.mm_highest,
-                                     rows=rows)
-    g1 = jax.tree.map(np.asarray, g1)
-    return {"losses": [float(l) for l in losses],
-            "grad_sq": {k: v for k, v in g1.items() if not k.startswith("proj.")},
-            "grad_proj": {k: v for k, v in g1.items() if k.startswith("proj.")},
-            "change_sq": jax.tree.map(np.asarray, ch)}
-
-
-def program_readings(net, feed: StepFeed, seed: int, dims: dict, hp: dict):
+def program_readings(family, net, feed: StepFeed, seed: int, dims: dict,
+                     hp: dict):
     """Drive the net's first CHECK_STEPS steps through fit(feed) and read
     each loss, the first gradient's norms out of Adam's state after step
     1, and the norms of the parameters' change after the last."""
@@ -166,15 +117,14 @@ def program_readings(net, feed: StepFeed, seed: int, dims: dict, hp: dict):
     b1 = hp["adam_b1"]
     def first_grad(opt, params, k):
         g = jax.tree.map(lambda m: m / (1.0 - b1),
-                         weights.first_moment_tree(opt, params))
-        return (weights.program_sq_norms(g, dims),
-                weights.program_projections(g, dims, k))
+                         family.first_moment_tree(opt, params))
+        return (family.program_sq_norms(g, dims),
+                family.program_projections(g, dims, k))
 
     grad_sq = jax.jit(first_grad)
-    change_sq = jax.jit(lambda params, k: weights.program_sq_norms(
+    change_sq = jax.jit(lambda params, k: family.program_sq_norms(
         jax.tree.map(lambda a, b: a.astype("float32") - b, params,
-                     weights.fit_program_tree(
-                         weights.program_params(k, dims), params)), dims))
+                     family.seeded_program_tree(k, dims, params)), dims))
     feed.keep = CHECK_STEPS
     losses, g1 = [], None
     for k in range(CHECK_STEPS):
@@ -193,16 +143,16 @@ def run(ctx) -> dict:
     import jax
 
     config, traffic, seed = ctx.config, ctx.traffic, ctx.seed
-    dims = weights.dims_of(config)
+    family = spec.family_of(config)
+    dims = family.dims_of(config)
     hp = config["training"]
     B, T = int(traffic["batch"]), int(traffic["seq_len"])
 
-    net = build_net(config, seed)
-    give_weights(net, seed, dims)
+    net = family.training_net(config, seed, dims)
     pace = Pace(depth=int(traffic.get("steps_in_flight", 2)))
     net.set_listeners(pace)
     feed = StepFeed(seed, B, T, dims["V"])
-    prog = program_readings(net, feed, seed, dims, hp)
+    prog = program_readings(family, net, feed, seed, dims, hp)
     for _ in range(int(traffic.get("warm_steps", 2))):
         feed.limit = 1
         net.fit(feed)
@@ -229,7 +179,7 @@ def run(ctx) -> dict:
     net.params = net.opt_state = net.state = None
     net._train_step = None
     del net
-    ref = reference_readings(seed, dims, hp, feed.kept)
+    ref = family.reference_readings(seed, dims, hp, feed.kept, proj_key(seed))
     checks = compare.train_checks(prog, ref, ctx.limits)
     checks["steps_finite"] = [0.0 if np.isfinite(last_loss) else 1.0, 0]
 
@@ -240,6 +190,6 @@ def run(ctx) -> dict:
         "end_to_end": {"train_tokens_per_s": steps * B * T / window},
         "facts": {"window": (t0, t1), "steps": steps, "batch": B,
                   "seq_len": T, "dims": dims, "step_done": list(pace.done),
-                  "flops_per_token": flops.train_flops_per_token(dims, T),
+                  "flops_per_token": family.train_flops_per_token(dims, T),
                   "traced": traced},
     }

@@ -1,5 +1,5 @@
 """whole serving step: model FLOPs of all prompt and generated tokens
-processed in the traced window (harness/flops.py: a first token pays its
+processed in the traced window (the family's counts: a first token pays its
 prompt's prefill, a later one a decode against its context) over the
 window and the chip's peak."""
 from harness import serve_facts

@@ -1,6 +1,6 @@
 """train step (nn/graph.fit -> nn/training.make_train_step): model FLOPs
 utilization of the whole step. The benchmark's executed-FLOP count per
-token (harness/flops.py; recomputation not counted) times the tokens per
+token (the family's count; recomputation not counted) times the tokens per
 second of the traced window (batch x seq over the period between the
 starts of consecutive step programs in the trace) over the chip's peak."""
 

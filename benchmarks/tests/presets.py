@@ -6,10 +6,12 @@ import os
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-TINY_MODEL = {"n_embd": 64, "n_head": 2, "n_layer": 2, "n_inner": 128,
+TINY_MODEL = {"model_type": "gpt2", "n_embd": 64, "n_head": 2, "n_layer": 2,
+              "n_inner": 128,
               "vocab_size": 256, "n_positions": 128,
               "layer_norm_epsilon": 1e-5, "compute_dtype": "bfloat16",
               "param_dtype": "float32"}
+GPT2 = {"model_type": "gpt2"}       # spec.family_of(GPT2): the family module
 TINY_TRAIN = dict(TINY_MODEL, training={
     "remat": True, "updater": "adam", "learning_rate": 3e-4, "adam_b1": 0.9,
     "adam_b2": 0.999, "adam_eps": 1e-8, "dropout": 0.0})
@@ -34,11 +36,15 @@ CLOSED_MIX = {"kind": "serve_closed", "clients": 6, "requests_per_client": 2,
               "check_requests": 2, "trace_start_s": 0.5, "trace_seconds": 1.0}
 # limits of the tiny presets, between the readings of the same CPU runs
 # (seeds 100..111): sound runs read grad_norm <= 0.029, grad_proj <= 0.031,
-# change_norm <= 0.023, token_gap 0; the float8 control reads grad_proj
-# 0.052..0.075 on seeds 100..103; the faults read 0.3 and more
+# change_norm <= 0.023; the float8 control reads grad_proj 0.052..0.075 on
+# seeds 100..103; the faults read 0.3 and more. Serving, over ALL requests
+# of both mixes on seven seeds (PR 30): sound runs read token_gap 0..0.131
+# (one prompt in six reads 0.05..0.13, so a limit of 0.05 failed whenever
+# the sample drew it) and token_gap_mean <= 0.0013; the altered token reads
+# token_gap 10.2..14.1 on eight runs
 TRAIN_LIMITS = {"grad_norm": 0.1, "grad_proj": 0.045, "change_norm": 0.1}
 LOOSE_TRAIN_LIMITS = {"grad_norm": 1.0, "grad_proj": 1.0, "change_norm": 1.0}
-SERVE_LIMITS = {"token_gap": 0.05, "token_gap_mean": 0.005, "answered": 0,
+SERVE_LIMITS = {"token_gap": 0.5, "token_gap_mean": 0.005, "answered": 0,
                 "min_sample_tokens": 4}
 
 

@@ -5,6 +5,10 @@ has to fit the chip's 16 GB by `memory_analysis()`, so that a later PR
 finds an over-full cell before it spends chip time. Slow (minutes): not
 in the repo's tier-1 run.
 
+The nets are the configuration's family's own (`serving_net`,
+`training_net`), built under `jax.eval_shape`, so that they hold shapes
+and nothing is allocated here.
+
 The topology is described inside a fixture, never at import, and every
 compile runs in this process (on-chip-measurement guide, section 2).
 """
@@ -14,7 +18,7 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from harness import serve_driver, weights
+from harness import spec
 from jax.sharding import SingleDeviceSharding
 from presets import ROOT
 
@@ -44,6 +48,22 @@ def on(chip, tree):
         x.shape, x.dtype, sharding=chip), tree)
 
 
+def built(config, builder):
+    """(family, dims, the net `builder` names, its parameters and optimizer
+    state as shapes): the family's builder traced, never run."""
+    family = spec.family_of(config)
+    dims, held = family.dims_of(config), {}
+
+    def traced():
+        net = held["net"] = getattr(family, builder)(config, 0, dims)
+        return net.params, getattr(net, "opt_state", None)
+
+    params, opt = jax.eval_shape(traced)
+    net = held["net"]
+    net.params = net.opt_state = None       # the tracers it was built on
+    return family, dims, net, params, opt
+
+
 def total(mem):
     return (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -52,19 +72,7 @@ def total(mem):
 def test_train_step_fits(one_chip):
     config = load("configs/cerebras-gpt-590m.json")
     mix = load("traffic/train_seq2048_b4.json")
-    from deeplearning4j_tpu.models.transformer import transformer_lm
-
-    net = transformer_lm(
-        vocab_size=config["vocab_size"], d_model=config["n_embd"],
-        n_heads=config["n_head"], n_layers=config["n_layer"],
-        d_ff=config["n_inner"], max_length=config["n_positions"],
-        dtype=config["compute_dtype"], remat=config["training"]["remat"])
-    params = serve_driver.param_shapes(net)
-    from deeplearning4j_tpu.nn.updater import build_optimizer
-
-    net.tx = build_optimizer(net.conf.conf, {
-        n: v.layer for n, v in net.layer_vertices.items()}, params=params)
-    opt = jax.eval_shape(net.tx.init, params)
+    _family, _dims, net, params, opt = built(config, "training_net")
     state = {n: {} for n in params}
     B, T = mix["batch"], mix["seq_len"]
     tok = jax.ShapeDtypeStruct((B, T), jnp.int32)
@@ -81,8 +89,9 @@ def test_reference_step_fits(one_chip):
 
     config = load("configs/cerebras-gpt-590m.json")
     mix = load("traffic/train_seq2048_b4.json")
-    dims, hp = weights.dims_of(config), config["training"]
-    W = jax.eval_shape(lambda k: weights.reference_params(k, dims),
+    family = spec.family_of(config)
+    dims, hp = family.dims_of(config), config["training"]
+    W = jax.eval_shape(lambda k: family.reference_params(k, dims),
                        jax.random.PRNGKey(0))
     step, _change = ref._train_programs(
         tuple(sorted(dims.items())),
@@ -98,36 +107,37 @@ def test_reference_step_fits(one_chip):
 
 
 def test_server_programs_fit(one_chip):
+    """The decode step and the largest prefill bucket as `_GenWorker` jits
+    them (serving/engine.py): the greedy token and the cache come back, and
+    the cache argument is donated, so a step holds one cache, not two."""
     config = load("configs/cerebras-gpt-1.3b.json")
     dep = config["deployment"]
-    from deeplearning4j_tpu.models.transformer import transformer_lm
-
-    net = transformer_lm(
-        vocab_size=config["vocab_size"], d_model=config["n_embd"],
-        n_heads=config["n_head"], n_layers=config["n_layer"],
-        d_ff=config["n_inner"], max_length=config["n_positions"],
-        dtype=config["compute_dtype"])
-    params = serve_driver.param_shapes(net)
+    _family, _dims, net, params, _opt = built(config, "serving_net")
     state = {n: {} for n in params}
     page = dep["page_size"]
     cap = -(-(max(dep["prefill_seq_lens"]) + dep["max_new_tokens"]) // page) * page
     cache = jax.eval_shape(lambda: net.init_kv_cache(
         dep["slots"], cap, dep["kv_dtype"], page))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    weights_and_cache = sum(x.size * x.dtype.itemsize
-                            for x in jax.tree.leaves((params, cache)))
-    decode = jax.jit(net.incremental_decode_fn(dep["kv_dtype"], page))
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+
+    def greedy(raw):
+        def step(params, state, cache, *rest):
+            probs, cache = raw(params, state, cache, *rest)
+            return jnp.argmax(probs, axis=-1).astype(jnp.int32), cache
+        return jax.jit(step, donate_argnums=2)
+
+    decode = greedy(net.incremental_decode_fn(dep["kv_dtype"], page))
     mem = decode.lower(*on(one_chip, (params, state, cache,
                                       i32(dep["slots"]), i32(dep["slots"])))
                        ).compile().memory_analysis()
-    # nothing is donated: a whole second cache is the step's output
-    assert weights_and_cache + mem.output_size_in_bytes \
-        + mem.temp_size_in_bytes < HBM, mem
+    assert mem.alias_size_in_bytes == cache_bytes, mem      # donation engaged
+    assert total(mem) < HBM, mem
     Tb = max(dep["prefill_seq_lens"])
-    prefill = jax.jit(net.prefill_fn(dep["kv_dtype"], page))
+    prefill = greedy(net.prefill_fn(dep["kv_dtype"], page))
     mem = prefill.lower(*on(one_chip, (
         params, state, cache, i32(1, Tb),
         jax.ShapeDtypeStruct((1, Tb), jnp.float32), i32(1), i32(1), i32(1)))
     ).compile().memory_analysis()
-    assert weights_and_cache + mem.output_size_in_bytes \
-        + mem.temp_size_in_bytes < HBM, mem
+    assert mem.alias_size_in_bytes == cache_bytes, mem
+    assert total(mem) < HBM, mem

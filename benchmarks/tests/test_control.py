@@ -6,8 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import presets
 import pytest
-from harness import compare, serve_driver, train_driver, weights
+from harness import compare, serve_driver, spec, train_driver, weights
 from reference import gpt2_block as ref
+
+gpt2 = spec.family_of(presets.GPT2)
 
 LOOSE = dict.fromkeys(presets.TRAIN_LIMITS, 1e9)
 
@@ -15,16 +17,15 @@ LOOSE = dict.fromkeys(presets.TRAIN_LIMITS, 1e9)
 @pytest.mark.parametrize("seed", [100, 101, 103])
 def test_training_control_reads_above_the_program(seed):
     config, mix = presets.TINY_TRAIN, presets.TRAIN_MIX
-    dims, hp = weights.dims_of(config), config["training"]
-    net = train_driver.build_net(config, seed)
-    train_driver.give_weights(net, seed, dims)
+    dims, hp = gpt2.dims_of(config), config["training"]
+    net = gpt2.training_net(config, seed, dims)
     feed = train_driver.StepFeed(seed, mix["batch"], mix["seq_len"], dims["V"])
-    prog = train_driver.program_readings(net, feed, seed, dims, hp)
-    r = train_driver.reference_readings(seed, dims, hp, feed.kept)
-    low = train_driver.reference_readings(seed, dims, hp, feed.kept,
-                                          mm=ref.mm_fp8)
-    half = train_driver.reference_readings(seed, dims, hp, feed.kept,
-                                           rows=mix["batch"] // 2)
+    prog = train_driver.program_readings(gpt2, net, feed, seed, dims, hp)
+    pk = train_driver.proj_key(seed)
+    r = gpt2.reference_readings(seed, dims, hp, feed.kept, pk)
+    low = gpt2.reference_readings(seed, dims, hp, feed.kept, pk, lowprec=True)
+    half = gpt2.reference_readings(seed, dims, hp, feed.kept, pk,
+                                   rows=mix["batch"] // 2)
     p = compare.train_checks(prog, r, LOOSE)
     c = compare.train_checks(low, r, LOOSE)
     h = compare.train_checks(half, r, LOOSE)
@@ -42,11 +43,11 @@ def test_serving_control_is_not_correct(seed):
     correct; the float8 reference's choices are not, and neither are the
     reference's own choices with four rows of the context altered (stale
     rows of the cache; at the cells' 24 layers of 16 heads one row does)."""
-    dims = weights.dims_of(presets.TINY_SERVE)
+    dims = gpt2.dims_of(presets.TINY_SERVE)
     rng = np.random.default_rng(seed)
     P, T = 32, 96
     tokens = rng.integers(0, dims["V"], T)
-    W = weights.reference_params(weights.seed_key(seed), dims)
+    W = gpt2.reference_params(weights.seed_key(seed), dims)
     at, valid = jnp.arange(P - 1, T - 1), jnp.ones(T - P, bool)
 
     def choices(row, mm=ref.mm_highest):

@@ -4,7 +4,9 @@ reference in float8 in the program's place, is read against the same
 reference in test_control.py.)"""
 import presets
 import run
-from harness import train_driver
+from harness import spec, train_driver
+
+gpt2 = spec.family_of(presets.GPT2)
 
 
 def train_line(seed=100):
@@ -19,10 +21,10 @@ def test_sound_run_is_correct():
 
 
 def test_step_that_returns_its_state_unchanged(monkeypatch):
-    real = train_driver.build_net
+    real = gpt2.training_net
 
-    def build(config, seed):
-        net = real(config, seed)
+    def build(config, seed, dims):
+        net = real(config, seed, dims)
         step = net._get_train_step()
 
         def frozen(params, opt_state, state, rng, batch):
@@ -35,7 +37,7 @@ def test_step_that_returns_its_state_unchanged(monkeypatch):
         net._train_step = frozen
         return net
 
-    monkeypatch.setattr(train_driver, "build_net", build)
+    monkeypatch.setattr(gpt2, "training_net", build)
     line = train_line()
     assert line["correct"] is False
     assert line["checks"]["change_norm"][0] > 0.9      # reads 1: nothing moved

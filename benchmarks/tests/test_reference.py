@@ -5,8 +5,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from harness import weights
+from harness import spec, weights
+from presets import GPT2
 from reference import gpt2_block as ref
+
+gpt2 = spec.family_of(GPT2)
 
 DIMS = {"d": 64, "H": 2, "L": 3, "F": 128, "V": 97, "eps": 1e-5}
 HP = {"learning_rate": 3e-4, "adam_b1": 0.9, "adam_b2": 0.999,
@@ -16,7 +19,7 @@ HP = {"learning_rate": 3e-4, "adam_b1": 0.9, "adam_b2": 0.999,
 @pytest.fixture(scope="module")
 def setup():
     key = weights.seed_key(2**31 + 7)
-    W = weights.reference_params(key, DIMS)
+    W = gpt2.reference_params(key, DIMS)
     rng = np.random.default_rng(0)
     tok = jnp.asarray(rng.integers(0, 97, (3, 16)), jnp.int32)
     lab = jnp.asarray(rng.integers(0, 97, (3, 16)), jnp.int32)
@@ -45,19 +48,19 @@ def test_written_out_gradient_is_jax_grad(setup):
 
 def test_layouts_hold_the_same_numbers(setup):
     key, W, _tok, _lab = setup
-    back = weights.program_to_reference(weights.program_params(key, DIMS), DIMS)
+    back = gpt2.program_to_reference(gpt2.program_params(key, DIMS), DIMS)
     assert all(bool(jnp.array_equal(a, b)) for a, b in
                zip(jax.tree.leaves(back), jax.tree.leaves(W)))
-    assert sum(x.size for x in jax.tree.leaves(W)) == weights.count_params(DIMS)
-    p = weights.program_sq_norms(weights.program_params(key, DIMS), DIMS)
+    assert sum(x.size for x in jax.tree.leaves(W)) == gpt2.count_params(DIMS)
+    p = gpt2.program_sq_norms(gpt2.program_params(key, DIMS), DIMS)
     r = ref.sq_norms(W)
     for k in r:
         np.testing.assert_allclose(p[k], r[k], rtol=1e-5)
 
 
 def test_big_seed_is_a_different_seed():
-    a = weights.reference_params(weights.seed_key(5), DIMS)["Wout"]
-    b = weights.reference_params(weights.seed_key(5 + 2**31), DIMS)["Wout"]
+    a = gpt2.reference_params(weights.seed_key(5), DIMS)["Wout"]
+    b = gpt2.reference_params(weights.seed_key(5 + 2**31), DIMS)["Wout"]
     assert not bool(jnp.array_equal(a, b))
 
 
@@ -65,11 +68,11 @@ def test_first_moment_from_flat_state():
     import optax
     from deeplearning4j_tpu.nn.updater import _flatten_leaves
 
-    params = weights.program_params(weights.seed_key(1), DIMS)
+    params = gpt2.program_params(weights.seed_key(1), DIMS)
     flat = _flatten_leaves(params)      # the program's own flat layout
     state = optax.adam(1e-3).init(flat)
     state = (state[0]._replace(mu=flat * 2.0),) + tuple(state[1:])
-    mu = weights.first_moment_tree(state, params)
+    mu = gpt2.first_moment_tree(state, params)
     for a, b in zip(jax.tree.leaves(mu), jax.tree.leaves(params)):
         np.testing.assert_allclose(a, 2.0 * b)
 

@@ -63,32 +63,34 @@ def judged(kind, seed, checks):
 def train(cell, config, mix, seeds, control_seeds, limits):
     import jax
 
-    from harness import compare, train_driver as td, weights
-    from reference import gpt2_block as ref
+    from harness import compare, spec, train_driver as td
 
-    dims, hp = weights.dims_of(config), config["training"]
+    family = spec.family_of(config)
+    dims, hp = family.dims_of(config), config["training"]
     B, T = int(mix["batch"]), int(mix["seq_len"])
-    net = td.build_net(config, seeds[0])
+    net = family.training_net(config, seeds[0], dims)
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), net.params)
     net.params = None
     rows, kept, held = [], [], {}
     for seed in seeds:
-        td.give_weights(net, seed, dims, like=shapes)
+        family.give_weights(net, seed, dims, like=shapes)
         net.opt_state = net.tx.init(net.params)
         feed = td.StepFeed(seed, B, T, dims["V"])
-        prog = td.program_readings(net, feed, seed, dims, hp)
+        prog = td.program_readings(family, net, feed, seed, dims, hp)
         net.params = net.opt_state = None
-        r = td.reference_readings(seed, dims, hp, feed.kept)
+        r = family.reference_readings(seed, dims, hp, feed.kept,
+                                      td.proj_key(seed))
         rows.append(judged("program", seed, compare.train_checks(prog, r, limits)))
         kept.append({"kind": "program", "seed": seed, "readings": plain(prog),
                      "reference": plain(r)})
         if seed in control_seeds:
             held[seed] = (feed.kept, r)
     for seed, (batches, r) in held.items():
-        for kind, how in (("control_fp8", {"mm": ref.mm_fp8}),
+        for kind, how in (("control_fp8", {"lowprec": True}),
                           ("fault_half_batch", {"rows": B // 2})):
-            got = td.reference_readings(seed, dims, hp, batches, **how)
+            got = family.reference_readings(seed, dims, hp, batches,
+                                            td.proj_key(seed), **how)
             rows.append(judged(kind, seed, compare.train_checks(got, r, limits)))
             kept.append({"kind": kind, "seed": seed, "readings": plain(got)})
     return rows, kept
@@ -103,9 +105,10 @@ def train_again(kept, limits):
 
 
 def serve(cell, config, mix, seeds, seconds, limits):
-    from harness import serve_driver as sd, weights
+    from harness import serve_driver as sd, spec
 
-    dims = weights.dims_of(config)
+    family = spec.family_of(config)
+    dims = family.dims_of(config)
     held = []
     for seed in seeds:
         served = sd.Served(config, seed, log)
@@ -131,7 +134,7 @@ def serve(cell, config, mix, seeds, seconds, limits):
             f"{sum(len(r['tokens']) for r in sample)} served tokens, "
             f"{distinct} distinct")
         for kind, low in (("program", False), ("control_fp8", True)):
-            gaps = sd.reference_gaps(sample, prompts, seed, dims, lowprec=low)
+            gaps = family.served_gaps(sample, prompts, seed, dims, lowprec=low)
             rows.append(judged(kind, seed, sd.serve_checks(
                 sample, gaps, compiles, limits)))
             kept.append({"kind": kind, "seed": seed, "sample": slim,

@@ -32,15 +32,18 @@ from deeplearning4j_tpu.nn.conf.layers import (  # noqa: F401
     DropoutLayer,
     EmbeddingLayer,
     FeedForwardLayer,
+    GatedDenseLayer,
     GravesBidirectionalLSTM,
     GravesLSTM,
     GRU,
     Layer,
+    LatentAttentionLayer,
     LayerNormalization,
     LocalResponseNormalization,
     LSTM,
     OutputLayer,
     RBM,
+    RMSNormalization,
     RnnOutputLayer,
     SelfAttentionLayer,
     SubsamplingLayer,

@@ -81,6 +81,7 @@ class DenseLayer(FeedForwardLayer):
 @dataclasses.dataclass
 class BaseOutputLayer(FeedForwardLayer):
     loss_function: str = "mcxent"
+    has_bias: bool = True  # False: a head that is one matrix, z = x W
 
     def has_loss(self) -> bool:
         return True
@@ -311,6 +312,30 @@ class LayerNormalization(FeedForwardLayer):
 
 @register_config
 @dataclasses.dataclass
+class RMSNormalization(LayerNormalization):
+    """Root-mean-square norm over the feature axis:
+    x * rsqrt(mean(x^2) + eps) * gamma. No mean is taken off, no offset
+    is added."""
+
+
+@register_config
+@dataclasses.dataclass
+class GatedDenseLayer(FeedForwardLayer):
+    """Gated feed-forward block without biases:
+    y = (act(x Wgate) * (x Wup)) Wdown, `d_hidden` wide inside (the
+    default activation is silu)."""
+
+    d_hidden: int = 0  # defaults to 4 * n_in
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in == 0:
+            self.n_in = input_type.flat_size()
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+
+@register_config
+@dataclasses.dataclass
 class PositionalEncodingLayer(Layer):
     """Adds positional information to [batch, time, features] — sinusoidal
     (param-free) or learned. New capability for the Transformer north star."""
@@ -348,6 +373,35 @@ class SelfAttentionLayer(BaseRecurrentLayer):
     # local K/V block — the sequence-parallel training path
     # (parallel/sequence_parallel.py)
     seq_parallel_axis: str = ""
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in == 0:
+            self.n_in = input_type.flat_size()
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+
+@register_config
+@dataclasses.dataclass
+class LatentAttentionLayer(BaseRecurrentLayer):
+    """Causal multi-head attention through low-rank latents, no biases
+    (nn/layers/latent_attention.py holds the equations). The query goes
+    through a normalised `q_rank` latent; keys and values are expanded
+    from one normalised `kv_rank` latent a token, which with the one
+    rotary key slice shared by all heads is the whole cache row:
+    `kv_rank + rope_dim` values a token, whatever the head count. Each
+    head's query and key are a `nope_dim` part without position and a
+    `rope_dim` part with rotary position; values are `v_dim` wide."""
+
+    n_heads: int = 8
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    causal: bool = True
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:

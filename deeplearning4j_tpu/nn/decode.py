@@ -37,8 +37,26 @@ contract for transformer stacks, on BOTH containers:
   verify window — which always starts at or before the stale region —
   overwrites it.
 * ``init_cache(net, batch, capacity)`` — zeroed per-attention-layer
-  K/V pytree ``{layer: {"k": [B, S, H, D], "v": ...}}`` (key position
-  on axis 1 so per-position scatter writes are contiguous).
+  pytree, built from each attention layer's OWN spec
+  (``impl.cache_arrays``; ``cache_specs(net, ...)`` lists them):
+  ``{layer: {"k": [B, S, H, D], "v": ...}}`` for `SelfAttentionLayer`
+  (key position on axis 1 so per-position scatter writes are
+  contiguous), ``{layer: {"ckv": [B, S, kv_rank], "kpe": [B, S,
+  rope]}}`` for `LatentAttentionLayer`. The serving allocator bills
+  the same spec.
+
+A layer that owns its cache entry AND its cached forward
+(``impl.apply_cached(conf, params, x, entry, step)``; `CacheStep` says
+which rows and positions the call holds) is called by the walk, not
+re-implemented here: its mathematics lives once, in nn/layers/. A layer
+that counts its own work (``impl.apply_counted``: the dropless expert
+layer) hands its counters to the step, which then returns a third value,
+an int32 vector in the order of the fn's ``counters`` attribute (empty,
+and two values returned, for a net without such a layer). The decode and
+verify fns take one more, optional argument, ``live`` [B] bool: the rows
+that hold a request, by the caller's word (the serving engine pads its
+batch with idle rows); a counting layer computes and counts nothing for
+the others. Without it every row is real.
 
 All three entry fns (and ``init_cache``) take ``kv_dtype`` ("f32" |
 "int8") and ``page_size``: the int8 paged cache stores codes plus
@@ -73,6 +91,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     DenseLayer,
     DropoutLayer,
     EmbeddingLayer,
+    GatedDenseLayer,
     LayerNormalization,
     PositionalEncodingLayer,
     SelfAttentionLayer,
@@ -86,7 +105,8 @@ from deeplearning4j_tpu.ops.decode_attention import (
 )
 
 _POINTWISE = (DenseLayer, EmbeddingLayer, LayerNormalization,
-              BaseOutputLayer, ActivationLayer, DropoutLayer)
+              BaseOutputLayer, ActivationLayer, DropoutLayer,
+              GatedDenseLayer)
 
 _NEG_INF = -1e30
 
@@ -135,7 +155,7 @@ def _plan(net):
             inputs = list(net.conf.vertex_inputs[name])
             if isinstance(vconf, LayerVertexConf):
                 lc = vconf.layer
-                if not _decodable_layer(lc):
+                if not _decodable_layer(lc, net.impls[name]):
                     problems.append(f"{name} ({type(lc).__name__})")
                 ops.append(_Op("layer", name, lc, net.impls[name],
                                vconf.preprocessor, inputs))
@@ -149,7 +169,7 @@ def _plan(net):
         prev = "__input__"
         for i, (name, lc, impl) in enumerate(zip(
                 net.layer_names, net.layer_confs, net.impls)):
-            if not _decodable_layer(lc):
+            if not _decodable_layer(lc, impl):
                 problems.append(f"{name} ({type(lc).__name__})")
             ops.append(_Op("layer", name, lc, impl,
                            net.conf.get_preprocessor(i), [prev]))
@@ -163,48 +183,86 @@ def _plan(net):
     return in_name, out_name, ops
 
 
-def _decodable_layer(lc) -> bool:
-    if isinstance(lc, SelfAttentionLayer):
+def _owns_cache(impl) -> bool:
+    """The layer carries its cached forward itself (`apply_cached`)."""
+    return hasattr(impl, "apply_cached")
+
+
+def _decodable_layer(lc, impl) -> bool:
+    if isinstance(lc, SelfAttentionLayer) or _owns_cache(impl):
         return bool(lc.causal)  # non-causal attention reads the future
     if isinstance(lc, PositionalEncodingLayer):
         return True
-    return isinstance(lc, _POINTWISE)
+    return isinstance(lc, _POINTWISE) or hasattr(impl, "apply_counted")
 
 
-def attention_specs(net):
-    """[(layer_name, n_heads, head_dim)] for every attention layer —
-    the cache layout contract init_cache allocates by."""
+def _counting_impl(ops):
+    """The impl of the plan's counting layers (`apply_counted`, with its
+    `counters` names and `merge_counts`: the expert layer's), or None."""
+    for op in ops:
+        if op.kind == "layer" and hasattr(op.impl, "apply_counted"):
+            return op.impl
+    return None
+
+
+def _mark_counters(fn, ops):
+    """`fn.counters`: the names of the int32 vector the step returns as
+    its third value, () where the plan has no counting layer."""
+    fn.counting = _counting_impl(ops)
+    fn.counters = tuple(fn.counting.counters) if fn.counting else ()
+    return fn
+
+
+def cache_specs(net, capacity: int, kv_dtype: str = "f32",
+                page_size: int = 16) -> dict:
+    """{layer: {array: (shape of one slot, dtype name)}} for every
+    attention layer, each as the layer's own `cache_arrays` gives it:
+    what `init_cache` allocates a batch of and what the serving
+    allocator bills (serving/kvcache.bytes_per_slot)."""
+    if kv_dtype == "int8" and capacity % page_size != 0:
+        raise ValueError(
+            f"int8 cache needs page-quantized capacity; {capacity} "
+            f"is not a multiple of page_size {page_size}")
     _, _, ops = _plan(net)
-    return [(op.name, op.conf.n_heads, op.conf.n_out // op.conf.n_heads)
-            for op in ops
-            if op.kind == "layer" and isinstance(op.conf,
-                                                 SelfAttentionLayer)]
+    return {op.name: {
+        arr: (tuple(shape), jnp.dtype(dt).name)
+        for arr, (shape, dt) in op.impl.cache_arrays(
+            op.conf, capacity, kv_dtype, page_size,
+            net.compute_dtype).items()}
+        for op in ops
+        if op.kind == "layer" and hasattr(op.impl, "cache_arrays")}
 
 
 def init_cache(net, batch: int, capacity: int, kv_dtype: str = "f32",
                page_size: int = 16):
-    """Zeroed KV cache: {layer: {"k": [batch, capacity, H, D], "v":
-    ...}} in the net's compute dtype. `capacity` is the per-row key
-    budget (prompt + generated, page-quantized by the serving layer).
-    kv_dtype="int8" stores int8 codes plus per-(row, page, head) f32
-    scales ({"k", "k_scale", "v", "v_scale"} entries); capacity must
-    sit on the page grid."""
-    if kv_dtype == "int8":
-        if capacity % page_size != 0:
-            raise ValueError(
-                f"int8 cache needs page-quantized capacity; {capacity} "
-                f"is not a multiple of page_size {page_size}")
-        n_pages = capacity // page_size
-        return {name: {
-            "k": jnp.zeros((batch, capacity, H, D), jnp.int8),
-            "k_scale": jnp.zeros((batch, n_pages, H), jnp.float32),
-            "v": jnp.zeros((batch, capacity, H, D), jnp.int8),
-            "v_scale": jnp.zeros((batch, n_pages, H), jnp.float32)}
-            for name, H, D in attention_specs(net)}
-    dtype = net.compute_dtype
-    return {name: {"k": jnp.zeros((batch, capacity, H, D), dtype),
-                   "v": jnp.zeros((batch, capacity, H, D), dtype)}
-            for name, H, D in attention_specs(net)}
+    """Zeroed cache, {layer: {array: [batch, ...]}} by `cache_specs`:
+    keys and values {"k": [batch, capacity, H, D], "v": ...} in the
+    net's compute dtype for `SelfAttentionLayer` (kv_dtype="int8": int8
+    codes plus per-(row, page, head) f32 scales, {"k", "k_scale", "v",
+    "v_scale"}; capacity must sit on the page grid), one latent row
+    {"ckv": [batch, capacity, kv_rank], "kpe": [batch, capacity, rope]}
+    for `LatentAttentionLayer`. `capacity` is the per-row key budget
+    (prompt + generated, page-quantized by the serving layer)."""
+    return {name: {arr: jnp.zeros((batch,) + shape, dt)
+                   for arr, (shape, dt) in arrays.items()}
+            for name, arrays in cache_specs(net, capacity, kv_dtype,
+                                            page_size).items()}
+
+
+class CacheStep:
+    """What a layer that owns its cache entry is told about the serving
+    step it is called in: `rows` [b] the cache rows the call's batch
+    rows are (None: all of them, in order), `positions` [b, T] the
+    position each token occupies, `keep` [b, T] 1 for real tokens (None:
+    all; the pad of a prefill bucket writes zero rows), `chunk` True for
+    a prefill chunk (many queries a row), False for a decode or verify
+    step."""
+
+    __slots__ = ("rows", "positions", "keep", "chunk")
+
+    def __init__(self, rows, positions, keep=None, chunk=False):
+        self.rows, self.positions = rows, positions
+        self.keep, self.chunk = keep, chunk
 
 
 def _cache_write(entry, k_new, v_new, rows, positions, kv_dtype,
@@ -313,11 +371,15 @@ def _merge_lse(o1, lse1, o2, lse2):
 
 # -------------------------------------------------------------- the walk
 
-def _walk(net, ops, in_name, out_name, params, state, x0, attn, posenc):
+def _walk(net, ops, in_name, out_name, params, state, x0, attn, posenc,
+          cache=None, step=None, valid=None, counts=None):
     """Topo traversal with inference semantics (train=False, no rng),
-    attention/posenc routed to the supplied handlers. Mirrors the
+    attention/posenc routed to the supplied handlers. A layer that owns
+    its cache is called with its entry of `cache` (updated in place in
+    that dict) and `step`; a counting layer is told which tokens are
+    real (`valid`) and appends its counters to `counts`. Mirrors the
     containers' _forward dtype policy: float inputs and per-layer params
-    cast to the compute dtype."""
+    cast to the compute dtype where the two differ."""
     cdtype = net.compute_dtype
     pdtype = net.param_dtype
     x0 = jnp.asarray(x0)
@@ -337,6 +399,14 @@ def _walk(net, ops, in_name, out_name, params, state, x0, attn, posenc):
                 y = attn(op.name, op.conf, p, x)
             elif isinstance(op.conf, PositionalEncodingLayer):
                 y = posenc(op.name, op.conf, p, x)
+            elif _owns_cache(op.impl):
+                y, cache[op.name] = op.impl.apply_cached(
+                    op.conf, p, _as_seq(x), cache[op.name], step)
+                if x.ndim == 2:     # a one-token walk that arrived 2-D
+                    y = y[:, 0, :]  # stays so (see `_as_seq`)
+            elif hasattr(op.impl, "apply_counted"):
+                y, c = op.impl.apply_counted(op.conf, p, x, valid)
+                counts.append(c)
             else:
                 y, _ = op.impl.apply(op.conf, p, state.get(op.name, {}),
                                      x, train=False, rng=None)
@@ -382,6 +452,27 @@ def _vertex(vconf, inputs):
     raise ValueError(f"unhandled vertex {type(vconf).__name__}")
 
 
+def _live_tokens(live, positions):
+    """[B, T] True for the tokens of the rows `live` [B] marks (the
+    caller's word on which rows of the batch hold a request); None, and
+    every token real, where the caller gave none."""
+    if live is None:
+        return None
+    return jnp.broadcast_to(jnp.asarray(live, bool)[:, None],
+                            positions.shape)
+
+
+def _finish(fn, counts, out, cache):
+    """A step's return: (out, cache), and the layers' counters merged
+    into one int32 vector in the order of `fn.counters` where the plan
+    has counting layers."""
+    if not fn.counters:
+        return out, cache
+    total = fn.counting.merge_counts(counts)
+    return out, cache, jnp.stack(
+        [total[n] for n in fn.counters]).astype(jnp.int32)
+
+
 def _split_heads(t, H):
     b, T, n = t.shape
     return t.reshape(b, T, H, n // H)
@@ -408,7 +499,7 @@ def make_decode_fn(net, kv_dtype: str = "f32", page_size: int = 16):
     K/V written at (row, pos)."""
     in_name, out_name, ops = _plan(net)
 
-    def step(params, state, cache, token, pos):
+    def step(params, state, cache, token, pos, live=None):
         B = token.shape[0]
         new_cache = dict(cache)
         rows = jnp.arange(B)
@@ -441,11 +532,14 @@ def make_decode_fn(net, kv_dtype: str = "f32", page_size: int = 16):
                 pe = _sinusoidal_at(pos, d, x.dtype)
             return x + pe[:, None, :]
 
-        probs = _as_seq(_walk(net, ops, in_name, out_name, params, state,
-                              token[:, None], attn, posenc))
-        return probs[:, 0, :], new_cache
+        counts = []
+        probs = _as_seq(_walk(
+            net, ops, in_name, out_name, params, state, token[:, None],
+            attn, posenc, cache=new_cache, step=CacheStep(None, positions),
+            valid=_live_tokens(live, positions), counts=counts))
+        return _finish(step, counts, probs[:, 0, :], new_cache)
 
-    return step
+    return _mark_counters(step, ops)
 
 
 def make_prefill_fn(net, kv_dtype: str = "f32", page_size: int = 16):
@@ -505,11 +599,16 @@ def make_prefill_fn(net, kv_dtype: str = "f32", page_size: int = 16):
                 pe = _sinusoidal_at(positions, d, x.dtype)
             return x + pe
 
-        probs = _as_seq(_walk(net, ops, in_name, out_name, params, state,
-                              tokens, attn, posenc))
-        return probs[jnp.arange(b), last_idx, :], new_cache
+        counts = []
+        probs = _as_seq(_walk(
+            net, ops, in_name, out_name, params, state, tokens, attn, posenc,
+            cache=new_cache,
+            step=CacheStep(rows, positions, keep=kmask, chunk=True),
+            valid=kmask > 0 if prefill.counters else None, counts=counts))
+        return _finish(prefill, counts,
+                       probs[jnp.arange(b), last_idx, :], new_cache)
 
-    return prefill
+    return _mark_counters(prefill, ops)
 
 
 def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
@@ -527,7 +626,7 @@ def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
     window overwrites it."""
     in_name, out_name, ops = _plan(net)
 
-    def verify(params, state, cache, tokens, pos):
+    def verify(params, state, cache, tokens, pos, live=None):
         B, K = tokens.shape
         new_cache = dict(cache)
         rows = jnp.arange(B)
@@ -559,8 +658,11 @@ def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
                 pe = _sinusoidal_at(positions, d, x.dtype)
             return x + pe
 
-        probs = _walk(net, ops, in_name, out_name, params, state,
-                      tokens, attn, posenc)
-        return probs, new_cache
+        counts = []
+        probs = _walk(
+            net, ops, in_name, out_name, params, state, tokens, attn, posenc,
+            cache=new_cache, step=CacheStep(None, positions),
+            valid=_live_tokens(live, positions), counts=counts)
+        return _finish(verify, counts, probs, new_cache)
 
-    return verify
+    return _mark_counters(verify, ops)

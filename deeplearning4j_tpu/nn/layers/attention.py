@@ -26,6 +26,7 @@ from deeplearning4j_tpu.ops.flash_attention import (
 from deeplearning4j_tpu.nn.conf.layers import (
     LayerNormalization,
     PositionalEncodingLayer,
+    RMSNormalization,
     SelfAttentionLayer,
 )
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, apply_dropout, register_impl
@@ -55,6 +56,24 @@ class LayerNormImpl(LayerImpl):
         var = jnp.var(x, axis=-1, keepdims=True)
         xn = (x - mu) * jax.lax.rsqrt(var + conf.eps)
         return xn * params["gamma"] + params["beta"], state
+
+
+def rms_norm(x, gamma, eps):
+    """x * rsqrt(mean(x^2) + eps) * gamma over the last axis; the mean in
+    float32 whatever x is (a bfloat16 sum of 7,680 squares keeps three
+    digits), the result in x's dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+@register_impl(RMSNormalization)
+class RMSNormImpl(LayerImpl):
+    def init(self, conf, rng, dtype):
+        return {"gamma": jnp.ones((conf.n_out or conf.n_in,), dtype)}, {}
+
+    def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
+        return rms_norm(x, params["gamma"], conf.eps), state
 
 
 def _sp_axis_in_scope(name: str) -> bool:
@@ -149,6 +168,20 @@ class SelfAttentionImpl(LayerImpl):
             "Wo": init_weights(k2, (n, n), conf.weight_init, conf.dist, dtype),
             "bo": jnp.zeros((n,), dtype),
         }, {}
+
+    def cache_arrays(self, conf, capacity, kv_dtype, page_size, dtype):
+        """The arrays one decode slot of this layer holds, {name: (shape,
+        dtype)}: keys and values, one [H, D] row a position each, in the
+        compute dtype; or int8 codes with one float32 scale a (page,
+        head). nn/decode.py allocates them and walks them; the serving
+        allocator bills them (serving/kvcache.bytes_per_slot)."""
+        H = conf.n_heads
+        row = (capacity, H, conf.n_out // H)
+        if kv_dtype == "int8":
+            scale = (capacity // page_size, H)
+            return {"k": (row, jnp.int8), "k_scale": (scale, jnp.float32),
+                    "v": (row, jnp.int8), "v_scale": (scale, jnp.float32)}
+        return {"k": (row, dtype), "v": (row, dtype)}
 
     def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
         if conf.dropout:

@@ -21,6 +21,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     DenseLayer,
     DropoutLayer,
     EmbeddingLayer,
+    GatedDenseLayer,
     RBM,
 )
 from deeplearning4j_tpu.nn.layers.base import (
@@ -47,8 +48,37 @@ def _dense_forward(conf, params, x, train, rng):
         W = apply_dropconnect(W, conf.dropout, rng, train=train)
     elif conf.dropout:
         x = apply_dropout(x, conf.dropout, rng, train=train)
-    z = x @ W + params["b"]
+    z = x @ W
+    if "b" in params:
+        z = z + params["b"]
     return get_activation(conf.activation)(z), z
+
+
+def gated_ffn(x, w_gate, w_up, w_down, activation="silu"):
+    """(act(x Wgate) * (x Wup)) Wdown: the gated feed-forward block, no
+    biases. The expert layers (nn/layers/moe.py) call it per expert."""
+    h = get_activation(activation)(x @ w_gate) * (x @ w_up)
+    return h @ w_down
+
+
+@register_impl(GatedDenseLayer)
+class GatedDenseImpl(LayerImpl):
+    def init(self, conf, rng, dtype):
+        D, O = conf.n_in, conf.n_out or conf.n_in
+        F = conf.d_hidden or 4 * D
+        kg, ku, kd = jax.random.split(rng, 3)
+
+        def w(k, shape):
+            return init_weights(k, shape, conf.weight_init, conf.dist, dtype)
+
+        return {"Wgate": w(kg, (D, F)), "Wup": w(ku, (D, F)),
+                "Wdown": w(kd, (F, O))}, {}
+
+    def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
+        if conf.dropout:
+            x = apply_dropout(x, conf.dropout, rng, train=train)
+        return gated_ffn(x, params["Wgate"], params["Wup"], params["Wdown"],
+                         conf.activation or "silu"), state
 
 
 @register_impl(DenseLayer)
@@ -68,7 +98,10 @@ class OutputImpl(LayerImpl):
     computes the softmax/loss delta jointly)."""
 
     def init(self, conf, rng, dtype):
-        return _dense_init(conf, rng, dtype)
+        params, state = _dense_init(conf, rng, dtype)
+        if not conf.has_bias:
+            params.pop("b")
+        return params, state
 
     def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
         y, z = _dense_forward(conf, params, x, train, rng)
@@ -117,7 +150,7 @@ class OutputImpl(LayerImpl):
         if not (labels.ndim == x.ndim - 1
                 and jnp.issubdtype(labels.dtype, jnp.integer)):
             return False
-        if getattr(conf, "drop_connect", False):
+        if getattr(conf, "drop_connect", False) or "b" not in params:
             return False
         n = int(np.prod(x.shape[:-1]))
         d = x.shape[-1]

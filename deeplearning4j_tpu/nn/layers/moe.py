@@ -27,6 +27,11 @@ MoE): E expert FFNs with a learned router. Two execution paths:
 
 With ample capacity (capacity_factor >= E/top_k) the routed path drops
 nothing and matches the dense path to float tolerance.
+
+`DroplessMoELayer` (below) is the expert layer of the served models:
+sigmoid scores, the top k normalised and scaled, gated experts, a shared
+expert, NO dropped (token, expert) pair at any imbalance, and a
+configuration that says which of the router's experts this chip holds.
 """
 
 from __future__ import annotations
@@ -40,6 +45,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.conf.inputs import InputType
 from deeplearning4j_tpu.nn.conf.layers import FeedForwardLayer
 from deeplearning4j_tpu.nn.conf.serde import register_config
+from deeplearning4j_tpu.nn.layers.feedforward import gated_ffn
 from deeplearning4j_tpu.nn.layers.base import (
     AUX_LOSS_KEY,
     LayerImpl,
@@ -304,3 +310,203 @@ class MixtureOfExpertsImpl(LayerImpl):
                 y = out
         y = y.reshape(*shape[:-1], y.shape[-1])
         return y, new_state
+
+
+# ---------------------------------------------------------------- dropless
+
+@register_config
+@dataclasses.dataclass
+class DroplessMoELayer(FeedForwardLayer):
+    """Routed gated experts with a shared expert, no pair dropped, told
+    which experts it holds.
+
+        s = sigmoid(x_f32 Wg_f32)               all `n_experts` scores
+        w = s_top / (sum of the top_k + 1e-20) * routed_scaling
+        y = sum over the selected experts HELD HERE of w_e E_e(x)
+            + Shared(x)
+        E_e(x) = (act(x Wgate_e) * (x Wup_e)) Wdown_e,  d_hidden wide
+
+    The router keeps its whole width and the weights are normalised over
+    all top_k selected experts, held or not; this layer holds experts
+    `first_expert .. first_expert + n_held - 1` and leaves out what the
+    others would add (one chip's share of an expert-parallel layer: the
+    shares of all chips, with the shared expert counted once, add up to
+    the whole layer). `n_held` 0 holds all of them. The shared expert is
+    one gated block `n_shared * d_hidden` wide, computed for every
+    token."""
+
+    n_experts: int = 8      # the router's width
+    top_k: int = 2
+    d_hidden: int = 0       # an expert's inner width; defaults to 4 * n_in
+    first_expert: int = 0
+    n_held: int = 0
+    n_shared: int = 0
+    routed_scaling: float = 1.0
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in == 0:
+            self.n_in = input_type.flat_size()
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+
+# A round of the dispatch gives every held expert `round_rows` rows:
+# ROUND_SLACK times the mean a uniform router would send it, at least 8
+# (a sublane tile). Under a uniform router an expert's count is near
+# Poisson, and the fullest of 16 held experts reads, in one layer of a
+# hundred, 4 times a mean of 2 (a decode batch), 2.4 times a mean of 8
+# and 1.6 times a mean of 32 (a prefill chunk): 4 keeps the small
+# batches to one round, and at the large ones it costs rows alone. Those are
+# cheap: an expert's three products are bound by reading its weights up
+# to the device's ridge (some 240 rows at bfloat16 on a v5e), while a
+# second round reads every held expert's weights again. A router that
+# is NOT uniform (text of one domain; seeded weights) spends the spare
+# rows of the large batches first and the second round after them.
+ROUND_SLACK = 4.0
+COUNTERS = ("moe_pairs", "moe_rows", "moe_max_load")
+
+
+def round_rows(n_tokens: int, top_k: int, n_experts: int) -> int:
+    c = math.ceil(ROUND_SLACK * n_tokens * top_k / n_experts)
+    return min(max(8, -(-c // 8) * 8), max(8, -(-n_tokens // 8) * 8))
+
+
+def route_sigmoid_topk(x2d, Wg, top_k, routed_scaling):
+    """(expert ids [N, k], weights [N, k] float32). The router's product
+    and the sigmoid in float32 at full precision, as the published
+    models compute them: a bfloat16 rounding of a score picks another
+    expert than the reference where two scores lie close."""
+    logits = jnp.matmul(x2d.astype(jnp.float32), Wg.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
+    return top_i, w * routed_scaling
+
+
+def dropless_moe(conf, params, x2d, valid=None):
+    """The layer on x2d [N, D]; `valid` [N] marks real tokens (pad rows
+    and idle slots select nothing). -> (y [N, O], counts).
+
+    Dispatch: a token selects a held expert at most once, so the pairs
+    are an [N, held] grid; `rank` numbers each expert's pairs in token
+    order. A ROUND gives every held expert its next `round_rows` pairs:
+    row j = e * round_rows + c of the round's [held * round_rows, D]
+    buffer is the pair of expert e with rank c (+ the rounds before). The
+    buffer is filled and emptied by two products with the round's
+    [N, rows] one-hot matrix (the GShard dispatch and combine: on a TPU
+    the MXU moves rows an order of magnitude faster than a gather or a
+    scatter-add; a one-hot product copies exactly), the second carrying
+    the pairs' weights; between them the held experts run as one batched
+    gated block. Rounds repeat until the fullest expert is served, so no
+    pair is dropped whatever the imbalance, and a batch whose fullest
+    expert has at most `round_rows` pairs costs one. The round count is a
+    value, not a shape: the loop runs its static bound (every token on
+    one expert) and skips the rounds past the last needed one
+    (`lax.cond`), which keeps it differentiable.
+
+    counts (int32 scalars): `moe_pairs` the (token, held selected
+    expert) pairs, `moe_rows` the expert rows computed for them (rounds
+    x held x round_rows: padding included), `moe_max_load` the most pairs
+    on one held expert."""
+    N, D = x2d.shape
+    held = conf.n_held or conf.n_experts
+    act = conf.activation or "silu"
+    top_i, w = route_sigmoid_topk(x2d, params["Wg"], conf.top_k,
+                                  conf.routed_scaling)
+    local = top_i - conf.first_expert                        # [N, k]
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine = mine & valid.reshape(N, 1).astype(bool)
+    onehot = (local[:, :, None] == jnp.arange(held)) & mine[:, :, None]
+    sel = jnp.any(onehot, axis=1)                            # [N, held]
+    gate = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1)
+    rank = jnp.cumsum(sel.astype(jnp.int32), axis=0) - 1
+    max_load = jnp.max(jnp.sum(sel.astype(jnp.int32), axis=0))
+    C = round_rows(N, conf.top_k, conf.n_experts)
+    rounds = (max_load + C - 1) // C
+    # per buffer row j: its expert's column of the [N, held] grids, and
+    # the rank within the round that it serves
+    rank_r = jnp.repeat(jnp.where(sel, rank, -1), C, axis=1)  # [N, held*C]
+    gate_r = jnp.repeat(gate, C, axis=1)
+    slot = jnp.tile(jnp.arange(C), held)
+    O = params["We_down"].shape[-1]
+
+    def one_round(r, y):
+        here = rank_r == slot + r * C                        # [N, held*C]
+        xin = jnp.einsum("nj,nd->jd", here.astype(x2d.dtype),
+                         x2d).reshape(held, C, D)
+        g = get_activation(act)(
+            jnp.einsum("ecd,edf->ecf", xin, params["We_gate"]))
+        h = g * jnp.einsum("ecd,edf->ecf", xin, params["We_up"])
+        out = jnp.einsum("ecf,efo->eco", h, params["We_down"])
+        return y + jnp.einsum(
+            "nj,jo->no", jnp.where(here, gate_r, 0.0).astype(out.dtype),
+            out.reshape(held * C, O), preferred_element_type=jnp.float32)
+
+    y = jax.lax.fori_loop(
+        0, -(-N // C),
+        lambda r, y: jax.lax.cond(r < rounds, one_round,
+                                  lambda _r, y: y, r, y),
+        jnp.zeros((N, O), jnp.float32))
+    if conf.n_shared:
+        y = y + gated_ffn(x2d, params["Ws_gate"], params["Ws_up"],
+                          params["Ws_down"], act).astype(jnp.float32)
+    counts = {"moe_pairs": jnp.sum(sel.astype(jnp.int32)),
+              "moe_rows": rounds * (held * C),
+              "moe_max_load": max_load}
+    return y.astype(x2d.dtype), counts
+
+
+@register_impl(DroplessMoELayer)
+class DroplessMoEImpl(LayerImpl):
+    counters = COUNTERS
+
+    @staticmethod
+    def merge_counts(counts: list) -> dict:
+        """One step's counters from its expert layers': pairs and rows
+        add up, the fullest expert is the fullest of any layer."""
+        return {"moe_pairs": sum(c["moe_pairs"] for c in counts),
+                "moe_rows": sum(c["moe_rows"] for c in counts),
+                "moe_max_load": jnp.max(jnp.stack(
+                    [c["moe_max_load"] for c in counts]))}
+
+    def init(self, conf, rng, dtype):
+        D, O = conf.n_in, conf.n_out or conf.n_in
+        F = conf.d_hidden or 4 * D
+        held = conf.n_held or conf.n_experts
+        if not 0 <= conf.first_expert <= conf.n_experts - held:
+            raise ValueError(
+                f"experts {conf.first_expert}..{conf.first_expert + held - 1}"
+                f" are not among the router's {conf.n_experts}")
+        k = jax.random.split(rng, 7)
+
+        def w(key, shape, fan_in, fan_out):
+            return init_weights(key, shape, conf.weight_init, conf.dist,
+                                dtype, fan_in=fan_in, fan_out=fan_out)
+
+        params = {"Wg": w(k[0], (D, conf.n_experts), D, conf.n_experts),
+                  "We_gate": w(k[1], (held, D, F), D, F),
+                  "We_up": w(k[2], (held, D, F), D, F),
+                  "We_down": w(k[3], (held, F, O), F, O)}
+        if conf.n_shared:
+            Fs = conf.n_shared * F
+            params.update(Ws_gate=w(k[4], (D, Fs), D, Fs),
+                          Ws_up=w(k[5], (D, Fs), D, Fs),
+                          Ws_down=w(k[6], (Fs, O), Fs, O))
+        return params, {}
+
+    def apply_counted(self, conf, params, x, valid=None):
+        """-> (y like x, counts): what `apply` computes, with the
+        dispatch's counters (the serving steps of nn/decode.py hand them
+        home in the fetch they already make)."""
+        shape = x.shape
+        y, counts = dropless_moe(conf, params, x.reshape(-1, shape[-1]),
+                                 None if valid is None else valid.reshape(-1))
+        return y.reshape(*shape[:-1], y.shape[-1]), counts
+
+    def apply(self, conf, params, state, x, *, train=False, rng=None,
+              mask=None):
+        if conf.dropout:
+            x = apply_dropout(x, conf.dropout, rng, train=train)
+        valid = mask if mask is not None and x.ndim == 3 else None
+        return self.apply_counted(conf, params, x, valid)[0], state

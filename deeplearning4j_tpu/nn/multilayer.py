@@ -647,6 +647,15 @@ class MultiLayerNetwork:
 
         return init_cache(self, batch, capacity, kv_dtype, page_size)
 
+    def kv_cache_specs(self, capacity: int, kv_dtype: str = "f32",
+                       page_size: int = 16) -> dict:
+        """{layer: {array: (shape of one slot, dtype name)}}: each
+        attention layer's own cache spec (nn/decode.cache_specs), what
+        `init_kv_cache` allocates and the serving allocator bills."""
+        from deeplearning4j_tpu.nn.decode import cache_specs
+
+        return cache_specs(self, capacity, kv_dtype, page_size)
+
     def score(self, dataset: DataSet = None, training: bool = False):
         """Loss on a dataset (reference score()). training=False uses
         inference-mode forward (BatchNorm running stats, no dropout)."""

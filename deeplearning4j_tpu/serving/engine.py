@@ -778,21 +778,33 @@ class _GenWorker:
         step_raw = net.incremental_decode_fn(plan.kv_dtype,
                                              plan.page_size)
 
+        # a net with counting layers (the dropless expert layer) makes
+        # its steps return a third value, the counters of `.counters`:
+        # they ride home behind the tokens, in the one array the host
+        # fetches anyway (`_split_fetch` takes them off again)
+        self.step_counters = tuple(step_raw.counters)
+
+        def fetched(out):
+            tok = jnp.argmax(out[0], axis=-1).astype(jnp.int32)
+            if self.step_counters:
+                tok = jnp.concatenate([tok.reshape(-1), out[2]])
+            return tok, out[1]
+
         def counted_prefill(params, state, cache, padded_tokens,
                             bucket_kmask, rows, start, last_idx):
             with self._mu:  # trace-time bump: the retrace tell
                 self.trace_count += 1
-            probs, cache = prefill_raw(params, state, cache,
+            return fetched(prefill_raw(params, state, cache,
                                        padded_tokens, bucket_kmask,
-                                       rows, start, last_idx)
-            return jnp.argmax(probs, axis=-1).astype(jnp.int32), cache
+                                       rows, start, last_idx))
 
-        def counted_step(params, state, cache, padded_tokens, pos):
+        # `*live`: the occupied rows, which a net with counting layers
+        # is told (`_live`); nothing for any other net
+        def counted_step(params, state, cache, padded_tokens, pos, *live):
             with self._mu:
                 self.trace_count += 1
-            probs, cache = step_raw(params, state, cache, padded_tokens,
-                                    pos)
-            return jnp.argmax(probs, axis=-1).astype(jnp.int32), cache
+            return fetched(step_raw(params, state, cache, padded_tokens,
+                                    pos, *live))
 
         # argument 2 is the cache: donated, so the steps' scatters write
         # in place into the buffers they were handed (class docstring)
@@ -804,17 +816,38 @@ class _GenWorker:
                                               plan.page_size)
 
             def counted_verify(params, state, cache, padded_windows,
-                               pos):
+                               pos, *live):
                 with self._mu:
                     self.trace_count += 1
-                probs, cache = verify_raw(params, state, cache,
-                                          padded_windows, pos)
                 # [B, k] argmax rows: the acceptance mask's input —
                 # k verification verdicts for one batch-boundary fetch
-                return (jnp.argmax(probs, axis=-1).astype(jnp.int32),
-                        cache)
+                return fetched(verify_raw(params, state, cache,
+                                          padded_windows, pos, *live))
 
             self._verify_jit = jax.jit(counted_verify, donate_argnums=2)
+
+    def _live(self, active=()) -> tuple:
+        """The decode or verify step's last argument for a net with
+        counting layers: ([n_slots] True for the occupied rows,), so
+        that an idle row, which is fed the scratch position, selects no
+        expert and counts for nothing. () for any other net: its steps
+        take no such argument."""
+        if not self.step_counters:
+            return ()
+        live = np.zeros(self.plan.n_slots, bool)
+        live[list(active)] = True
+        return (live,)
+
+    def _split_fetch(self, fetched, shape: tuple, span) -> np.ndarray:
+        """The step's tokens, in `shape`, out of the fetched array; the
+        counters behind them (a net with counting layers) become fields
+        of the step's span: `moe_pairs`, `moe_rows`, `moe_max_load`."""
+        n = len(self.step_counters)
+        if not n:
+            return fetched
+        span.update(**{k: int(v) for k, v in
+                       zip(self.step_counters, fetched[-n:])})
+        return fetched[:-n].reshape(shape)
 
     # ---------------------------------------------------------- planning
     def chunk_buckets(self) -> list:
@@ -873,7 +906,7 @@ class _GenWorker:
                                         replica=self.index, warmup=True):
                     tok, self.cache = self._verify_jit(
                         ws.params, ws.state, self.cache,
-                        np.zeros((B, K), np.int32), scratch)
+                        np.zeros((B, K), np.int32), scratch, *self._live())
                     np.asarray(tok)  # batch-boundary fetch
                 self._seen_shapes.add("verify")
                 compiles += 1
@@ -881,7 +914,7 @@ class _GenWorker:
                     "verify", [B, K, self.plan.capacity],
                     self._verify_jit,
                     (ws.params, ws.state, self.cache,
-                     np.zeros((B, K), np.int32), scratch))
+                     np.zeros((B, K), np.int32), scratch, *self._live()))
         elif "decode" not in self._seen_shapes:
             B = self.plan.n_slots
             scratch = np.full(B, self.plan.capacity - 1, np.int32)
@@ -890,14 +923,15 @@ class _GenWorker:
                                     replica=self.index, warmup=True):
                 tok, self.cache = self._decode_jit(
                     ws.params, ws.state, self.cache,
-                    np.zeros(B, np.int32), scratch)
+                    np.zeros(B, np.int32), scratch, *self._live())
                 np.asarray(tok)  # batch-boundary fetch
             self._seen_shapes.add("decode")
             compiles += 1
             self.costbook.record("decode", [B, self.plan.capacity],
                                  self._decode_jit,
                                  (ws.params, ws.state, self.cache,
-                                  np.zeros(B, np.int32), scratch))
+                                  np.zeros(B, np.int32), scratch,
+                                  *self._live()))
         return compiles
 
     # --------------------------------------------------------- admission
@@ -1005,12 +1039,13 @@ class _GenWorker:
         try:
             with rec.span("prefill_chunk", bucket=[1, Tc],
                           start=slot.start, replica=self.index,
-                          final=final, n_real=n_real), compiling:
+                          final=final, n_real=n_real) as step, compiling:
                 with rec.span("dispatch"):
                     tok, self.cache = self._prefill_jit(
                         ws.params, ws.state, handed, *inputs)
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # batch-boundary fetch
+                toks = self._split_fetch(toks, (1,), step)
         except Exception as exc:
             self._fail_step(handed, [slot_idx], exc, clock)
             return
@@ -1057,16 +1092,18 @@ class _GenWorker:
             self.current_batch = list(active)
         try:
             with rec.span("decode_step", replica=self.index,
-                          n_active=len(active), slots=self.current_batch):
+                          n_active=len(active),
+                          slots=self.current_batch) as step:
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
                 with rec.span("dispatch"):
                     tok, self.cache = self._decode_jit(
                         ws.params, ws.state, handed,
-                        padded_tokens, pos)
+                        padded_tokens, pos, *self._live(active))
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # batch-boundary fetch
+                toks = self._split_fetch(toks, (B,), step)
         except ReplicaKilled as exc:
             # injected mid-decode death: every active slot fails (pages
             # released by _fail_slot), the thread dies; the supervisor
@@ -1142,16 +1179,17 @@ class _GenWorker:
         try:
             with rec.span("verify_step", replica=self.index,
                           n_active=len(active), k=K,
-                          slots=self.current_batch):
+                          slots=self.current_batch) as step:
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
                 with rec.span("dispatch"):
                     tok, self.cache = self._verify_jit(
                         ws.params, ws.state, handed,
-                        padded_windows, pos)
+                        padded_windows, pos, *self._live(active))
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # [B, k] batch-boundary fetch
+                toks = self._split_fetch(toks, (B, K), step)
         except ReplicaKilled as exc:
             # same containment contract as the plain decode step
             self.current_batch = None
@@ -1482,7 +1520,7 @@ class GenerationEngine:
         recorder.meta(role="generation-engine",
                       replicas=len(self._workers),
                       lattice=lattice.describe(),
-                      cache=self.plan.describe(),
+                      cache=self.plan.describe(net),
                       prefill_chunk=chunk,
                       speculative_k=self.speculative_k,
                       restored_step=self.restored_step)
@@ -1606,7 +1644,7 @@ class GenerationEngine:
             "trace_count": self.trace_count,
             "restored_step": self.restored_step,
             "lattice": self.lattice.describe(),
-            "cache": self.plan.describe(),
+            "cache": self.plan.describe(self.net),
             "page_pools": pools,
             "fleet": [w.describe(now) for w in self._workers],
             "weights": self.weights.describe(),

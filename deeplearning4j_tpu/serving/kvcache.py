@@ -25,12 +25,14 @@ allocation.
   resident pages is more HBM left for replicas) reconstructs from those
   events alone.
 
-* The cache DTYPE is part of the accounting (r16): an ``int8`` paged
-  cache stores 1-byte codes plus one f32 scale per (page, head), so a
-  slot's HBM bill shrinks ~4x vs f32 — `bytes_per_slot` is the single
-  home for that arithmetic, and the replay artifact's
-  ``slots_per_hbm_byte`` uplift row (gate: >= 1.8x) is computed from
-  it, not re-derived ad hoc.
+* What a slot costs is read from the attention layers' OWN cache specs
+  (nn/decode.cache_specs: {layer: {array: (shape of one slot, dtype
+  name)}}): keys and values in the net's compute dtype, int8 codes plus
+  one f32 scale per (page, head) under ``kv_dtype="int8"``, one latent
+  row a position for a latent-attention layer. `bytes_per_slot` is the
+  single home for that arithmetic: it bills exactly the arrays the
+  device holds, and the replay artifact's ``slots_per_hbm_byte`` uplift
+  row (gate: >= 1.8x) is computed from it, not re-derived ad hoc.
 
 Pure stdlib: importable under the graftlint AST stage's no-jax stubs.
 """
@@ -54,22 +56,36 @@ def validate_kv_dtype(kv_dtype: str) -> str:
     return kv_dtype
 
 
-def bytes_per_slot(capacity: int, attention_specs, kv_dtype: str = "f32",
-                   page_size: int = DEFAULT_PAGE_SIZE) -> int:
-    """HBM bytes one decode slot's K+V rows cost across all attention
-    layers. `attention_specs` is the nn/decode.py list of
-    (name, n_heads, head_dim). f32: capacity*H*D*4 per tensor. int8:
-    1-byte codes plus one f32 scale per (page, head) per tensor."""
-    validate_kv_dtype(kv_dtype)
-    total = 0
-    for _name, H, D in attention_specs:
-        if kv_dtype == "f32":
-            per_tensor = capacity * H * D * 4
-        else:
-            per_tensor = (capacity * H * D
-                          + (capacity // int(page_size)) * H * 4)
-        total += 2 * per_tensor  # K and V
-    return total
+_ITEMSIZE = {"float32": 4, "bfloat16": 2, "float16": 2, "int8": 1}
+
+
+def _nbytes(shape, dtype: str) -> int:
+    n = _ITEMSIZE[dtype]
+    for dim in shape:
+        n *= int(dim)
+    return n
+
+
+def bytes_per_slot(cache_specs: dict) -> int:
+    """HBM bytes one decode slot's cache rows cost across all attention
+    layers: the bytes of every array of `cache_specs` (nn/decode.py:
+    {layer: {array: (shape of one slot, dtype name)}}), which is what
+    `init_cache` allocates a slot of."""
+    return sum(_nbytes(shape, dtype) for arrays in cache_specs.values()
+               for shape, dtype in arrays.values())
+
+
+def row_kinds(cache_specs: dict, capacity: int) -> dict:
+    """{array name: bytes one position holds over all layers that keep
+    such an array}: the kinds of row in the cache ("k", "v"; "ckv", "kpe"
+    for a latent row) for the engine's `meta` event and /stats. An array
+    with fewer entries than positions (a page's scale) is billed to the
+    positions it covers."""
+    out: dict = {}
+    for arrays in cache_specs.values():
+        for name, (shape, dtype) in arrays.items():
+            out[name] = out.get(name, 0) + _nbytes(shape, dtype) / capacity
+    return {k: round(v, 3) for k, v in out.items()}
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:
@@ -168,10 +184,15 @@ class CachePlan:
         self.pool_pages = (self.n_slots * self.pages_per_slot
                            if pool_pages is None else int(pool_pages))
 
-    def bytes_per_slot(self, attention_specs) -> int:
+    def cache_specs(self, net) -> dict:
+        """The net's attention layers' cache specs at this plan's
+        geometry (nn/decode.cache_specs)."""
+        return net.kv_cache_specs(self.capacity, self.kv_dtype,
+                                  self.page_size)
+
+    def bytes_per_slot(self, net) -> int:
         """This plan's per-slot HBM bill (see module `bytes_per_slot`)."""
-        return bytes_per_slot(self.capacity, attention_specs,
-                              self.kv_dtype, self.page_size)
+        return bytes_per_slot(self.cache_specs(net))
 
     def make_pool(self) -> PagePool:
         return PagePool(self.pool_pages, self.page_size)
@@ -182,10 +203,18 @@ class CachePlan:
         length, so accounting and shapes stay on the same lattice."""
         return pages_for(prompt_bucket + max_new, self.page_size)
 
-    def describe(self) -> dict:
-        return {"n_slots": self.n_slots, "capacity": self.capacity,
-                "page_size": self.page_size,
-                "pages_per_slot": self.pages_per_slot,
-                "pool_pages": self.pool_pages,
-                "max_new_tokens": self.max_new_tokens,
-                "kv_dtype": self.kv_dtype}
+    def describe(self, net=None) -> dict:
+        """The geometry; with `net`, also what its layers keep in it:
+        `rows` ({kind of row: bytes a token over all layers}) and
+        `bytes_per_token`, their sum."""
+        out = {"n_slots": self.n_slots, "capacity": self.capacity,
+               "page_size": self.page_size,
+               "pages_per_slot": self.pages_per_slot,
+               "pool_pages": self.pool_pages,
+               "max_new_tokens": self.max_new_tokens,
+               "kv_dtype": self.kv_dtype}
+        if net is not None:
+            rows = row_kinds(self.cache_specs(net), self.capacity)
+            out["rows"] = rows
+            out["bytes_per_token"] = round(sum(rows.values()), 3)
+        return out

@@ -615,7 +615,6 @@ def run_speculative_replay(*, seed: int = 0, n_requests: int = 24,
     to its own telemetry file (`<path>.<arm>`) and reconstructs from it
     alone. rc semantics as `run_replay`: parity failures are REPORTED
     rows, not raises — the committed-artifact gate is benchdiff's."""
-    from deeplearning4j_tpu.nn.decode import attention_specs
     from deeplearning4j_tpu.serving.buckets import BucketLattice
     from deeplearning4j_tpu.serving.engine import GenerationEngine
     from deeplearning4j_tpu.serving.kvcache import CachePlan, bytes_per_slot
@@ -698,9 +697,10 @@ def run_speculative_replay(*, seed: int = 0, n_requests: int = 24,
     # the int8 cache, from the SAME plan the engines served under
     plan = CachePlan(max(prompt_lengths), max(output_lengths),
                      n_slots=slots, page_size=page_size)
-    specs = attention_specs(net)
-    f32_bytes = bytes_per_slot(plan.capacity, specs, "f32", page_size)
-    int8_bytes = bytes_per_slot(plan.capacity, specs, "int8", page_size)
+    f32_bytes = bytes_per_slot(
+        net.kv_cache_specs(plan.capacity, "f32", page_size))
+    int8_bytes = bytes_per_slot(
+        net.kv_cache_specs(plan.capacity, "int8", page_size))
     ratio = round(f32_bytes / int8_bytes, 4)
     lines.append(
         {"metric": "serving_quantized_slots_per_hbm_byte_x",

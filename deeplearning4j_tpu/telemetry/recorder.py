@@ -73,7 +73,19 @@ decode_step when the engine runs with
 the acceptance accounting); their first execution per shape nests a
 `compile` span exactly like the predict path, and the
 flat-across-prompt-buckets property of the decode_step timings is the
-"decode cost independent of prompt length" gate in tier-1.
+"decode cost independent of prompt length" gate in tier-1. Where the
+served net has a counting layer (the dropless expert layer,
+nn/layers/moe.py `DroplessMoELayer`), all three carry what the step's
+program counted, handed home behind the tokens in the one fetch:
+`moe_pairs` (the (token, held selected expert) pairs the step had to
+compute, over all expert layers; the pad of a bucket and idle slots
+select nothing), `moe_rows` (the expert rows it did compute for them:
+rounds x held experts x rows a round, padding included) and
+`moe_max_load` (the most pairs on one held expert in one layer). The
+engine's `meta` event (`cache`) and /stats give, from the attention
+layers' own cache specs, `rows` ({kind of cache row: bytes a token over
+all layers}: "k" and "v", or "ckv" and "kpe" for a latent row) and
+`bytes_per_token`.
 
 The engine thread's host loop (serving/engine.py `_GenWorker`) is named
 whole: every instant between two model steps lies in one of six LEAF

@@ -443,10 +443,17 @@ def profile_closed(closed, name: str):
                                  "downcast)",
                                  f"chain-subf32:{prim}:{_dt(out)}")
 
-            elif prim == "scan":
-                ncarry = eqn.params.get("num_carry", 0)
-                nconst = eqn.params.get("num_consts", 0)
-                body = eqn.params.get("jaxpr")
+            elif prim in ("scan", "while"):
+                # a `while` (a fori_loop with a traced bound: the decode
+                # walk over live key blocks) carries all its outputs
+                if prim == "scan":
+                    ncarry = eqn.params.get("num_carry", 0)
+                    nconst = eqn.params.get("num_consts", 0)
+                    body = eqn.params.get("jaxpr")
+                else:
+                    nconst = eqn.params.get("body_nconsts", 0)
+                    body = eqn.params.get("body_jaxpr")
+                    ncarry = len(eqn.outvars)
                 inner = getattr(body, "jaxpr", body)
                 if inner is not None and hasattr(inner, "outvars"):
                     body_prod = _producers(inner)

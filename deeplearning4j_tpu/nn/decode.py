@@ -12,8 +12,10 @@ contract for transformer stacks, on BOTH containers:
   token per cache row, positions per row (continuous batching mixes
   rows at different depths), the KV cache threaded as explicit state.
   Attention is single-query against the cache
-  (ops/decode_attention.py, `decode_attn` autotune family), so the
-  step's cost is independent of how much prompt each row has.
+  (ops/decode_attention.py, `decode_attn` autotune family): it reads
+  the cache where it lies, a key block at a time, up to the last block
+  a live row of the batch can see, so the step's cost follows the
+  longest live row and not the cache's capacity.
 * ``make_prefill_fn(net)`` — the chunked-prefill body
   ``(params, state, cache, tokens, kmask, rows, start, last_idx) ->
   (probs_last, cache)``: fills cache rows with a prompt chunk's K/V and
@@ -21,8 +23,10 @@ contract for transformer stacks, on BOTH containers:
   reuses the autotuned flash kernels when the chunk is inside their
   envelope (flash_attention_lse_masked — the same dispatch discipline
   as training); the cross-chunk half (chunk queries against the
-  already-written cache prefix) runs through `cache_attention`, and the
-  two merge by the standard two-way LSE combine. `start` is per-row, so
+  already-written cache prefix, the chunk's rows taken from each key
+  block; no block at all for a first chunk) runs through
+  `cache_attention`, and the two merge by the standard two-way LSE
+  combine. `start` is per-row, so
   a long prompt prefills in several bucket-shaped calls — the serving
   engine interleaves decode steps between them.
 * ``make_verify_fn(net)`` — the SPECULATIVE verification body
@@ -55,14 +59,16 @@ an int32 vector in the order of the fn's ``counters`` attribute (empty,
 and two values returned, for a net without such a layer). The decode and
 verify fns take one more, optional argument, ``live`` [B] bool: the rows
 that hold a request, by the caller's word (the serving engine pads its
-batch with idle rows); a counting layer computes and counts nothing for
-the others. Without it every row is real.
+batch with idle rows); the others attend no key (key_limit 0, so an
+idle row's scratch position never lengthens the walk over the cache)
+and a counting layer computes and counts nothing for them. Without it
+every row is real.
 
 All three entry fns (and ``init_cache``) take ``kv_dtype`` ("f32" |
 "int8") and ``page_size``: the int8 paged cache stores codes plus
 per-(row, page, head) f32 scales (``{"k", "k_scale", "v", "v_scale"}``
 entries), writes through ops/decode_attention.quantized_cache_update,
-and attends through `cache_attention_q8` (dequantize-in-the-scan) —
+and attends through `cache_attention_q8` (dequantize as a block loads) —
 ~4x less HBM per slot, gated on greedy-sequence parity vs the f32
 cache in the serving replay.
 
@@ -97,6 +103,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     SelfAttentionLayer,
 )
 from deeplearning4j_tpu.nn.training import tree_cast
+from deeplearning4j_tpu.ops import autotune
 from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.ops.decode_attention import (
     cache_attention,
@@ -249,6 +256,25 @@ def init_cache(net, batch: int, capacity: int, kv_dtype: str = "f32",
                                             page_size).items()}
 
 
+def walk_block(net, capacity: int, kv_dtype: str = "f32",
+               page_size: int = 16) -> int | None:
+    """The key-block length in which the net's cached attention walks a
+    cache of `capacity` positions (ops/decode_attention.py resolves it
+    from the capacity and the head size alone, so the host can know how
+    many blocks a step visits without asking the program): that of the
+    net's first `SelfAttentionLayer`. None where no layer walks the
+    cache in blocks (a layer that owns its cached forward reads its
+    entry its own way)."""
+    _, _, ops = _plan(net)
+    for op in ops:
+        if op.kind == "layer" and isinstance(op.conf, SelfAttentionLayer):
+            D = op.conf.n_out // op.conf.n_heads
+            if kv_dtype == "int8":
+                return autotune.decode_block_q8(capacity, D, page_size)
+            return autotune.decode_block(capacity, D)
+    return None
+
+
 class CacheStep:
     """What a layer that owns its cache entry is told about the serving
     step it is called in: `rows` [b] the cache rows the call's batch
@@ -287,18 +313,25 @@ def _cache_write(entry, k_new, v_new, rows, positions, kv_dtype,
 
 def _cache_attend(entry, qh, key_limit, kv_dtype, page_size, rows=None):
     """Attend qh [b, H, Tq, D] against a cache entry with per-query
-    visible-key bounds — dtype-dispatched. `rows` gathers a row subset
-    first (the prefill cross-chunk path)."""
+    visible-key bounds — dtype-dispatched. `rows` [b] names the cache
+    rows the queries attend (the prefill cross-chunk path); they are
+    taken from each key block as the walk loads it, never gathered from
+    the whole cache."""
     if kv_dtype == "int8":
-        k, v = entry["k"], entry["v"]
-        ks, vs = entry["k_scale"], entry["v_scale"]
-        if rows is not None:
-            k, v, ks, vs = k[rows], v[rows], ks[rows], vs[rows]
-        return cache_attention_q8(qh, k, v, ks, vs, key_limit, page_size)
-    k, v = entry["k"], entry["v"]
-    if rows is not None:
-        k, v = k[rows], v[rows]
-    return cache_attention(qh, k, v, key_limit)
+        return cache_attention_q8(qh, entry["k"], entry["v"],
+                                  entry["k_scale"], entry["v_scale"],
+                                  key_limit, page_size, rows)
+    return cache_attention(qh, entry["k"], entry["v"], key_limit, rows)
+
+
+def _live_limit(live, key_limit):
+    """`key_limit` [B, T] with the rows `live` [B] does not mark set to
+    0: an idle row (fed the scratch position, whose limit would be the
+    whole capacity) sees no key, so it never lengthens the walk over the
+    cache's blocks. `live` None: every row is real."""
+    if live is None:
+        return key_limit
+    return jnp.where(jnp.asarray(live, bool)[:, None], key_limit, 0)
 
 
 # ------------------------------------------------------------ shared math
@@ -517,8 +550,9 @@ def make_decode_fn(net, kv_dtype: str = "f32", page_size: int = 16):
                 kv_dtype, page_size)
             new_cache[name] = entry
             qh = q.reshape(B, H, 1, Dh)
-            o, _ = _cache_attend(entry, qh, (pos + 1)[:, None],
-                                 kv_dtype, page_size)
+            o, _ = _cache_attend(
+                entry, qh, _live_limit(live, (pos + 1)[:, None]),
+                kv_dtype, page_size)
             y = o[:, :, 0, :].reshape(B, n) @ p["Wo"] + p["bo"]
             return get_activation(conf.activation or "identity")(
                 y)[:, None, :]
@@ -643,8 +677,9 @@ def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
                 rows, positions, kv_dtype, page_size)
             new_cache[name] = entry
             qh = _split_heads(q, H).transpose(0, 2, 1, 3)  # [B, H, K, Dh]
-            o, _ = _cache_attend(entry, qh, positions + 1, kv_dtype,
-                                 page_size)
+            o, _ = _cache_attend(
+                entry, qh, _live_limit(live, positions + 1), kv_dtype,
+                page_size)
             y = o.transpose(0, 2, 1, 3).reshape(B, K, n)
             y = y @ p["Wo"] + p["bo"]
             return get_activation(conf.activation or "identity")(y)

@@ -107,11 +107,14 @@ DEFAULT_NEG_SOFTMAX_ROW_BLOCK = 128
 
 # Decode-attention key block (r11): single-query attention against a
 # paged KV cache streams the cache in blocks of block_k key positions
-# (page multiples) with a running-max/lse merge. The default cap keeps
-# a block's [bk, D] K/V slice plus the f32 score strip well inside
-# VMEM at every served head dim; the cap feeds a divisor search over
-# the cache capacity S (which is page-quantized, so divisors exist).
-DEFAULT_DECODE_BLOCK_K = 512
+# (page multiples) with a running-max/lse merge, and stops at the last
+# block a live row can see: a shorter block reads fewer rows past the
+# longest live row and costs more loop passes. The cap feeds a divisor
+# search over the cache capacity S (which is page-quantized, so
+# divisors exist). 128 is the winner of a sweep of 64 / 128 / 256 / 320
+# on a v5e (16 slots of 1,280 positions, heads of 128, rows filled to
+# 80-380: PERF.md section 6, PR 32); all four lie within 3 %.
+DEFAULT_DECODE_BLOCK_K = 128
 
 # Kernel-proven chunk-tile lengths for the long-context loop, largest
 # first (the single home for the tiling envelope quoted in error
@@ -395,8 +398,10 @@ def chunk_tile(T: int, D: int | None, *, causal: bool, dropout: bool,
 
 
 def decode_block(S: int, D: int) -> int:
-    """Key-block length for single-query decode attention against a
-    cache of capacity S (ops/decode_attention.py). The tuned value must
+    """Key-block length in which attention walks a cache of capacity S
+    (ops/decode_attention.py): the walk stops at the last block a live
+    query can see, so a shorter block bounds the live rows more finely
+    and costs more loop passes. The tuned value must
     divide S (the cache capacity is page-quantized, so page-multiple
     candidates always divide); any miss falls back to the largest
     divisor of S within the swept cap — deterministic, so off-TPU runs

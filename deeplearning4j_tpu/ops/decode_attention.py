@@ -16,22 +16,32 @@ are structurally different from training attention:
   against the already-written cache prefix, returning (out, lse) so the
   caller can LSE-merge with the within-chunk flash result.
 
-Implementation is a blocked lax.scan over key blocks with the standard
-flash running-max/sum merge — an XLA-level kernel whose block_k is the
-tuning knob (a hand-written Pallas single-query kernel would slot in
-behind the same dispatch). Off-TPU the tuning table is inactive
-(autotune.table_active), so interpret/CPU runs always use the
+Implementation (`_walk_live_blocks`) is one loop over key blocks with
+the standard flash running-max/sum merge, an XLA-level kernel whose
+block_k is the tuning knob. The loop takes block j out of the cache
+WHERE IT LIES (a dynamic slice along the position axis of [B, S, H, D];
+the cache is never relaid or copied) and its bound is traced:
+ceil(max(key_limit) / block_k) blocks, so one compiled program reads
+only the blocks some query of the call can see, whatever the cache's
+capacity. A caller that pads its batch with idle rows gives them
+key_limit 0 (nn/decode.py does, on the serving engine's word), or the
+idle rows' scratch position keeps the bound at the whole capacity. A
+row subset (`rows`: the prefill's cross-chunk half) is taken from each
+block, not gathered from the cache first. Off-TPU the tuning table is
+inactive (autotune.table_active), so interpret/CPU runs always use the
 deterministic divisor-search default — bit-identical to the fallback by
-construction. Scores accumulate in f32 regardless of cache dtype.
+construction. Scores and the running state are f32 regardless of cache
+dtype.
 
 INT8 QUANTIZED CACHE (r16): the `*_q8` twins read a cache stored as
 int8 codes plus one f32 scale per (row, page, head) — per-page
 symmetric quantization, scale = maxabs/127, so a page of K (or V)
 costs page_size*D bytes instead of page_size*D*4 and HBM streaming
 shrinks ~4x (slots per HBM byte is the serving headline this feeds).
-Dequantization happens INSIDE the blocked scan body — a code block
+Dequantization happens as the walk LOADS a block — a code block
 [bk, D] times its page scales, straight into the f32 score dot — so
-the quantized path streams codes, never a materialized f32 cache. The
+the quantized path streams codes, never a materialized f32 cache; the
+walk itself is the one the bf16/f32 cache uses. The
 `decode_attn_q8` tuning family constrains block_k to page multiples
 (a block may not split a page's scale broadcast). Cache WRITES go
 through `quantized_cache_update`: gather the page-aligned window
@@ -55,62 +65,91 @@ from deeplearning4j_tpu.ops import autotune
 _NEG_INF = -1e30
 
 
-@functools.partial(jax.jit, static_argnames=("block_k",))
-def _cache_attention_blocked(q, k, v, key_limit, block_k):
-    """q [B, H, Tq, D]; k, v [B, S, H, D] (cache layout: key position is
-    the second axis so per-position scatter writes are contiguous);
-    key_limit [B, Tq] — key j is visible to query (b, t) iff
-    j < key_limit[b, t]. Returns (out [B, H, Tq, D] in q.dtype,
-    lse [B, H, Tq] f32). All-masked rows produce a zero block and an
-    lse at the mask floor, which a downstream lse merge weighs away."""
-    B, S, H, D = k.shape
-    Tq = q.shape[2]
-    nb = S // block_k
+def _take_block(x, j, length, rows):
+    """Block `j` of `length` positions out of x [B, S, ...] where it
+    lies (a slice along axis 1, no copy of x), then the row subset
+    `rows` [b] of that block (None: every row, in order)."""
+    blk = jax.lax.dynamic_slice_in_dim(x, j * length, length, axis=1)
+    return blk if rows is None else jnp.take(blk, rows, axis=0)
+
+
+def _walk_live_blocks(q, load_block, key_limit, S, block_k):
+    """The one walk over key blocks, shared by the bf16/f32 cache and
+    its int8 twin. q [b, H, Tq, D]; `load_block(j)` hands back block j's
+    keys and values as float32 [b, block_k, H, D], the cache's own
+    layout (the body names a block's axes head-major for its two
+    products; that is a relayout of one block at the most, which XLA
+    folds into the products, never of the cache); key_limit [b, Tq] —
+    key j is visible to query (r, t) iff j < key_limit[r, t].
+
+    The loop's bound is traced: ceil(max(key_limit) / block_k) blocks,
+    at most S // block_k, so ONE program serves every fill of the cache
+    and a block no query of the call can see is never read. Such a block
+    would add exp(-1e30 - m) = 0 with alpha = 1 to every query that has
+    seen a key, so leaving it out changes no bit of the result. A query
+    that sees no key at all (key_limit 0: an idle row, a first prefill
+    chunk's cross-chunk half) gets a zero row and an lse at the mask
+    floor, which a downstream lse merge weighs away.
+
+    Returns (out [b, H, Tq, D] in q.dtype, lse [b, H, Tq] f32)."""
+    b, H, Tq, D = q.shape
     sm_scale = 1.0 / jnp.sqrt(jnp.float32(D))
     qf = q.astype(jnp.float32)
-    # [B, S, H, D] -> [nb, B, H, bk, D] so scan carries one block per step
-    kb = jnp.moveaxis(k.reshape(B, nb, block_k, H, D), 1, 0)
-    kb = kb.transpose(0, 1, 3, 2, 4)
-    vb = jnp.moveaxis(v.reshape(B, nb, block_k, H, D), 1, 0)
-    vb = vb.transpose(0, 1, 3, 2, 4)
+    n_live = jnp.clip((jnp.max(key_limit) + block_k - 1) // block_k,
+                      0, S // block_k)
 
-    m0 = jnp.full((B, H, Tq), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H, Tq), jnp.float32)
-    acc0 = jnp.zeros((B, H, Tq, D), jnp.float32)
+    m0 = jnp.full((b, H, Tq), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((b, H, Tq), jnp.float32)
+    acc0 = jnp.zeros((b, H, Tq, D), jnp.float32)
 
-    def body(carry, blk):
-        m, l, acc, j0 = carry
-        k_j, v_j = blk
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_j.astype(jnp.float32),
+    def body(j, carry):
+        m, l, acc = carry
+        k_j, v_j = load_block(j)
+        s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_j.transpose(0, 2, 1, 3),
                        preferred_element_type=jnp.float32) * sm_scale
-        idx = j0 + jnp.arange(block_k)
+        idx = j * block_k + jnp.arange(block_k)
         visible = idx[None, None, None, :] < key_limit[:, None, :, None]
         s = jnp.where(visible, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
+        # the select matters only while a query has seen no key yet
+        # (m_new still at the floor, where exp(s - m_new) would be 1)
+        p = jnp.where(visible, jnp.exp(s - m_new[..., None]), 0.0)
         l_new = l * alpha + p.sum(-1)
         acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhqk,bhkd->bhqd", p, v_j.astype(jnp.float32),
+            "bhqk,bhkd->bhqd", p, v_j.transpose(0, 2, 1, 3),
             preferred_element_type=jnp.float32)
-        return (m_new, l_new, acc_new, j0 + block_k), None
+        return m_new, l_new, acc_new
 
-    (m, l, acc, _), _ = jax.lax.scan(
-        body, (m0, l0, acc0, jnp.int32(0)), (kb, vb))
+    m, l, acc = jax.lax.fori_loop(0, n_live, body, (m0, l0, acc0))
     out = jnp.where(l[..., None] > 0.0, acc / jnp.maximum(l, 1e-30)[..., None],
                     0.0)
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     return out.astype(q.dtype), lse
 
 
-def cache_attention(q, k, v, key_limit):
+@functools.partial(jax.jit, static_argnames=("block_k",))
+def _cache_attention_blocked(q, k, v, key_limit, block_k, rows=None):
+    """q [b, H, Tq, D]; k, v [B, S, H, D] (cache layout: key position is
+    the second axis so per-position scatter writes are contiguous);
+    key_limit [b, Tq]; rows [b] the cache rows the queries attend (None:
+    b == B, row for row). The walk is `_walk_live_blocks`: each block is
+    sliced out of k and v where they lie, and only of `rows`."""
+    def load_block(j):
+        return (_take_block(k, j, block_k, rows).astype(jnp.float32),
+                _take_block(v, j, block_k, rows).astype(jnp.float32))
+
+    return _walk_live_blocks(q, load_block, key_limit, k.shape[1], block_k)
+
+
+def cache_attention(q, k, v, key_limit, rows=None):
     """Multi-query attention over a KV cache with a per-query visible-key
     bound. Shapes as `_cache_attention_blocked`; block_k resolves through
     the `decode_attn` tuning-table family (off-TPU: the deterministic
     divisor-search default — bit-identical fallback)."""
     S, D = k.shape[1], k.shape[3]
     bk = autotune.decode_block(S, D)
-    return _cache_attention_blocked(q, k, v, key_limit, bk)
+    return _cache_attention_blocked(q, k, v, key_limit, bk, rows)
 
 
 def decode_attention(q, k, v, pos):
@@ -205,61 +244,30 @@ def quantized_cache_update(codes, scales, new_vals, rows, positions,
 
 @functools.partial(jax.jit, static_argnames=("block_k", "page_size"))
 def _cache_attention_blocked_q8(q, k_codes, v_codes, k_scale, v_scale,
-                                key_limit, block_k, page_size):
-    """The int8 twin of `_cache_attention_blocked`: identical scan and
-    running-max merge, but each key block arrives as int8 codes and is
-    dequantized in the body (code * per-page scale, f32) right before
-    the score dot. block_k is a page multiple so the [B, ppb, H] scale
-    slice broadcasts across whole pages."""
-    B, S, H, D = k_codes.shape
-    Tq = q.shape[2]
-    nb = S // block_k
+                                key_limit, block_k, page_size, rows=None):
+    """The int8 twin of `_cache_attention_blocked`: the same walk
+    (`_walk_live_blocks`), but each key block arrives as int8 codes and
+    is dequantized as it is loaded (code * per-page scale, f32) right
+    before the score dot. block_k is a page multiple so the [b, ppb, H]
+    scale slice broadcasts across whole pages."""
     ppb = block_k // page_size
-    sm_scale = 1.0 / jnp.sqrt(jnp.float32(D))
-    qf = q.astype(jnp.float32)
-    kb = jnp.moveaxis(k_codes.reshape(B, nb, block_k, H, D), 1, 0)
-    kb = kb.transpose(0, 1, 3, 2, 4)
-    vb = jnp.moveaxis(v_codes.reshape(B, nb, block_k, H, D), 1, 0)
-    vb = vb.transpose(0, 1, 3, 2, 4)
-    ksb = jnp.moveaxis(k_scale.reshape(B, nb, ppb, H), 1, 0)  # [nb,B,ppb,H]
-    vsb = jnp.moveaxis(v_scale.reshape(B, nb, ppb, H), 1, 0)
 
-    m0 = jnp.full((B, H, Tq), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H, Tq), jnp.float32)
-    acc0 = jnp.zeros((B, H, Tq, D), jnp.float32)
+    def load_block(j):
+        def dequantized(codes, scale):
+            # [b, ppb, H] -> [b, bk, H, 1]: one scale per page, per head
+            s = jnp.repeat(_take_block(scale, j, ppb, rows), page_size,
+                           axis=1)
+            return (_take_block(codes, j, block_k, rows).astype(jnp.float32)
+                    * s[..., None])
 
-    def body(carry, blk):
-        m, l, acc, j0 = carry
-        k_j, v_j, ks_j, vs_j = blk
-        # [B, ppb, H] -> [B, H, bk, 1]: one scale per page, per head
-        ks = jnp.repeat(ks_j, page_size, axis=1).transpose(0, 2, 1)
-        vs = jnp.repeat(vs_j, page_size, axis=1).transpose(0, 2, 1)
-        kf = k_j.astype(jnp.float32) * ks[..., None]
-        vf = v_j.astype(jnp.float32) * vs[..., None]
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf,
-                       preferred_element_type=jnp.float32) * sm_scale
-        idx = j0 + jnp.arange(block_k)
-        visible = idx[None, None, None, :] < key_limit[:, None, :, None]
-        s = jnp.where(visible, s, _NEG_INF)
-        m_new = jnp.maximum(m, s.max(-1))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l_new = l * alpha + p.sum(-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhqk,bhkd->bhqd", p, vf,
-            preferred_element_type=jnp.float32)
-        return (m_new, l_new, acc_new, j0 + block_k), None
+        return dequantized(k_codes, k_scale), dequantized(v_codes, v_scale)
 
-    (m, l, acc, _), _ = jax.lax.scan(
-        body, (m0, l0, acc0, jnp.int32(0)), (kb, vb, ksb, vsb))
-    out = jnp.where(l[..., None] > 0.0,
-                    acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
-    lse = m + jnp.log(jnp.maximum(l, 1e-30))
-    return out.astype(q.dtype), lse
+    return _walk_live_blocks(q, load_block, key_limit, k_codes.shape[1],
+                             block_k)
 
 
 def cache_attention_q8(q, k_codes, v_codes, k_scale, v_scale, key_limit,
-                       page_size: int):
+                       page_size: int, rows=None):
     """Multi-query attention over an int8 paged KV cache. Shapes as
     `_cache_attention_blocked_q8`; block_k resolves through the
     `decode_attn_q8` tuning family (page-multiple candidates; off-TPU
@@ -267,4 +275,5 @@ def cache_attention_q8(q, k_codes, v_codes, k_scale, v_scale, key_limit,
     S, D = k_codes.shape[1], k_codes.shape[3]
     bk = autotune.decode_block_q8(S, D, page_size)
     return _cache_attention_blocked_q8(q, k_codes, v_codes, k_scale,
-                                       v_scale, key_limit, bk, page_size)
+                                       v_scale, key_limit, bk, page_size,
+                                       rows)

@@ -673,7 +673,12 @@ class _GenWorker:
     token whose K/V write is routed to the scratch position
     (capacity - 1), which any real tenant overwrites before it can ever
     be attended (a token's own K/V lands at its position in the same
-    step that reads it).
+    step that reads it). The step is TOLD which rows are occupied
+    (`_live`): an idle row attends no key, so the walk over the cache's
+    key blocks (ops/decode_attention.py) ends at the last block an
+    occupied row can see, not at the scratch position's; a counting
+    layer computes nothing for it. Each step's span says how far the
+    walk went: `kv_blocks` of `kv_blocks_cap` (`_kv_blocks`).
 
     SPECULATIVE MODE (speculative_k >= 2): the decode step is replaced
     by a fixed-shape VERIFY step over [n_slots, k] token windows
@@ -774,6 +779,9 @@ class _GenWorker:
         self._closed = False
         self._thread: threading.Thread | None = None
 
+        from deeplearning4j_tpu.nn.decode import walk_block
+        self.kv_block = walk_block(net, plan.capacity, plan.kv_dtype,
+                                   plan.page_size)
         prefill_raw = net.prefill_fn(plan.kv_dtype, plan.page_size)
         step_raw = net.incremental_decode_fn(plan.kv_dtype,
                                              plan.page_size)
@@ -798,13 +806,12 @@ class _GenWorker:
                                        padded_tokens, bucket_kmask,
                                        rows, start, last_idx))
 
-        # `*live`: the occupied rows, which a net with counting layers
-        # is told (`_live`); nothing for any other net
-        def counted_step(params, state, cache, padded_tokens, pos, *live):
+        # `live`: the occupied rows (`_live`)
+        def counted_step(params, state, cache, padded_tokens, pos, live):
             with self._mu:
                 self.trace_count += 1
             return fetched(step_raw(params, state, cache, padded_tokens,
-                                    pos, *live))
+                                    pos, live))
 
         # argument 2 is the cache: donated, so the steps' scatters write
         # in place into the buffers they were handed (class docstring)
@@ -816,27 +823,39 @@ class _GenWorker:
                                               plan.page_size)
 
             def counted_verify(params, state, cache, padded_windows,
-                               pos, *live):
+                               pos, live):
                 with self._mu:
                     self.trace_count += 1
                 # [B, k] argmax rows: the acceptance mask's input —
                 # k verification verdicts for one batch-boundary fetch
                 return fetched(verify_raw(params, state, cache,
-                                          padded_windows, pos, *live))
+                                          padded_windows, pos, live))
 
             self._verify_jit = jax.jit(counted_verify, donate_argnums=2)
 
-    def _live(self, active=()) -> tuple:
-        """The decode or verify step's last argument for a net with
-        counting layers: ([n_slots] True for the occupied rows,), so
-        that an idle row, which is fed the scratch position, selects no
-        expert and counts for nothing. () for any other net: its steps
-        take no such argument."""
-        if not self.step_counters:
-            return ()
+    def _live(self, active=()) -> np.ndarray:
+        """The decode or verify step's last argument: [n_slots] True for
+        the occupied rows. An idle row is fed the scratch position; told
+        that it is idle, the step lets it attend no key (it would see
+        the whole capacity, and the walk over the cache's key blocks
+        would never end early) and a counting layer selects no expert
+        and counts nothing for it."""
         live = np.zeros(self.plan.n_slots, bool)
         live[list(active)] = True
-        return (live,)
+        return live
+
+    def _kv_blocks(self, key_limit: int) -> dict:
+        """A model step's span fields `kv_blocks` and `kv_blocks_cap`:
+        the key blocks its cached attention visits, given the largest
+        visible-key bound among the step's live queries, and the blocks
+        the capacity holds. Host arithmetic on positions the engine
+        already has: the program is not asked. {} for a net none of
+        whose layers walks the cache in blocks (`kv_block` None)."""
+        if not self.kv_block:
+            return {}
+        cap = self.plan.capacity // self.kv_block
+        return {"kv_blocks": min(-(-int(key_limit) // self.kv_block), cap),
+                "kv_blocks_cap": cap}
 
     def _split_fetch(self, fetched, shape: tuple, span) -> np.ndarray:
         """The step's tokens, in `shape`, out of the fetched array; the
@@ -906,7 +925,7 @@ class _GenWorker:
                                         replica=self.index, warmup=True):
                     tok, self.cache = self._verify_jit(
                         ws.params, ws.state, self.cache,
-                        np.zeros((B, K), np.int32), scratch, *self._live())
+                        np.zeros((B, K), np.int32), scratch, self._live())
                     np.asarray(tok)  # batch-boundary fetch
                 self._seen_shapes.add("verify")
                 compiles += 1
@@ -914,7 +933,7 @@ class _GenWorker:
                     "verify", [B, K, self.plan.capacity],
                     self._verify_jit,
                     (ws.params, ws.state, self.cache,
-                     np.zeros((B, K), np.int32), scratch, *self._live()))
+                     np.zeros((B, K), np.int32), scratch, self._live()))
         elif "decode" not in self._seen_shapes:
             B = self.plan.n_slots
             scratch = np.full(B, self.plan.capacity - 1, np.int32)
@@ -923,7 +942,7 @@ class _GenWorker:
                                     replica=self.index, warmup=True):
                 tok, self.cache = self._decode_jit(
                     ws.params, ws.state, self.cache,
-                    np.zeros(B, np.int32), scratch, *self._live())
+                    np.zeros(B, np.int32), scratch, self._live())
                 np.asarray(tok)  # batch-boundary fetch
             self._seen_shapes.add("decode")
             compiles += 1
@@ -931,7 +950,7 @@ class _GenWorker:
                                  self._decode_jit,
                                  (ws.params, ws.state, self.cache,
                                   np.zeros(B, np.int32), scratch,
-                                  *self._live()))
+                                  self._live()))
         return compiles
 
     # --------------------------------------------------------- admission
@@ -1039,7 +1058,8 @@ class _GenWorker:
         try:
             with rec.span("prefill_chunk", bucket=[1, Tc],
                           start=slot.start, replica=self.index,
-                          final=final, n_real=n_real) as step, compiling:
+                          final=final, n_real=n_real,
+                          **self._kv_blocks(slot.start)) as step, compiling:
                 with rec.span("dispatch"):
                     tok, self.cache = self._prefill_jit(
                         ws.params, ws.state, handed, *inputs)
@@ -1092,15 +1112,16 @@ class _GenWorker:
             self.current_batch = list(active)
         try:
             with rec.span("decode_step", replica=self.index,
-                          n_active=len(active),
-                          slots=self.current_batch) as step:
+                          n_active=len(active), slots=self.current_batch,
+                          **self._kv_blocks(
+                              pos[active].max() + 1)) as step:
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
                 with rec.span("dispatch"):
                     tok, self.cache = self._decode_jit(
                         ws.params, ws.state, handed,
-                        padded_tokens, pos, *self._live(active))
+                        padded_tokens, pos, self._live(active))
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # batch-boundary fetch
                 toks = self._split_fetch(toks, (B,), step)
@@ -1179,14 +1200,16 @@ class _GenWorker:
         try:
             with rec.span("verify_step", replica=self.index,
                           n_active=len(active), k=K,
-                          slots=self.current_batch) as step:
+                          slots=self.current_batch,
+                          **self._kv_blocks(
+                              pos[active].max() + K)) as step:
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
                 with rec.span("dispatch"):
                     tok, self.cache = self._verify_jit(
                         ws.params, ws.state, handed,
-                        padded_windows, pos, *self._live(active))
+                        padded_windows, pos, self._live(active))
                 with rec.span("fetch", follows=True):
                     toks = np.asarray(tok)  # [B, k] batch-boundary fetch
                 toks = self._split_fetch(toks, (B, K), step)
@@ -1522,6 +1545,7 @@ class GenerationEngine:
                       lattice=lattice.describe(),
                       cache=self.plan.describe(net),
                       prefill_chunk=chunk,
+                      decode_block_k=self._workers[0].kv_block,
                       speculative_k=self.speculative_k,
                       restored_step=self.restored_step)
 

@@ -73,7 +73,16 @@ decode_step when the engine runs with
 the acceptance accounting); their first execution per shape nests a
 `compile` span exactly like the predict path, and the
 flat-across-prompt-buckets property of the decode_step timings is the
-"decode cost independent of prompt length" gate in tier-1. Where the
+"decode cost independent of prompt length" gate in tier-1. All three
+carry how far the step's walk over the cache's key blocks went
+(ops/decode_attention.py), where the net's attention walks it in
+blocks: `kv_blocks` (the blocks the loop visits: ceil(largest
+visible-key bound among the step's live queries / block length); 0 for
+a prompt's first chunk) of `kv_blocks_cap` (capacity / block length);
+host arithmetic on the positions the engine holds, nothing is fetched
+for it. The engine's `meta` event gives the block length,
+`decode_block_k` (null for a net whose layers own their cached
+forward). Where the
 served net has a counting layer (the dropless expert layer,
 nn/layers/moe.py `DroplessMoELayer`), all three carry what the step's
 program counted, handed home behind the tokens in the one fetch:

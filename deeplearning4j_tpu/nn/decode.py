@@ -1,85 +1,60 @@
-"""Incremental autoregressive decode with an explicit KV cache.
+"""Incremental autoregressive decode with an explicit KV cache: the
+serving steps of a transformer stack, on both containers.
 
-The reference's `rnnTimeStep` (MultiLayerNetwork.java:2147) is a
-stateful streaming-inference contract that our SelfAttention layers
-reject — attention "needs the full sequence" — so until r11 serving
-re-ran the whole forward per generated token: N tokens cost N
-full-sequence forwards. This module is the productionized incremental
-contract for transformer stacks, on BOTH containers:
+The module is a plan, a walk, a `CacheStep` and three entry functions.
 
-* ``make_decode_fn(net)`` — a pure jitted-step body
-  ``(params, state, cache, token, pos) -> (probs, cache)``: one new
-  token per cache row, positions per row (continuous batching mixes
-  rows at different depths), the KV cache threaded as explicit state.
-  Attention is single-query against the cache
-  (ops/decode_attention.py, `decode_attn` autotune family): it reads
-  the cache where it lies, a key block at a time, up to the last block
-  a live row of the batch can see, so the step's cost follows the
-  longest live row and not the cache's capacity.
-* ``make_prefill_fn(net)`` — the chunked-prefill body
+* **The plan** (`_plan`): the net's layers and vertices in order, each
+  checked to be servable one slice of a sequence at a time. A layer is
+  servable where its impl says it maps every position by itself
+  (`per_position`: dense, embedding, norms, output heads, activation,
+  dropout), where it counts its own work (`apply_counted`: the dropless
+  expert layer), or where it carries its own cached forward
+  (`apply_cached`, causal where its conf has the word: attention and
+  the positional encodings, nn/layers/attention.py and
+  nn/layers/latent_attention.py). Elementwise, merge, scale and subset
+  vertices ride along. Anything else (LSTMs, convolutions over time,
+  bidirectional attention) raises when the plan is built, with the
+  layer named. This module names no layer class: it asks the impls.
+* **The walk** (`_walk`): the forward with inference semantics and the
+  containers' dtype policy. It *calls* a layer's ``apply_cached(conf,
+  params, x, entry, step)`` with the layer's entry of the cache and
+  puts back what the layer returns; the layer's mathematics lives once,
+  in nn/layers/.
+* **`CacheStep`**: what such a layer is told of the step it is called
+  in (`rows`, `positions`, `keep`, `chunk`, `live`) and the two
+  operations on a key-value entry in the cache's stored format,
+  ``step.write`` and ``step.attend``: bfloat16/float32 rows, or int8
+  codes with per-(row, page, head) float32 scales. That decision lives
+  here and in ops/decode_attention.py and nowhere else.
+* **The entry functions** build the `CacheStep`, walk, and pick the
+  output rows. ``make_decode_fn``: ``(params, state, cache, token,
+  pos[, live]) -> (probs, cache)``, one token a cache row, positions
+  per row (continuous batching mixes rows at different depths).
+  ``make_verify_fn``: the same for a window of K tokens a row, the
+  speculative verification step (serving/speculative.py accepts on the
+  host); the decode step is this at K = 1. ``make_prefill_fn``:
   ``(params, state, cache, tokens, kmask, rows, start, last_idx) ->
-  (probs_last, cache)``: fills cache rows with a prompt chunk's K/V and
-  returns the last real token's output row. Within-chunk attention
-  reuses the autotuned flash kernels when the chunk is inside their
-  envelope (flash_attention_lse_masked — the same dispatch discipline
-  as training); the cross-chunk half (chunk queries against the
-  already-written cache prefix, the chunk's rows taken from each key
-  block; no block at all for a first chunk) runs through
-  `cache_attention`, and the two merge by the standard two-way LSE
-  combine. `start` is per-row, so
-  a long prompt prefills in several bucket-shaped calls — the serving
-  engine interleaves decode steps between them.
-* ``make_verify_fn(net)`` — the SPECULATIVE verification body
-  ``(params, state, cache, tokens, pos) -> (probs, cache)``: K tokens
-  per row at positions ``pos..pos+K-1`` in ONE fixed-shape step. All K
-  keys are written before attending and each query row i gets
-  ``key_limit = pos+i+1``, which is exactly causal including self — so
-  row i's output is bit-identical to what i sequential decode steps
-  would produce given the same inputs. Acceptance is therefore a pure
-  host-side mask over the K output rows (serving/speculative.py); a
-  rejected draft's stale K/V is invisible (key_limit) until the next
-  verify window — which always starts at or before the stale region —
-  overwrites it.
-* ``init_cache(net, batch, capacity)`` — zeroed per-attention-layer
-  pytree, built from each attention layer's OWN spec
-  (``impl.cache_arrays``; ``cache_specs(net, ...)`` lists them):
-  ``{layer: {"k": [B, S, H, D], "v": ...}}`` for `SelfAttentionLayer`
-  (key position on axis 1 so per-position scatter writes are
-  contiguous), ``{layer: {"ckv": [B, S, kv_rank], "kpe": [B, S,
-  rope]}}`` for `LatentAttentionLayer`. The serving allocator bills
-  the same spec.
+  (probs_last, cache)``, a bucket-shaped chunk of a prompt into the
+  cache rows `rows` from position `start` on, so that a long prompt
+  prefills in several calls with decode steps between them.
 
-A layer that owns its cache entry AND its cached forward
-(``impl.apply_cached(conf, params, x, entry, step)``; `CacheStep` says
-which rows and positions the call holds) is called by the walk, not
-re-implemented here: its mathematics lives once, in nn/layers/. A layer
-that counts its own work (``impl.apply_counted``: the dropless expert
-layer) hands its counters to the step, which then returns a third value,
-an int32 vector in the order of the fn's ``counters`` attribute (empty,
-and two values returned, for a net without such a layer). The decode and
-verify fns take one more, optional argument, ``live`` [B] bool: the rows
-that hold a request, by the caller's word (the serving engine pads its
-batch with idle rows); the others attend no key (key_limit 0, so an
-idle row's scratch position never lengthens the walk over the cache)
-and a counting layer computes and counts nothing for them. Without it
-every row is real.
+``init_cache(net, batch, capacity)`` allocates and ``cache_specs`` lists
+the cache, {layer: {array: [batch, ...]}}, from each layer's own
+`cache_arrays`; the serving allocator bills the same spec. All of them
+take ``kv_dtype`` ("f32": rows as the net computes them; "int8") and
+``page_size``.
 
-All three entry fns (and ``init_cache``) take ``kv_dtype`` ("f32" |
-"int8") and ``page_size``: the int8 paged cache stores codes plus
-per-(row, page, head) f32 scales (``{"k", "k_scale", "v", "v_scale"}``
-entries), writes through ops/decode_attention.quantized_cache_update,
-and attends through `cache_attention_q8` (dequantize as a block loads) —
-~4x less HBM per slot, gated on greedy-sequence parity vs the f32
-cache in the serving replay.
+A net with counting layers makes each step return a third value, an
+int32 vector in the order of the fn's ``counters`` attribute (empty,
+and two values returned, for any other net). ``live`` [B] bool, the
+optional last argument of the decode and verify fns, is the caller's
+word on which rows hold a request (the serving engine pads its batch
+with idle rows): the others attend no key (key_limit 0, so an idle
+row's scratch position never lengthens the walk over the cache) and a
+counting layer computes and counts nothing for them.
 
-Both fns are pure (no net mutation, no rng) so an external jit owner —
-the serving engine — controls the compile cache, exactly like
-`inference_fn`. Supported graphs: single-input/single-output stacks of
-time-pointwise layers (dense / embedding / layernorm / output heads /
-activation / dropout) plus causal SelfAttention and PositionalEncoding;
-elementwise/merge/scale/subset vertices ride along. Anything that mixes
-time any other way (LSTMs, convolutions over time, bidirectional
-attention) raises at build time with the offending layer named.
+The fns are pure (no net mutation, no rng), so an external jit owner,
+the serving engine, controls the compile cache, as with `inference_fn`.
 
 Equivalence contract (tier-1, tests/test_generation.py): greedy decode
 through prefill + K incremental steps matches argmax over K
@@ -88,34 +63,14 @@ full-sequence forwards at atol 1e-5.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from deeplearning4j_tpu.nn.conf.layers import (
-    ActivationLayer,
-    BaseOutputLayer,
-    DenseLayer,
-    DropoutLayer,
-    EmbeddingLayer,
-    GatedDenseLayer,
-    LayerNormalization,
-    PositionalEncodingLayer,
-    SelfAttentionLayer,
-)
 from deeplearning4j_tpu.nn.training import tree_cast
-from deeplearning4j_tpu.ops import autotune
-from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.ops.decode_attention import (
     cache_attention,
     cache_attention_q8,
     quantized_cache_update,
 )
-
-_POINTWISE = (DenseLayer, EmbeddingLayer, LayerNormalization,
-              BaseOutputLayer, ActivationLayer, DropoutLayer,
-              GatedDenseLayer)
-
-_NEG_INF = -1e30
 
 
 # ------------------------------------------------------------- model plan
@@ -184,38 +139,28 @@ def _plan(net):
         in_name, out_name = "__input__", prev
     if problems:
         raise ValueError(
-            "incremental decode supports transformer stacks (pointwise "
-            "layers + causal SelfAttention + PositionalEncoding); these "
-            "cannot stream one token at a time: " + ", ".join(problems))
+            "incremental decode supports transformer stacks (layers that "
+            "map each position by itself + causal attention + positional "
+            "encodings); these cannot stream one token at a time: "
+            + ", ".join(problems))
     return in_name, out_name, ops
 
 
-def _owns_cache(impl) -> bool:
-    """The layer carries its cached forward itself (`apply_cached`)."""
-    return hasattr(impl, "apply_cached")
-
-
 def _decodable_layer(lc, impl) -> bool:
-    if isinstance(lc, SelfAttentionLayer) or _owns_cache(impl):
-        return bool(lc.causal)  # non-causal attention reads the future
-    if isinstance(lc, PositionalEncodingLayer):
-        return True
-    return isinstance(lc, _POINTWISE) or hasattr(impl, "apply_counted")
+    if hasattr(impl, "apply_cached"):
+        # non-causal attention reads the future
+        return bool(getattr(lc, "causal", True))
+    return impl.per_position or hasattr(impl, "apply_counted")
 
 
-def _counting_impl(ops):
-    """The impl of the plan's counting layers (`apply_counted`, with its
-    `counters` names and `merge_counts`: the expert layer's), or None."""
-    for op in ops:
-        if op.kind == "layer" and hasattr(op.impl, "apply_counted"):
-            return op.impl
-    return None
-
-
-def _mark_counters(fn, ops):
-    """`fn.counters`: the names of the int32 vector the step returns as
-    its third value, () where the plan has no counting layer."""
-    fn.counting = _counting_impl(ops)
+def _mark_counters(fn, plan):
+    """`fn.counting`: the impl of the plan's counting layers
+    (`apply_counted`, with its `counters` names and `merge_counts`: the
+    expert layer's) or None; `fn.counters`: the names of the int32
+    vector the step returns as its third value, () where there is no
+    such layer."""
+    fn.counting = next((op.impl for op in plan[2] if op.kind == "layer"
+                        and hasattr(op.impl, "apply_counted")), None)
     fn.counters = tuple(fn.counting.counters) if fn.counting else ()
     return fn
 
@@ -242,14 +187,10 @@ def cache_specs(net, capacity: int, kv_dtype: str = "f32",
 
 def init_cache(net, batch: int, capacity: int, kv_dtype: str = "f32",
                page_size: int = 16):
-    """Zeroed cache, {layer: {array: [batch, ...]}} by `cache_specs`:
-    keys and values {"k": [batch, capacity, H, D], "v": ...} in the
-    net's compute dtype for `SelfAttentionLayer` (kv_dtype="int8": int8
-    codes plus per-(row, page, head) f32 scales, {"k", "k_scale", "v",
-    "v_scale"}; capacity must sit on the page grid), one latent row
-    {"ckv": [batch, capacity, kv_rank], "kpe": [batch, capacity, rope]}
-    for `LatentAttentionLayer`. `capacity` is the per-row key budget
-    (prompt + generated, page-quantized by the serving layer)."""
+    """Zeroed cache, {layer: {array: [batch, ...]}} by `cache_specs`
+    (each layer's docstring has its arrays; an int8 capacity must sit on
+    the page grid). `capacity` is the per-row key budget (prompt +
+    generated, page-quantized by the serving layer)."""
     return {name: {arr: jnp.zeros((batch,) + shape, dt)
                    for arr, (shape, dt) in arrays.items()}
             for name, arrays in cache_specs(net, capacity, kv_dtype,
@@ -259,45 +200,73 @@ def init_cache(net, batch: int, capacity: int, kv_dtype: str = "f32",
 def walk_block(net, capacity: int, kv_dtype: str = "f32",
                page_size: int = 16) -> int | None:
     """The key-block length in which the net's cached attention walks a
-    cache of `capacity` positions (ops/decode_attention.py resolves it
-    from the capacity and the head size alone, so the host can know how
-    many blocks a step visits without asking the program): that of the
-    net's first `SelfAttentionLayer`. None where no layer walks the
-    cache in blocks (a layer that owns its cached forward reads its
-    entry its own way)."""
+    cache of `capacity` positions, by the first layer that says one
+    (`impl.cache_block`), so the host can know how many blocks a step
+    visits without asking the program. None where no layer walks the
+    cache in blocks (the latent layer reads its entry its own way)."""
     _, _, ops = _plan(net)
     for op in ops:
-        if op.kind == "layer" and isinstance(op.conf, SelfAttentionLayer):
-            D = op.conf.n_out // op.conf.n_heads
-            if kv_dtype == "int8":
-                return autotune.decode_block_q8(capacity, D, page_size)
-            return autotune.decode_block(capacity, D)
+        if op.kind == "layer" and hasattr(op.impl, "cache_block"):
+            return op.impl.cache_block(op.conf, capacity, kv_dtype,
+                                       page_size)
     return None
 
 
 class CacheStep:
-    """What a layer that owns its cache entry is told about the serving
-    step it is called in: `rows` [b] the cache rows the call's batch
-    rows are (None: all of them, in order), `positions` [b, T] the
+    """What a layer that carries `apply_cached` is told about the
+    serving step it is called in: `rows` [b] the cache rows the call's
+    batch rows are (None: all of them, in order), `positions` [b, T] the
     position each token occupies, `keep` [b, T] 1 for real tokens (None:
     all; the pad of a prefill bucket writes zero rows), `chunk` True for
     a prefill chunk (many queries a row), False for a decode or verify
-    step."""
+    step, `live` [b] bool the rows the caller says hold a request (None:
+    all). `write` and `attend` are the two operations on a key-value
+    entry in the cache's stored format (`kv_dtype`, `page_size`)."""
 
-    __slots__ = ("rows", "positions", "keep", "chunk")
+    __slots__ = ("rows", "positions", "keep", "chunk", "live", "kv_dtype",
+                 "page_size")
 
-    def __init__(self, rows, positions, keep=None, chunk=False):
+    def __init__(self, rows, positions, keep=None, chunk=False, live=None,
+                 kv_dtype="f32", page_size=16):
         self.rows, self.positions = rows, positions
-        self.keep, self.chunk = keep, chunk
+        self.keep, self.chunk, self.live = keep, chunk, live
+        self.kv_dtype, self.page_size = kv_dtype, page_size
+
+    def write(self, entry, k_new, v_new):
+        """`entry` with k_new/v_new [b, T, H, D] written at the step's
+        rows and positions."""
+        rows = (jnp.arange(k_new.shape[0]) if self.rows is None
+                else self.rows)
+        return _cache_write(entry, k_new, v_new, rows, self.positions,
+                            self.kv_dtype, self.page_size)
+
+    def attend(self, entry, qh, key_limit, rows=None):
+        """qh [b, H, Tq, D] against `entry`, query t of row i seeing the
+        keys before key_limit[i, t]: -> (out, lse). A row not `live`
+        sees no key (an idle row is fed the scratch position, whose
+        limit would be the whole capacity: it never lengthens the walk
+        over the cache's blocks). `rows` [b] names the cache rows the
+        queries attend (a prefill chunk's); they are taken from each key
+        block as the walk loads it, never gathered from the whole
+        cache."""
+        if self.live is not None:
+            key_limit = jnp.where(jnp.asarray(self.live, bool)[:, None],
+                                  key_limit, 0)
+        if self.kv_dtype == "int8":
+            return cache_attention_q8(qh, entry["k"], entry["v"],
+                                      entry["k_scale"], entry["v_scale"],
+                                      key_limit, self.page_size, rows)
+        return cache_attention(qh, entry["k"], entry["v"], key_limit, rows)
 
 
 def _cache_write(entry, k_new, v_new, rows, positions, kv_dtype,
                  page_size):
-    """Write k_new/v_new [b, T, H, D] at (rows x positions [b, T]) —
-    the dtype-dispatched cache scatter. Out-of-range positions (the
-    engine's inactive-row scratch / a speculative tail past capacity)
-    are dropped on both paths: the f32 scatter by jax's out-of-bounds
-    default, the int8 path inside quantized_cache_update."""
+    """Write k_new/v_new [b, T, H, D] at (rows x positions [b, T]): the
+    one function every key-value write goes through. Out-of-range
+    positions (the engine's inactive-row scratch / a speculative tail
+    past capacity) are dropped on both paths: the plain scatter by jax's
+    out-of-bounds default, the int8 path inside
+    quantized_cache_update."""
     if kv_dtype == "int8":
         ck, ks = quantized_cache_update(entry["k"], entry["k_scale"],
                                         k_new, rows, positions, page_size)
@@ -311,108 +280,19 @@ def _cache_write(entry, k_new, v_new, rows, positions, kv_dtype,
     return {"k": ck, "v": cv}
 
 
-def _cache_attend(entry, qh, key_limit, kv_dtype, page_size, rows=None):
-    """Attend qh [b, H, Tq, D] against a cache entry with per-query
-    visible-key bounds — dtype-dispatched. `rows` [b] names the cache
-    rows the queries attend (the prefill cross-chunk path); they are
-    taken from each key block as the walk loads it, never gathered from
-    the whole cache."""
-    if kv_dtype == "int8":
-        return cache_attention_q8(qh, entry["k"], entry["v"],
-                                  entry["k_scale"], entry["v_scale"],
-                                  key_limit, page_size, rows)
-    return cache_attention(qh, entry["k"], entry["v"], key_limit, rows)
-
-
-def _live_limit(live, key_limit):
-    """`key_limit` [B, T] with the rows `live` [B] does not mark set to
-    0: an idle row (fed the scratch position, whose limit would be the
-    whole capacity) sees no key, so it never lengthens the walk over the
-    cache's blocks. `live` None: every row is real."""
-    if live is None:
-        return key_limit
-    return jnp.where(jnp.asarray(live, bool)[:, None], key_limit, 0)
-
-
-# ------------------------------------------------------------ shared math
-
-def _sinusoidal_at(positions, d, dtype):
-    """Sinusoidal encodings at explicit positions [...] -> [..., d] —
-    the per-position twin of PositionalEncodingImpl._sinusoidal (same
-    f32 math, cast at the end, so decode matches the full forward)."""
-    pos = positions.astype(jnp.float32)[..., None]
-    dim = jnp.arange(0, d, 2).astype(jnp.float32)
-    angle = pos / jnp.power(10000.0, dim / d)
-    pe = jnp.zeros(positions.shape + (d,), jnp.float32)
-    pe = pe.at[..., 0::2].set(jnp.sin(angle))
-    pe = pe.at[..., 1::2].set(jnp.cos(angle[..., : d // 2]))
-    return pe.astype(dtype)
-
-
-def _dense_lse(qh, kh, vh, kmask):
-    """Within-chunk causal attention with (out, lse) — the fallback for
-    chunk shapes outside the flash envelope (tiny serving buckets, CPU
-    tier-1). qh/kh/vh [b, H, T, D]; kmask [b, T]. f32 softmax like
-    every other attention path."""
-    D, T = qh.shape[-1], qh.shape[2]
-    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                   preferred_element_type=jnp.float32) / jnp.sqrt(
-                       jnp.float32(D))
-    cm = jnp.tril(jnp.ones((T, T), bool))
-    s = jnp.where(cm, s, _NEG_INF)
-    s = jnp.where(kmask[:, None, None, :].astype(bool), s, _NEG_INF)
-    m = s.max(-1)
-    p = jnp.exp(s - m[..., None])
-    l = p.sum(-1)
-    o = jnp.einsum("bhqk,bhkd->bhqd", p, vh.astype(jnp.float32))
-    o = o / jnp.maximum(l, 1e-30)[..., None]
-    return o.astype(qh.dtype), m + jnp.log(jnp.maximum(l, 1e-30))
-
-
-def _chunk_self_lse(qh, kh, vh, kmask):
-    """Within-chunk causal attention (out, lse), through the autotuned
-    flash kernels when the chunk is inside their envelope — the prefill
-    half of the "reuse the flash kernels" contract."""
-    from deeplearning4j_tpu.ops import flash_attention as fa
-
-    b, H, T, D = qh.shape
-    if fa.supports(qh.shape, causal=True, dropout=0.0, mask=kmask):
-        # flat [b*H, T, D] layout is b-major, so the key mask repeats
-        # per head within each batch row
-        km = jnp.repeat(jnp.asarray(kmask, jnp.float32), H,
-                        axis=0)[:, None, :]
-        o, lse = fa.flash_attention_lse_masked(
-            qh.reshape(b * H, T, D), kh.reshape(b * H, T, D),
-            vh.reshape(b * H, T, D), km, 1.0 / float(D) ** 0.5, True)
-        return (o.reshape(b, H, T, D),
-                lse.reshape(b, H, T).astype(jnp.float32))
-    return _dense_lse(qh, kh, vh, kmask)
-
-
-def _merge_lse(o1, lse1, o2, lse2):
-    """Two-way blockwise softmax merge (the ring/chunk-loop combine):
-    each part carries its own lse; fully-masked parts (lse at the mask
-    floor) weigh to zero."""
-    m = jnp.maximum(lse1, lse2)
-    w1 = jnp.exp(lse1 - m)
-    w2 = jnp.exp(lse2 - m)
-    denom = jnp.maximum(w1 + w2, 1e-30)[..., None]
-    o = (o1.astype(jnp.float32) * w1[..., None]
-         + o2.astype(jnp.float32) * w2[..., None]) / denom
-    return o.astype(o1.dtype)
-
-
 # -------------------------------------------------------------- the walk
 
-def _walk(net, ops, in_name, out_name, params, state, x0, attn, posenc,
-          cache=None, step=None, valid=None, counts=None):
-    """Topo traversal with inference semantics (train=False, no rng),
-    attention/posenc routed to the supplied handlers. A layer that owns
-    its cache is called with its entry of `cache` (updated in place in
-    that dict) and `step`; a counting layer is told which tokens are
-    real (`valid`) and appends its counters to `counts`. Mirrors the
-    containers' _forward dtype policy: float inputs and per-layer params
-    cast to the compute dtype where the two differ."""
+def _walk(net, plan, params, state, cache, x0, step, valid):
+    """Topo traversal with inference semantics (train=False, no rng) of
+    the tokens x0 [b, T] -> (out [b, T, V], the cache with what the
+    layers wrote, the counting layers' counters). A layer that carries
+    `apply_cached` is called with its entry of the cache (None for a
+    layer that keeps none) and `step`; a counting layer is told which
+    tokens are real (`valid`). Mirrors the containers' _forward dtype
+    policy: float inputs and per-layer params cast to the compute dtype
+    where the two differ."""
+    in_name, out_name, ops = plan
+    cache, counts = dict(cache), []
     cdtype = net.compute_dtype
     pdtype = net.param_dtype
     x0 = jnp.asarray(x0)
@@ -428,13 +308,11 @@ def _walk(net, ops, in_name, out_name, params, state, x0, attn, posenc,
             p = params.get(op.name, {})
             if cdtype != pdtype:
                 p = tree_cast(p, cdtype)
-            if isinstance(op.conf, SelfAttentionLayer):
-                y = attn(op.name, op.conf, p, x)
-            elif isinstance(op.conf, PositionalEncodingLayer):
-                y = posenc(op.name, op.conf, p, x)
-            elif _owns_cache(op.impl):
-                y, cache[op.name] = op.impl.apply_cached(
-                    op.conf, p, _as_seq(x), cache[op.name], step)
+            if hasattr(op.impl, "apply_cached"):
+                y, entry = op.impl.apply_cached(
+                    op.conf, p, _as_seq(x), cache.get(op.name), step)
+                if entry is not None:
+                    cache[op.name] = entry
                 if x.ndim == 2:     # a one-token walk that arrived 2-D
                     y = y[:, 0, :]  # stays so (see `_as_seq`)
             elif hasattr(op.impl, "apply_counted"):
@@ -446,7 +324,7 @@ def _walk(net, ops, in_name, out_name, params, state, x0, attn, posenc,
             acts[op.name] = y
         else:
             acts[op.name] = _vertex(op.conf, inputs)
-    return acts[out_name]
+    return _as_seq(acts[out_name]), cache, counts
 
 
 def _vertex(vconf, inputs):
@@ -495,6 +373,16 @@ def _live_tokens(live, positions):
                             positions.shape)
 
 
+def _as_seq(x):
+    """Re-expand [B, d] to [B, 1, d]. EmbeddingImpl squeezes a [B, 1]
+    index column to [B] (reference EmbeddingLayer is feed-forward), so a
+    single-token walk's activations can arrive 2-D; adding a [B, 1, d]
+    positional term to a 2-D [B, d] would BROADCAST to [B, B, d] and
+    silently hand every row past 0 row 0's features. Every layer that
+    mixes x with per-row position data is handed x through this."""
+    return x[:, None, :] if x.ndim == 2 else x
+
+
 def _finish(fn, counts, out, cache):
     """A step's return: (out, cache), and the layers' counters merged
     into one int32 vector in the order of `fn.counters` where the plan
@@ -506,74 +394,27 @@ def _finish(fn, counts, out, cache):
         [total[n] for n in fn.counters]).astype(jnp.int32)
 
 
-def _split_heads(t, H):
-    b, T, n = t.shape
-    return t.reshape(b, T, H, n // H)
-
-
-def _as_seq(x):
-    """Re-expand [B, d] to [B, 1, d]. EmbeddingImpl squeezes a [B, 1]
-    index column to [B] (reference EmbeddingLayer is feed-forward), so a
-    single-token walk's activations can arrive 2-D; adding a [B, 1, d]
-    positional term to a 2-D [B, d] would BROADCAST to [B, B, d] and
-    silently hand every row past 0 row 0's features. Every handler that
-    mixes x with per-row position data goes through this first."""
-    return x[:, None, :] if x.ndim == 2 else x
-
-
 # ------------------------------------------------------------ entry fns
 
 def make_decode_fn(net, kv_dtype: str = "f32", page_size: int = 16):
     """-> pure ``step(params, state, cache, token, pos) -> (probs,
-    cache)``. token [B] int32; pos [B] int32 is the position the token
-    OCCUPIES (0-based — a row whose prompt filled [0, L) decodes its
-    first generated token at pos=L). probs [B, V] is the output layer's
-    activation row for that token; cache comes back with the token's
-    K/V written at (row, pos)."""
-    in_name, out_name, ops = _plan(net)
+    cache)``: the verify step at K = 1. token [B] int32; pos [B] int32
+    is the position the token OCCUPIES (0-based — a row whose prompt
+    filled [0, L) decodes its first generated token at pos=L). probs
+    [B, V] is the output layer's activation row for that token; cache
+    comes back with the token's K/V written at (row, pos)."""
+    plan = _plan(net)
 
     def step(params, state, cache, token, pos, live=None):
-        B = token.shape[0]
-        new_cache = dict(cache)
-        rows = jnp.arange(B)
         positions = pos[:, None]                           # [B, 1]
+        probs, cache, counts = _walk(
+            net, plan, params, state, cache, token[:, None],
+            CacheStep(None, positions, live=live, kv_dtype=kv_dtype,
+                      page_size=page_size),
+            _live_tokens(live, positions))
+        return _finish(step, counts, probs[:, 0, :], cache)
 
-        def attn(name, conf, p, x):
-            H, n = conf.n_heads, conf.n_out
-            x = _as_seq(x)
-            qkv = x[:, 0, :] @ p["Wqkv"] + p["bqkv"]       # [B, 3n]
-            q, k_new, v_new = jnp.split(qkv, 3, axis=-1)
-            Dh = n // H
-            entry = _cache_write(
-                new_cache[name], k_new.reshape(B, 1, H, Dh),
-                v_new.reshape(B, 1, H, Dh), rows, positions,
-                kv_dtype, page_size)
-            new_cache[name] = entry
-            qh = q.reshape(B, H, 1, Dh)
-            o, _ = _cache_attend(
-                entry, qh, _live_limit(live, (pos + 1)[:, None]),
-                kv_dtype, page_size)
-            y = o[:, :, 0, :].reshape(B, n) @ p["Wo"] + p["bo"]
-            return get_activation(conf.activation or "identity")(
-                y)[:, None, :]
-
-        def posenc(name, conf, p, x):
-            x = _as_seq(x)
-            d = x.shape[-1]
-            if conf.learned:
-                pe = jnp.take(p["pe"], pos, axis=0)        # [B, d]
-            else:
-                pe = _sinusoidal_at(pos, d, x.dtype)
-            return x + pe[:, None, :]
-
-        counts = []
-        probs = _as_seq(_walk(
-            net, ops, in_name, out_name, params, state, token[:, None],
-            attn, posenc, cache=new_cache, step=CacheStep(None, positions),
-            valid=_live_tokens(live, positions), counts=counts))
-        return _finish(step, counts, probs[:, 0, :], new_cache)
-
-    return _mark_counters(step, ops)
+    return _mark_counters(step, plan)
 
 
 def make_prefill_fn(net, kv_dtype: str = "f32", page_size: int = 16):
@@ -588,61 +429,22 @@ def make_prefill_fn(net, kv_dtype: str = "f32", page_size: int = 16):
     [b, V] comes home; pass Tc-1 for non-final chunks and ignore the
     result). Padded positions write ZERO K/V (masked) and are
     overwritten as decode advances."""
-    in_name, out_name, ops = _plan(net)
+    plan = _plan(net)
 
     def prefill(params, state, cache, tokens, kmask, rows, start,
                 last_idx):
         b, Tc = tokens.shape
-        new_cache = dict(cache)
         local = jnp.arange(Tc)
         positions = start[:, None] + local[None, :]        # [b, Tc]
-
-        def attn(name, conf, p, x):
-            H, n = conf.n_heads, conf.n_out
-            Dh = n // H
-            x = _as_seq(x)
-            qkv = x @ p["Wqkv"] + p["bqkv"]                # [b, Tc, 3n]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            keep = kmask[..., None, None]
-            entry = _cache_write(
-                new_cache[name], _split_heads(k, H) * keep,
-                _split_heads(v, H) * keep, rows, positions,
-                kv_dtype, page_size)
-            new_cache[name] = entry
-            qh = _split_heads(q, H).transpose(0, 2, 1, 3)  # [b, H, Tc, Dh]
-            kh = _split_heads(k, H).transpose(0, 2, 1, 3)
-            vh = _split_heads(v, H).transpose(0, 2, 1, 3)
-            o1, lse1 = _chunk_self_lse(qh, kh, vh, kmask)
-            # cross-chunk half: queries against the cache prefix this
-            # row wrote before `start` (empty on the first chunk — its
-            # lse sits at the mask floor and merges to weight zero)
-            limit = jnp.broadcast_to(start[:, None], (b, Tc))
-            o2, lse2 = _cache_attend(entry, qh, limit, kv_dtype,
-                                     page_size, rows=rows)
-            o = _merge_lse(o1, lse1, o2, lse2)
-            y = o.transpose(0, 2, 1, 3).reshape(b, Tc, n)
-            y = y @ p["Wo"] + p["bo"]
-            return get_activation(conf.activation or "identity")(y)
-
-        def posenc(name, conf, p, x):
-            x = _as_seq(x)
-            d = x.shape[-1]
-            if conf.learned:
-                pe = jnp.take(p["pe"], positions, axis=0)  # [b, Tc, d]
-            else:
-                pe = _sinusoidal_at(positions, d, x.dtype)
-            return x + pe
-
-        counts = []
-        probs = _as_seq(_walk(
-            net, ops, in_name, out_name, params, state, tokens, attn, posenc,
-            cache=new_cache,
-            step=CacheStep(rows, positions, keep=kmask, chunk=True),
-            valid=kmask > 0 if prefill.counters else None, counts=counts))
+        probs, cache, counts = _walk(
+            net, plan, params, state, cache, tokens,
+            CacheStep(rows, positions, keep=kmask, chunk=True,
+                      kv_dtype=kv_dtype, page_size=page_size),
+            kmask > 0 if prefill.counters else None)
         return _finish(prefill, counts,
-                       probs[jnp.arange(b), last_idx, :], new_cache)
+                       probs[jnp.arange(b), last_idx, :], cache)
 
-    return _mark_counters(prefill, ops)
+    return _mark_counters(prefill, plan)
 
 
 def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
@@ -658,46 +460,15 @@ def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
     (serving/speculative.py) compares argmax rows against the drafts;
     rejected positions' stale K/V stays invisible until the next verify
     window overwrites it."""
-    in_name, out_name, ops = _plan(net)
+    plan = _plan(net)
 
     def verify(params, state, cache, tokens, pos, live=None):
-        B, K = tokens.shape
-        new_cache = dict(cache)
-        rows = jnp.arange(B)
-        positions = pos[:, None] + jnp.arange(K)[None, :]  # [B, K]
+        positions = pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
+        probs, cache, counts = _walk(
+            net, plan, params, state, cache, tokens,
+            CacheStep(None, positions, live=live, kv_dtype=kv_dtype,
+                      page_size=page_size),
+            _live_tokens(live, positions))
+        return _finish(verify, counts, probs, cache)
 
-        def attn(name, conf, p, x):
-            H, n = conf.n_heads, conf.n_out
-            Dh = n // H
-            x = _as_seq(x)
-            qkv = x @ p["Wqkv"] + p["bqkv"]                # [B, K, 3n]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            entry = _cache_write(
-                new_cache[name], _split_heads(k, H), _split_heads(v, H),
-                rows, positions, kv_dtype, page_size)
-            new_cache[name] = entry
-            qh = _split_heads(q, H).transpose(0, 2, 1, 3)  # [B, H, K, Dh]
-            o, _ = _cache_attend(
-                entry, qh, _live_limit(live, positions + 1), kv_dtype,
-                page_size)
-            y = o.transpose(0, 2, 1, 3).reshape(B, K, n)
-            y = y @ p["Wo"] + p["bo"]
-            return get_activation(conf.activation or "identity")(y)
-
-        def posenc(name, conf, p, x):
-            x = _as_seq(x)
-            d = x.shape[-1]
-            if conf.learned:
-                pe = jnp.take(p["pe"], positions, axis=0)  # [B, K, d]
-            else:
-                pe = _sinusoidal_at(positions, d, x.dtype)
-            return x + pe
-
-        counts = []
-        probs = _walk(
-            net, ops, in_name, out_name, params, state, tokens, attn, posenc,
-            cache=new_cache, step=CacheStep(None, positions),
-            valid=_live_tokens(live, positions), counts=counts)
-        return _finish(verify, counts, probs, new_cache)
-
-    return _mark_counters(verify, ops)
+    return _mark_counters(verify, plan)

@@ -5,6 +5,30 @@ reference analogue. Attention computes per-head scaled dot product over
 [batch, time, features]; XLA fuses the softmax chain. A ring-attention
 sequence-parallel variant lives in deeplearning4j_tpu/parallel/ring_attention.py
 and is selected by the parallel plan, not the layer config.
+
+Serving. A layer that has to know where its tokens stand carries
+``apply_cached(conf, params, x, entry, step) -> (y, entry)`` beside
+`apply`: x [b, T, n_in] is one serving step's tokens, `entry` the
+layer's own arrays of the decode cache (None for a layer that keeps
+none: `PositionalEncodingLayer`), `step` the nn/decode.CacheStep that
+says which rows and positions the call holds and writes and attends an
+entry in the cache's stored format. The walk of nn/decode.py calls it
+and knows nothing else of the layer.
+
+`SelfAttentionLayer`'s entry is its keys and values as projected, one
+[H, D] row a position: {"k": [B, S, H, D], "v": ...} in the compute
+dtype (the position on axis 1, so that a step's scatter writes whole
+rows), or, with kv_dtype="int8", int8 codes and one float32 scale a
+(row, page, head): {"k", "k_scale", "v", "v_scale"}, about a quarter of
+the bytes a slot (ops/decode_attention.py quantizes as a step writes
+and dequantizes as a key block loads). A decode step (one token a row)
+and a speculative verify step (a window of K) write their rows and then
+attend the cache with ``key_limit = position + 1``: causal, itself
+included, so query i of a window reads what i + 1 decode steps would
+have read, and a rejected draft's rows stay unseen until the next
+window overwrites them. A prefill chunk attends itself (the flash
+kernels inside their envelope, as in training) and the rows its prompt
+wrote before it, and merges the two by their log-sum-exps.
 """
 
 from __future__ import annotations
@@ -17,7 +41,9 @@ from deeplearning4j_tpu.ops.flash_attention import (
     chunked_flash_attention,
     chunked_unsupported_reason,
     flash_attention,
+    flash_attention_lse_masked,
     flash_attention_qkv,
+    lse_combine,
     supports as flash_supports,
     supports_chunked as flash_supports_chunked,
     supports_monolithic_fallback as flash_supports_monolithic_fallback,
@@ -31,11 +57,14 @@ from deeplearning4j_tpu.nn.conf.layers import (
 )
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, apply_dropout, register_impl
 from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.ops import autotune
 from deeplearning4j_tpu.ops.activations import get_activation
 
 
 @register_impl(LayerNormalization)
 class LayerNormImpl(LayerImpl):
+    per_position = True
+
     def init(self, conf, rng, dtype):
         n = conf.n_out or conf.n_in
         return {"gamma": jnp.ones((n,), dtype), "beta": jnp.zeros((n,), dtype)}, {}
@@ -69,6 +98,8 @@ def rms_norm(x, gamma, eps):
 
 @register_impl(RMSNormalization)
 class RMSNormImpl(LayerImpl):
+    per_position = True
+
     def init(self, conf, rng, dtype):
         return {"gamma": jnp.ones((conf.n_out or conf.n_in,), dtype)}, {}
 
@@ -91,6 +122,19 @@ def _sp_axis_in_scope(name: str) -> bool:
         return False
 
 
+def sinusoidal(positions, d, dtype):
+    """Sinusoidal encodings at explicit positions [...] -> [..., d]:
+    float32 arithmetic, cast at the end, so that a serving step and the
+    full forward give a position the same row."""
+    pos = positions[..., None].astype(jnp.float32)
+    dim = jnp.arange(0, d, 2).astype(jnp.float32)
+    angle = pos / jnp.power(10000.0, dim / d)
+    pe = jnp.zeros(positions.shape + (d,), jnp.float32)
+    pe = pe.at[..., 0::2].set(jnp.sin(angle))
+    pe = pe.at[..., 1::2].set(jnp.cos(angle[..., : d // 2]))
+    return pe.astype(dtype)
+
+
 @register_impl(PositionalEncodingLayer)
 class PositionalEncodingImpl(LayerImpl):
     def init(self, conf, rng, dtype):
@@ -99,16 +143,6 @@ class PositionalEncodingImpl(LayerImpl):
                 rng, (conf.max_length, conf.n_features), dtype)
             return {"pe": pe}, {}
         return {}, {}
-
-    @staticmethod
-    def _sinusoidal(T, d, dtype, offset=0):
-        pos = (offset + jnp.arange(T))[:, None].astype(jnp.float32)
-        dim = jnp.arange(0, d, 2).astype(jnp.float32)
-        angle = pos / jnp.power(10000.0, dim / d)
-        pe = jnp.zeros((T, d), jnp.float32)
-        pe = pe.at[:, 0::2].set(jnp.sin(angle))
-        pe = pe.at[:, 1::2].set(jnp.cos(angle[:, : d // 2]))
-        return pe.astype(dtype)
 
     def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
         T, d = x.shape[1], x.shape[2]
@@ -130,8 +164,17 @@ class PositionalEncodingImpl(LayerImpl):
         if conf.learned:
             pe = jax.lax.dynamic_slice(params["pe"], (offset, 0), (T, d))
         else:
-            pe = self._sinusoidal(T, d, x.dtype, offset)
+            pe = sinusoidal(offset + jnp.arange(T), d, x.dtype)
         return x + pe, state
+
+    def apply_cached(self, conf, params, x, entry, step):
+        """x + pe(step.positions); the layer keeps nothing in the cache
+        (`entry` is None and goes back as it came)."""
+        if conf.learned:
+            pe = jnp.take(params["pe"], step.positions, axis=0)
+        else:
+            pe = sinusoidal(step.positions, x.shape[-1], x.dtype)
+        return x + pe, entry
 
 
 def dot_product_attention(q, k, v, *, causal, mask=None, dropout=0.0, rng=None,
@@ -154,6 +197,44 @@ def dot_product_attention(q, k, v, *, causal, mask=None, dropout=0.0, rng=None,
         w = jnp.where(keep, w / (1.0 - dropout), 0.0)
     out = jnp.einsum("bhqk,bhkd->bhqd", w.astype(v.dtype), v)
     return out.astype(q.dtype)
+
+
+def dense_attention_lse(qh, kh, vh, kmask):
+    """Causal attention of a chunk on itself that keeps its lse, (out,
+    lse): what a prefill chunk outside the flash envelope runs (tiny
+    serving buckets, the CPU). qh/kh/vh [b, H, T, D]; kmask [b, T].
+    float32 softmax like every other attention path."""
+    D, T = qh.shape[-1], qh.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
+                   preferred_element_type=jnp.float32) / jnp.sqrt(
+                       jnp.float32(D))
+    cm = jnp.tril(jnp.ones((T, T), bool))
+    s = jnp.where(cm, s, -1e30)
+    s = jnp.where(kmask[:, None, None, :].astype(bool), s, -1e30)
+    m = s.max(-1)
+    p = jnp.exp(s - m[..., None])
+    l = p.sum(-1)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p, vh.astype(jnp.float32))
+    o = o / jnp.maximum(l, 1e-30)[..., None]
+    return o.astype(qh.dtype), m + jnp.log(jnp.maximum(l, 1e-30))
+
+
+def chunk_attention_lse(qh, kh, vh, kmask):
+    """Causal attention of a prefill chunk on itself, (out, lse): the
+    autotuned flash kernels where the chunk is inside their envelope
+    (the dispatch discipline of training), the dense form outside."""
+    b, H, T, D = qh.shape
+    if flash_supports(qh.shape, causal=True, dropout=0.0, mask=kmask):
+        # flat [b*H, T, D] layout is b-major, so the key mask repeats
+        # per head within each batch row
+        km = jnp.repeat(jnp.asarray(kmask, jnp.float32), H,
+                        axis=0)[:, None, :]
+        o, lse = flash_attention_lse_masked(
+            qh.reshape(b * H, T, D), kh.reshape(b * H, T, D),
+            vh.reshape(b * H, T, D), km, 1.0 / float(D) ** 0.5, True)
+        return (o.reshape(b, H, T, D),
+                lse.reshape(b, H, T).astype(jnp.float32))
+    return dense_attention_lse(qh, kh, vh, kmask)
 
 
 @register_impl(SelfAttentionLayer)
@@ -182,6 +263,47 @@ class SelfAttentionImpl(LayerImpl):
             return {"k": (row, jnp.int8), "k_scale": (scale, jnp.float32),
                     "v": (row, jnp.int8), "v_scale": (scale, jnp.float32)}
         return {"k": (row, dtype), "v": (row, dtype)}
+
+    def cache_block(self, conf, capacity, kv_dtype, page_size):
+        """The key-block length in which a step walks this layer's entry
+        of `capacity` positions (ops/decode_attention.py resolves the
+        same from the capacity and the head size alone)."""
+        D = conf.n_out // conf.n_heads
+        if kv_dtype == "int8":
+            return autotune.decode_block_q8(capacity, D, page_size)
+        return autotune.decode_block(capacity, D)
+
+    def apply_cached(self, conf, params, x, entry, step):
+        """One serving step through this layer's cache entry (module
+        docstring): project, write the call's key and value rows (zero
+        where `step.keep` is 0: the pad of a prefill bucket), attend.
+        x [b, T, n_in] -> (y [b, T, n_out], entry)."""
+        b, T, _ = x.shape
+        H, n = conf.n_heads, conf.n_out
+        qkv = x @ params["Wqkv"] + params["bqkv"]
+        q, k, v = (t.reshape(b, T, H, n // H)
+                   for t in jnp.split(qkv, 3, axis=-1))
+        if step.keep is None:
+            entry = step.write(entry, k, v)
+        else:
+            keep = step.keep[..., None, None]
+            entry = step.write(entry, k * keep, v * keep)
+        qh = q.transpose(0, 2, 1, 3)                    # [b, H, T, D]
+        if step.chunk:
+            o, lse = chunk_attention_lse(qh, k.transpose(0, 2, 1, 3),
+                                         v.transpose(0, 2, 1, 3), step.keep)
+            # the rows this prompt wrote before the chunk's first token
+            # (none on a first chunk: that half's lse sits at the mask
+            # floor and merges to weight zero)
+            before = jnp.broadcast_to(step.positions[:, :1], (b, T))
+            o, _ = lse_combine(o, lse, *step.attend(entry, qh, before,
+                                                    rows=step.rows))
+            o = o.astype(qh.dtype)
+        else:
+            o, _ = step.attend(entry, qh, step.positions + 1)
+        y = o.transpose(0, 2, 1, 3).reshape(b, T, n)
+        y = y @ params["Wo"] + params["bo"]
+        return get_activation(conf.activation or "identity")(y), entry
 
     def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
         if conf.dropout:

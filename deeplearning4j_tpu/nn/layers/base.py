@@ -65,6 +65,12 @@ def get_impl(conf) -> "LayerImpl":
 class LayerImpl:
     """Stateless singleton holding pure init/apply for one layer kind."""
 
+    # True where `apply` maps each position of [B, T, ...] by itself: a
+    # serving step (nn/decode.py) may then call it on any slice of a
+    # sequence. A layer that has to know where its tokens stand carries
+    # `apply_cached` instead (nn/layers/attention.py).
+    per_position = False
+
     def init(self, conf, rng, dtype):
         return {}, {}
 

@@ -63,6 +63,8 @@ def gated_ffn(x, w_gate, w_up, w_down, activation="silu"):
 
 @register_impl(GatedDenseLayer)
 class GatedDenseImpl(LayerImpl):
+    per_position = True
+
     def init(self, conf, rng, dtype):
         D, O = conf.n_in, conf.n_out or conf.n_in
         F = conf.d_hidden or 4 * D
@@ -83,6 +85,8 @@ class GatedDenseImpl(LayerImpl):
 
 @register_impl(DenseLayer)
 class DenseImpl(LayerImpl):
+    per_position = True
+
     def init(self, conf, rng, dtype):
         return _dense_init(conf, rng, dtype)
 
@@ -96,6 +100,8 @@ class OutputImpl(LayerImpl):
     """Output layer: dense + activation; the container computes the loss on
     the preactivation for numeric stability (reference BaseOutputLayer
     computes the softmax/loss delta jointly)."""
+
+    per_position = True
 
     def init(self, conf, rng, dtype):
         params, state = _dense_init(conf, rng, dtype)
@@ -162,6 +168,8 @@ class OutputImpl(LayerImpl):
 
 @register_impl(ActivationLayer)
 class ActivationImpl(LayerImpl):
+    per_position = True
+
     def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
         if conf.dropout:
             x = apply_dropout(x, conf.dropout, rng, train=train)
@@ -170,6 +178,8 @@ class ActivationImpl(LayerImpl):
 
 @register_impl(DropoutLayer)
 class DropoutImpl(LayerImpl):
+    per_position = True
+
     def apply(self, conf, params, state, x, *, train=False, rng=None, mask=None):
         return apply_dropout(x, conf.dropout, rng, train=train), state
 
@@ -179,6 +189,8 @@ class EmbeddingImpl(LayerImpl):
     """Index lookup. The reference implements this as a select of rows of W
     (EmbeddingLayer.java); here it is jnp.take — XLA lowers it to a dynamic
     gather; grads are scatter-adds. Input: int [batch] or [batch, 1]."""
+
+    per_position = True
 
     def init(self, conf, rng, dtype):
         params, _ = _dense_init(conf, rng, dtype)

@@ -701,6 +701,7 @@ class _GenWorker:
 
     THE CACHE IS DONATED to every step (`donate_argnums` on the cache
     argument of the three jits): the scatter of nn/decode._cache_write
+    (what a layer's `apply_cached` reaches through `CacheStep.write`)
     writes in place into the buffers the step was handed, so a step
     neither copies the cache nor holds it twice. The worker owns the
     buffers' lifetime. It rebinds `self.cache` to the step's output as

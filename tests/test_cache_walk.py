@@ -5,10 +5,15 @@ call can see; the scan below relaid the whole cache and walked all of
 it. Same dtype, same block length: the results are equal to the last
 bit for every query that sees a key, a query that sees none reads zero,
 and a block past the last live one is never read. Also here: the served
-decode step holds no relaid copy of a cache entry, and the engine's
-spans say how far each step's walk went."""
+decode step holds no relaid copy of a cache entry, the engine's spans
+say how far each step's walk went, and (ISSUE 33) the layers' own cached
+forward, `apply_cached`, against their `apply` over the whole sequence;
+the walk of nn/decode.py calls it and names no layer."""
 
+import ast
+import inspect
 import json
+import pathlib
 import urllib.request
 
 import jax
@@ -305,3 +310,167 @@ def test_spans_say_how_far_the_walk_went():
     # a chunk's cross-chunk half sees the keys before its start
     assert [(e["start"], e["kv_blocks"]) for e in spans["prefill_chunk"]] \
         == [(0, 0), (0, 0), (8, 1), (16, 2)]
+
+
+# ------------------------------------------- the layers' cached forward
+
+def _attention_layer():
+    from deeplearning4j_tpu.nn.conf.layers import SelfAttentionLayer
+    from deeplearning4j_tpu.nn.layers.attention import SelfAttentionImpl
+
+    conf = SelfAttentionLayer(n_in=32, n_out=32, n_heads=2, causal=True,
+                              activation="tanh", weight_init="xavier")
+    impl = SelfAttentionImpl()
+    params, _ = impl.init(conf, jax.random.PRNGKey(0), jnp.float32)
+    rng = np.random.default_rng(1)      # biases that are not zero
+    for name in ("bqkv", "bo"):
+        params[name] = jnp.asarray(
+            rng.normal(size=params[name].shape), jnp.float32)
+    return conf, impl, params
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "verify", "two_chunks"])
+def test_attention_apply_cached_equals_apply(kind, kv_dtype):
+    """`SelfAttentionImpl.apply_cached`, called as the walk calls it,
+    against `apply` over the whole sequence: four rows with prompts of
+    different lengths in one padded chunk, then one decode step, a
+    verify window of 3 (two rows not `live` in both: they are fed the
+    scratch position and attend nothing), or a second chunk into a
+    permuted subset of the rows. Rows stored plain: atol 1e-5, the
+    tolerance of tests/test_generation.py. int8: a code is within 1/254
+    of its page's largest value, so 2 % of the output's largest value,
+    set here before the run; and not the plain result (the codes were
+    read)."""
+    from deeplearning4j_tpu.nn.decode import CacheStep
+
+    conf, impl, params = _attention_layer()
+    n_rows, cap, page, L = 4, 32, 8, 20
+    x = jnp.asarray(np.random.default_rng(2).normal(size=(n_rows, L, 32)),
+                    jnp.float32)
+    want = np.asarray(impl.apply(conf, params, {}, x)[0])
+    atol = 1e-5 if kv_dtype == "f32" else 0.02 * np.abs(want).max()
+    entry = {name: jnp.zeros((n_rows,) + shape, dt) for name, (shape, dt)
+             in impl.cache_arrays(conf, cap, kv_dtype, page,
+                                  jnp.float32).items()}
+
+    def step(**kw):
+        return CacheStep(kv_dtype=kv_dtype, page_size=page, **kw)
+
+    def close(got, rows, positions, first_chunk=False):
+        got, ref = np.asarray(got), want[rows, positions]
+        # a first chunk attends its own fresh rows, whatever the format
+        assert np.abs(got - ref).max() <= (1e-5 if first_chunk else atol)
+        if kv_dtype == "int8" and not first_chunk:
+            assert np.abs(got - ref).max() > 1e-5
+
+    prompt = np.array([8, 5, 7, 3])
+    local = np.arange(8)
+    keep = (local[None, :] < prompt[:, None]).astype(np.float32)
+    with autotune.override({"decode_attn": {"block_k": 8},
+                            "decode_attn_q8": {"block_k": 8}}):
+        y, entry = impl.apply_cached(
+            conf, params, x[:, :8], entry, step(
+                rows=jnp.arange(n_rows), keep=jnp.asarray(keep), chunk=True,
+                positions=jnp.broadcast_to(local, (n_rows, 8))))
+        for i, n in enumerate(prompt):
+            close(y[i, :n], i, local[:n], first_chunk=True)
+        if kind == "two_chunks":
+            # rows 2 and 0 go on: 7 + 6 and 8 + 8 tokens
+            rows, more = np.array([2, 0]), np.array([6, 8])
+            positions = prompt[rows][:, None] + local[None, :]
+            keep = (local[None, :] < more[:, None]).astype(np.float32)
+            xs = jnp.stack([x[r, positions[j]] for j, r in enumerate(rows)])
+            y, entry = impl.apply_cached(
+                conf, params, xs, entry, step(
+                    rows=jnp.asarray(rows), keep=jnp.asarray(keep),
+                    chunk=True, positions=jnp.asarray(positions)))
+            for j, r in enumerate(rows):
+                close(y[j, :more[j]], r, positions[j, :more[j]])
+            return
+        T = 1 if kind == "decode" else 3
+        live = np.array([True, False, True, False])
+        positions = np.where(live[:, None],
+                             prompt[:, None] + np.arange(T)[None, :], cap - 1)
+        xs = jnp.stack([x[i, positions[i] % L] for i in range(n_rows)])
+        y, after = impl.apply_cached(
+            conf, params, xs, entry, step(
+                rows=None, positions=jnp.asarray(positions),
+                live=jnp.asarray(live)))
+    for i in np.flatnonzero(live):
+        close(y[i], i, positions[i])
+    # a row not live attends no key: its output is the bias through the
+    # activation, and it wrote only the scratch position
+    assert np.allclose(np.asarray(y)[~live], np.tanh(params["bo"]),
+                       atol=1e-6)
+    for name in ("k", "v"):
+        assert np.array_equal(np.asarray(after[name])[~live, :cap - 1],
+                              np.asarray(entry[name])[~live, :cap - 1])
+
+
+@pytest.mark.parametrize("learned", [True, False])
+def test_positional_apply_cached_equals_apply(learned):
+    """The positions said outright, arange(T) for every row, give what
+    `apply` gives the whole sequence, to the bit (one sinusoid function,
+    one table); a layer with no cache entry hands back the None it was
+    given."""
+    from deeplearning4j_tpu.nn.conf.layers import PositionalEncodingLayer
+    from deeplearning4j_tpu.nn.decode import CacheStep
+    from deeplearning4j_tpu.nn.layers.attention import PositionalEncodingImpl
+
+    conf = PositionalEncodingLayer(learned=learned, max_length=16,
+                                   n_features=12)
+    impl = PositionalEncodingImpl()
+    params, _ = impl.init(conf, jax.random.PRNGKey(3), jnp.float32)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(3, 10, 12)),
+                    jnp.float32)
+    positions = jnp.broadcast_to(jnp.arange(10), (3, 10))
+    y, entry = impl.apply_cached(conf, params, x, None,
+                                 CacheStep(None, positions))
+    assert entry is None
+    assert np.array_equal(y, impl.apply(conf, params, {}, x)[0])
+    assert np.abs(np.asarray(y - x)).max() > 1e-3
+    # and a decode step's [b, 1] positions pick the same rows
+    at = np.array([7, 0, 9])
+    y1, _ = impl.apply_cached(conf, params, x[np.arange(3), at][:, None],
+                              None, CacheStep(None, jnp.asarray(at)[:, None]))
+    assert np.array_equal(y1[:, 0], y[np.arange(3), at])
+
+
+def test_the_walk_names_no_layer_and_no_layer_imports_the_walk():
+    """nn/decode.py asks the impls (`apply_cached`, `apply_counted`,
+    `per_position`, `cache_arrays`, `cache_block`): no identifier in it
+    is a layer class of nn/conf/layers.py, it does not import the flash
+    kernels, and nothing under nn/layers/ imports nn.decode (the step
+    object is passed in)."""
+    import deeplearning4j_tpu.nn as nn_pkg
+    from deeplearning4j_tpu.nn.conf import layers as conf_layers
+
+    root = pathlib.Path(nn_pkg.__file__).parent
+    classes = {name for name, cls in inspect.getmembers(
+        conf_layers, inspect.isclass) if cls.__module__ == conf_layers.__name__}
+    assert {"SelfAttentionLayer", "PositionalEncodingLayer",
+            "DenseLayer"} <= classes
+
+    def identifiers(path):
+        names, modules = set(), set()
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Name):
+                names.add(node.id)
+            elif isinstance(node, ast.Attribute):
+                names.add(node.attr)
+            elif isinstance(node, ast.ImportFrom):
+                modules.add(node.module or "")
+                names.update(a.name for a in node.names)
+                modules.update(f"{node.module}.{a.name}" for a in node.names)
+            elif isinstance(node, ast.Import):
+                modules.update(a.name for a in node.names)
+        return names, modules
+
+    names, modules = identifiers(root / "decode.py")
+    assert not names & classes
+    assert not any("flash_attention" in m for m in modules)
+    assert {"attn", "posenc"}.isdisjoint(names)
+    for path in sorted((root / "layers").glob("*.py")):
+        assert not any(m.startswith("deeplearning4j_tpu.nn.decode")
+                       for m in identifiers(path)[1]), path.name

@@ -55,6 +55,8 @@ counting layer computes and counts nothing for them.
 
 The fns are pure (no net mutation, no rng), so an external jit owner,
 the serving engine, controls the compile cache, as with `inference_fn`.
+``serving_params(net)`` is the walk's dtype rule applied ahead of time:
+the parameter tree a server hands to these fns step after step.
 
 Equivalence contract (tier-1, tests/test_generation.py): greedy decode
 through prefill + K incremental steps matches argmax over K
@@ -63,6 +65,7 @@ full-sequence forwards at atol 1e-5.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.training import tree_cast
@@ -325,6 +328,24 @@ def _walk(net, plan, params, state, cache, x0, step, valid):
         else:
             acts[op.name] = _vertex(op.conf, inputs)
     return _as_seq(acts[out_name]), cache, counts
+
+
+_cast_tree = jax.jit(tree_cast, static_argnums=1)
+
+
+def serving_params(net):
+    """`net.params` as `_walk` multiplies by them: where the net's
+    compute dtype differs from its parameter dtype, every floating leaf
+    cast to the compute dtype by the walk's own `tree_cast` (one jitted
+    program over the tree); where the two are equal, the very tree it
+    was given (no copy). The cast does not depend on the step, so a
+    server makes this tree once and hands it to every step: the walk's
+    `tree_cast` then traces to nothing, and every product has the
+    operands it had with the stored tree. `net.params` stay the
+    caller's, in `param_dtype`."""
+    if net.compute_dtype == net.param_dtype:
+        return net.params
+    return _cast_tree(net.params, net.compute_dtype)
 
 
 def _vertex(vconf, inputs):

@@ -62,13 +62,31 @@ from deeplearning4j_tpu.serving.speculative import (NgramProposer,
                                                     accept_greedy)
 from deeplearning4j_tpu.telemetry.costbook import CostBook, peak_flops
 from deeplearning4j_tpu.telemetry.memstat import (MemoryLedger,
-                                                  MemorySampler)
+                                                  MemorySampler, tree_bytes)
 
 
 def _peak_fields(peak) -> dict:
     """The device's published peak as an event/stats field — absent
     off-TPU (costbook.peak_flops gives None there), never invented."""
     return {} if peak is None else {"peak_flops": peak}
+
+
+def _weights_facts(stored, served) -> dict:
+    """What a generation engine's store holds, against the net's own
+    tree `stored`: the floating dtype(s) of the served leaves, their
+    bytes on the device, and how many leaves nn/decode.serving_params
+    cast (0 where the net computes in the dtype it stores)."""
+    import jax
+    import jax.numpy as jnp
+
+    leaves = jax.tree.leaves(served)
+    return {"weights_dtype": "+".join(sorted(
+                {x.dtype.name for x in leaves
+                 if jnp.issubdtype(x.dtype, jnp.floating)})),
+            "weights_bytes": tree_bytes(served),
+            "weights_cast_leaves": sum(
+                a.dtype != b.dtype
+                for a, b in zip(jax.tree.leaves(stored), leaves))}
 
 
 def _engine_recorder(recorder):
@@ -738,6 +756,8 @@ class _GenWorker:
         import jax
         import jax.numpy as jnp
 
+        from deeplearning4j_tpu.nn.decode import serving_params, walk_block
+
         self.index = index
         self.net = net
         self.lattice = lattice
@@ -746,7 +766,12 @@ class _GenWorker:
         self.max_queue = max_queue
         self.recorder = recorder
         self.costbook = costbook or CostBook(recorder)
-        self.weights = weights or WeightStore(net.params, net.state)
+        # a worker built alone serves as the engine's do: from the net's
+        # parameters in its COMPUTE dtype (GenerationEngine's docstring)
+        self.weights = weights or WeightStore(serving_params(net),
+                                              net.state)
+        self.weights_facts = _weights_facts(net.params,
+                                            self.weights.current.params)
         self.faults = faults
         self.pool = plan.make_pool()
         self.slots = DecodeSlots(plan.n_slots)
@@ -780,7 +805,6 @@ class _GenWorker:
         self._closed = False
         self._thread: threading.Thread | None = None
 
-        from deeplearning4j_tpu.nn.decode import walk_block
         self.kv_block = walk_block(net, plan.capacity, plan.kv_dtype,
                                    plan.page_size)
         prefill_raw = net.prefill_fn(plan.kv_dtype, plan.page_size)
@@ -1451,7 +1475,8 @@ class _GenWorker:
                    "alive": self.alive, "served": self.served,
                    "failed": self.failed,
                    "cache_losses": self.cache_losses,
-                   "decode_steps_run": self.decode_steps_run}
+                   "decode_steps_run": self.decode_steps_run,
+                   **self.weights_facts}
             if self.speculative_k >= 2:
                 out["verify_steps_run"] = self.verify_steps_run
                 out["accepted_tokens"] = self.accepted_tokens
@@ -1476,7 +1501,19 @@ class GenerationEngine:
     warmup compiles each (replica, prefill-bucket) and the (replica,
     decode-shape) once, and the trace counters stay frozen under mixed
     traffic (tier-1 asserts it). Page accounting and the
-    exhaustion-queues-not-crashes contract live in serving/kvcache.py."""
+    exhaustion-queues-not-crashes contract live in serving/kvcache.py.
+
+    TWO COPIES OF THE WEIGHTS, TWO OWNERS. `net.params` are the
+    caller's, in the net's `param_dtype`; the engine reads them once,
+    here (after the optional checkpoint restore), and never again. What
+    every step of every replica is handed is the engine's own
+    `WeightStore`: the same parameters in the net's `compute_dtype`
+    (nn/decode.serving_params: cast once, as the walk would cast them
+    in every step; the very arrays of `net.params`, no copy, where the
+    two dtypes are equal). The `meta` event and stats() say which it is
+    (`weights_dtype`, `weights_bytes`, `weights_cast_leaves`). A serving
+    process that wants the stored tree's bytes back drops `net.params`
+    once the engine is built."""
 
     def __init__(self, net, lattice: BucketLattice, *, slots: int = 4,
                  max_new_tokens: int = 16, page_size: int = 16,
@@ -1496,8 +1533,10 @@ class GenerationEngine:
             # the blessed fleet restore path (any-mesh checkpoint onto
             # this process's own one-device mesh)
             self.restored_step = restore_for_serving(net, checkpoint)
+        from deeplearning4j_tpu.nn.decode import serving_params
+
         self.net = net
-        self.weights = WeightStore(net.params, net.state,
+        self.weights = WeightStore(serving_params(net), net.state,
                                    step=self.restored_step)
         self._faults = None
         if faults is not None:
@@ -1548,7 +1587,8 @@ class GenerationEngine:
                       prefill_chunk=chunk,
                       decode_block_k=self._workers[0].kv_block,
                       speculative_k=self.speculative_k,
-                      restored_step=self.restored_step)
+                      restored_step=self.restored_step,
+                      **self._workers[0].weights_facts)
 
     # ------------------------------------------------------------- warmup
     def warmup(self) -> int:
@@ -1670,6 +1710,7 @@ class GenerationEngine:
             "restored_step": self.restored_step,
             "lattice": self.lattice.describe(),
             "cache": self.plan.describe(self.net),
+            **self._workers[0].weights_facts,
             "page_pools": pools,
             "fleet": [w.describe(now) for w in self._workers],
             "weights": self.weights.describe(),

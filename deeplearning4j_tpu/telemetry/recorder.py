@@ -96,6 +96,17 @@ layers' own cache specs, `rows` ({kind of cache row: bytes a token over
 all layers}: "k" and "v", or "ckv" and "kpe" for a latent row) and
 `bytes_per_token`.
 
+The generation engine's `meta` event, /stats and each worker's
+`describe()` also say what the engine serves FROM (serving/engine.py
+`_weights_facts`; the store holds the net's parameters in its compute
+dtype, cast once when it is built: nn/decode.serving_params):
+
+| field | what it is |
+|---|---|
+| `weights_dtype` | the floating dtype of the served leaves ("bfloat16" for a net stored in float32 that computes in bfloat16; several joined by "+" where they differ) |
+| `weights_bytes` | the served set's bytes on the device (what the `params` entry of the memory ledger reads; the caller's own `net.params` are not in it) |
+| `weights_cast_leaves` | the leaves whose dtype the engine changed when it built the store: every floating leaf where `compute_dtype != param_dtype`, 0 where they are equal (the store then holds the net's own arrays, no copy) |
+
 The engine thread's host loop (serving/engine.py `_GenWorker`) is named
 whole: every instant between two model steps lies in one of six LEAF
 spans, so a device idle gap laid over them names what the host was

@@ -19,6 +19,8 @@ worker imports this file. Nothing runs; a compile that passes is not a
 chip run.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -244,6 +246,114 @@ def test_decode_step_over_paged_cache(as_on_chip, one_chip, lm_shapes):
         step, _on(net.params, one_chip), _on(net.state, one_chip),
         _on(cache, one_chip), tok, tok)
     assert compiled.memory_analysis() is not None
+
+
+def _matrix_shapes(params):
+    return {f"[{a.shape[0]},{a.shape[1]}]"
+            for a in jax.tree.leaves(params) if a.ndim == 2}
+
+
+def test_decode_step_with_served_parameters_reads_no_float32_weight(
+        as_on_chip, one_chip, lm_shapes):
+    """The step as the generation engine calls it since ISSUE 34: its
+    parameters arrive in the compute dtype (nn/decode.serving_params),
+    so the compiled program holds no float32 array of a weight matrix's
+    shape, converts none into one, and reads fewer bytes than the step
+    over the stored float32 tree by at least that tree's half."""
+    from deeplearning4j_tpu.nn.decode import serving_params
+
+    net = lm_shapes
+    capacity = (256 + 32 + PAGE - 1) // PAGE * PAGE
+    step = net.incremental_decode_fn("f32", PAGE)
+    cache = jax.eval_shape(
+        lambda: net.init_kv_cache(SLOTS, capacity, "f32", PAGE))
+    tok = _sds((SLOTS,), jnp.int32, one_chip)
+    served = serving_params(net)
+    assert {a.dtype.name for a in jax.tree.leaves(served)} == {"bfloat16"}
+    read, texts = {}, {}
+    for name, params in (("stored", net.params), ("served", served)):
+        compiled, texts[name] = _compile(
+            step, _on(params, one_chip), _on(net.state, one_chip),
+            _on(cache, one_chip), tok, tok)
+        cost = compiled.cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        read[name] = cost["bytes accessed"]
+    shapes = _matrix_shapes(net.params)
+    for dims in shapes:
+        # the check can fail: the stored tree's step holds every one
+        assert "f32" + dims in texts["stored"], dims
+        assert "f32" + dims not in texts["served"], dims
+        assert re.search(r"= bf16" + re.escape(dims) + r"\S* convert\(",
+                         texts["served"]) is None, dims
+    stored_bytes = sum(a.nbytes for a in jax.tree.leaves(net.params))
+    assert read["stored"] - read["served"] >= stored_bytes / 2, read
+
+
+def test_gpt2_server_fits_beside_the_callers_float32_tree(
+        as_on_chip, one_chip, monkeypatch):
+    """`cerebras-gpt-1.3b` as the benchmark serves it, built through its
+    family's `serving_net` the way benchmarks/tests/test_chip_fit.py
+    does: the caller's float32 tree stays on the device beside the
+    engine's bfloat16 copy, and with it the decode step and the 1,024
+    bucket still fit what the v5e's compiler allows a program."""
+    import json
+    import os
+
+    from deeplearning4j_tpu.nn.training import tree_cast
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    monkeypatch.syspath_prepend(bench)
+    from harness import spec
+
+    with open(os.path.join(bench, "configs", "cerebras-gpt-1.3b.json")) as fh:
+        config = json.load(fh)
+    family = spec.family_of(config)
+    dims, held = family.dims_of(config), {}
+
+    def traced():
+        held["net"] = family.serving_net(config, 0, dims)
+        return held["net"].params
+
+    stored = jax.eval_shape(traced)
+    net = held["net"]
+    net.params = None                   # the tracers it was built on
+    served = jax.eval_shape(lambda p: tree_cast(p, net.compute_dtype),
+                            stored)
+    stored_bytes = sum(a.size * a.dtype.itemsize
+                       for a in jax.tree.leaves(stored))
+    state = {n: {} for n in stored}
+    dep = config["deployment"]
+    page, slots = dep["page_size"], dep["slots"]
+    bucket = max(dep["prefill_seq_lens"])
+    cap = -(-(bucket + dep["max_new_tokens"]) // page) * page
+    cache = jax.eval_shape(
+        lambda: net.init_kv_cache(slots, cap, dep["kv_dtype"], page))
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    i32 = lambda *s: _sds(s, jnp.int32, one_chip)
+
+    def greedy(raw):        # as `_GenWorker` jits it: the cache donated
+        def step(params, state, cache, *rest):
+            probs, cache = raw(params, state, cache, *rest)
+            return jnp.argmax(probs, axis=-1).astype(jnp.int32), cache
+        return jax.jit(step, donate_argnums=2)
+
+    hbm = 15.75 * 2**30     # test_chip_fit.py's HBM
+    programs = {
+        "decode": (greedy(net.incremental_decode_fn(dep["kv_dtype"], page)),
+                   (i32(slots), i32(slots),
+                    _sds((slots,), jnp.bool_, one_chip))),
+        "prefill": (greedy(net.prefill_fn(dep["kv_dtype"], page)),
+                    (i32(1, bucket), _sds((1, bucket), jnp.float32, one_chip),
+                     i32(1), i32(1), i32(1)))}
+    for name, (fn, rest) in programs.items():
+        mem = fn.lower(*_on((served, state, cache), one_chip), *rest) \
+            .compile().memory_analysis()
+        assert mem.alias_size_in_bytes == cache_bytes, (name, mem)
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+        assert stored_bytes + total < hbm, (name, stored_bytes, mem)
 
 
 @pytest.mark.parametrize("bucket", [64, 256, 512])

@@ -42,6 +42,7 @@ from deeplearning4j_tpu.nn.conf.layers import (  # noqa: F401
     LocalResponseNormalization,
     LSTM,
     OutputLayer,
+    PowerRetentionLayer,
     RBM,
     RMSNormalization,
     RnnOutputLayer,

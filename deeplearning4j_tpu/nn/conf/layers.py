@@ -410,6 +410,34 @@ class LatentAttentionLayer(BaseRecurrentLayer):
             self.n_out = self.n_in
 
 
+@register_config
+@dataclasses.dataclass
+class PowerRetentionLayer(BaseRecurrentLayer):
+    """Causal power retention of degree 2 with grouped heads, no biases
+    but the gate's (nn/layers/power_retention.py holds the equations):
+    `n_heads` queries of `head_dim` read the states of `n_kv_heads`
+    key-value heads, `n_heads / n_kv_heads` queries a state; RMS norms
+    with learned gains on each head's query and key, rotary position,
+    and one scalar gate a key-value head a token. What the layer keeps
+    of the past is a STATE of fixed size a sequence (`state_dtype`;
+    float32: it is a sum over thousands of tokens), not a row a token:
+    a serving step cannot be unwound from it."""
+
+    n_heads: int = 8
+    n_kv_heads: int = 0         # defaults to n_heads
+    head_dim: int = 0           # defaults to n_out / n_heads
+    rope_theta: float = 10000.0
+    eps: float = 1e-6           # of the query's and the key's RMS norm
+    sum_eps: float = 1e-6       # added to the sum of weights y is divided by
+    state_dtype: str = "float32"
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in == 0:
+            self.n_in = input_type.flat_size()
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+
 def _conv_out_hw(h, w, kernel, stride, padding, mode, dilation):
     kh = (kernel[0] - 1) * dilation[0] + 1
     kw = (kernel[1] - 1) * dilation[1] + 1

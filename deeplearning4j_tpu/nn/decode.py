@@ -10,11 +10,12 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   dropout), where it counts its own work (`apply_counted`: the dropless
   expert layer), or where it carries its own cached forward
   (`apply_cached`, causal where its conf has the word: attention and
-  the positional encodings, nn/layers/attention.py and
-  nn/layers/latent_attention.py). Elementwise, merge, scale and subset
-  vertices ride along. Anything else (LSTMs, convolutions over time,
-  bidirectional attention) raises when the plan is built, with the
-  layer named. This module names no layer class: it asks the impls.
+  the positional encodings, nn/layers/attention.py,
+  nn/layers/latent_attention.py and nn/layers/power_retention.py).
+  Elementwise, merge, scale and subset vertices ride along. Anything
+  else (LSTMs, convolutions over time, bidirectional attention) raises
+  when the plan is built, with the layer named. This module names no
+  layer class: it asks the impls.
 * **The walk** (`_walk`): the forward with inference semantics and the
   containers' dtype policy. It *calls* a layer's ``apply_cached(conf,
   params, x, entry, step)`` with the layer's entry of the cache and
@@ -25,18 +26,25 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   operations on a key-value entry in the cache's stored format,
   ``step.write`` and ``step.attend``: bfloat16/float32 rows, or int8
   codes with per-(row, page, head) float32 scales. That decision lives
-  here and in ops/decode_attention.py and nowhere else.
+  here and in ops/decode_attention.py and nowhere else. A layer whose
+  entry is a STATE (a running sum a slot, not a row a token: power
+  retention) uses neither; it reads the step itself, because a sum
+  forgives nothing a row does: a row whose first position is 0 starts a
+  sequence and its state is zeroed first, a token with `keep` 0 adds and
+  decays nothing, a row not `live` keeps its state bit for bit.
 * **The entry functions** build the `CacheStep`, walk, and pick the
   output rows. ``make_decode_fn``: ``(params, state, cache, token,
   pos[, live]) -> (probs, cache)``, one token a cache row, positions
   per row (continuous batching mixes rows at different depths).
   ``make_verify_fn``: the same for a window of K tokens a row, the
   speculative verification step (serving/speculative.py accepts on the
-  host); the decode step is this at K = 1. ``make_prefill_fn``:
-  ``(params, state, cache, tokens, kmask, rows, start, last_idx) ->
-  (probs_last, cache)``, a bucket-shaped chunk of a prompt into the
-  cache rows `rows` from position `start` on, so that a long prompt
-  prefills in several calls with decode steps between them.
+  host); the decode step is this at K = 1. It raises, with the layer
+  named, for a net with a layer whose step cannot be unwound (the impl
+  says `rewindable` False: a rejected draft's share of a state stays).
+  ``make_prefill_fn``: ``(params, state, cache, tokens, kmask, rows,
+  start, last_idx) -> (probs_last, cache)``, a bucket-shaped chunk of a
+  prompt into the cache rows `rows` from position `start` on, so that a
+  long prompt prefills in several calls with decode steps between them.
 
 ``init_cache(net, batch, capacity)`` allocates and ``cache_specs`` lists
 the cache, {layer: {array: [batch, ...]}}, from each layer's own
@@ -44,9 +52,11 @@ the cache, {layer: {array: [batch, ...]}}, from each layer's own
 take ``kv_dtype`` ("f32": rows as the net computes them; "int8") and
 ``page_size``.
 
-A net with counting layers makes each step return a third value, an
-int32 vector in the order of the fn's ``counters`` attribute (empty,
-and two values returned, for any other net). ``live`` [B] bool, the
+A net with counting layers (an impl with `counters`: the expert layer
+through `apply_counted`, a state layer as the third value its
+`apply_cached` returns) makes each step return a third value, an int32
+vector in the order of the fn's ``counters`` attribute (empty, and two
+values returned, for any other net). ``live`` [B] bool, the
 optional last argument of the decode and verify fns, is the caller's
 word on which rows hold a request (the serving engine pads its batch
 with idle rows): the others attend no key (key_limit 0, so an idle
@@ -157,13 +167,13 @@ def _decodable_layer(lc, impl) -> bool:
 
 
 def _mark_counters(fn, plan):
-    """`fn.counting`: the impl of the plan's counting layers
-    (`apply_counted`, with its `counters` names and `merge_counts`: the
-    expert layer's) or None; `fn.counters`: the names of the int32
+    """`fn.counting`: the impl of the plan's counting layers (one with
+    `counters` names and `merge_counts`: the expert layer's, the
+    retention layer's) or None; `fn.counters`: the names of the int32
     vector the step returns as its third value, () where there is no
     such layer."""
     fn.counting = next((op.impl for op in plan[2] if op.kind == "layer"
-                        and hasattr(op.impl, "apply_counted")), None)
+                        and hasattr(op.impl, "counters")), None)
     fn.counters = tuple(fn.counting.counters) if fn.counting else ()
     return fn
 
@@ -171,17 +181,19 @@ def _mark_counters(fn, plan):
 def cache_specs(net, capacity: int, kv_dtype: str = "f32",
                 page_size: int = 16) -> dict:
     """{layer: {array: (shape of one slot, dtype name)}} for every
-    attention layer, each as the layer's own `cache_arrays` gives it:
-    what `init_cache` allocates a batch of and what the serving
-    allocator bills (serving/kvcache.bytes_per_slot)."""
+    layer that keeps a cache entry, each as the layer's own
+    `cache_arrays` gives it: what `init_cache` allocates a batch of and
+    what the serving allocator bills (serving/kvcache.bytes_per_slot).
+    An array that is no row a position (a state) carries a third entry,
+    "slot"."""
     if kv_dtype == "int8" and capacity % page_size != 0:
         raise ValueError(
             f"int8 cache needs page-quantized capacity; {capacity} "
             f"is not a multiple of page_size {page_size}")
     _, _, ops = _plan(net)
     return {op.name: {
-        arr: (tuple(shape), jnp.dtype(dt).name)
-        for arr, (shape, dt) in op.impl.cache_arrays(
+        arr: (tuple(shape), jnp.dtype(dt).name, *per)
+        for arr, (shape, dt, *per) in op.impl.cache_arrays(
             op.conf, capacity, kv_dtype, page_size,
             net.compute_dtype).items()}
         for op in ops
@@ -195,7 +207,7 @@ def init_cache(net, batch: int, capacity: int, kv_dtype: str = "f32",
     the page grid). `capacity` is the per-row key budget (prompt +
     generated, page-quantized by the serving layer)."""
     return {name: {arr: jnp.zeros((batch,) + shape, dt)
-                   for arr, (shape, dt) in arrays.items()}
+                   for arr, (shape, dt, *_per) in arrays.items()}
             for name, arrays in cache_specs(net, capacity, kv_dtype,
                                             page_size).items()}
 
@@ -224,7 +236,10 @@ class CacheStep:
     a prefill chunk (many queries a row), False for a decode or verify
     step, `live` [b] bool the rows the caller says hold a request (None:
     all). `write` and `attend` are the two operations on a key-value
-    entry in the cache's stored format (`kv_dtype`, `page_size`)."""
+    entry in the cache's stored format (`kv_dtype`, `page_size`). A
+    layer whose entry is a state reads the fields alone: `positions[:,
+    0] == 0` zeroes a row's state before anything is added, `keep` 0
+    adds and decays nothing, a row not `live` keeps its state."""
 
     __slots__ = ("rows", "positions", "keep", "chunk", "live", "kv_dtype",
                  "page_size")
@@ -290,8 +305,9 @@ def _walk(net, plan, params, state, cache, x0, step, valid):
     the tokens x0 [b, T] -> (out [b, T, V], the cache with what the
     layers wrote, the counting layers' counters). A layer that carries
     `apply_cached` is called with its entry of the cache (None for a
-    layer that keeps none) and `step`; a counting layer is told which
-    tokens are real (`valid`). Mirrors the containers' _forward dtype
+    layer that keeps none) and `step`, and may return its counters
+    behind (y, entry); a counting layer is told which tokens are real
+    (`valid`). Mirrors the containers' _forward dtype
     policy: float inputs and per-layer params cast to the compute dtype
     where the two differ."""
     in_name, out_name, ops = plan
@@ -312,8 +328,9 @@ def _walk(net, plan, params, state, cache, x0, step, valid):
             if cdtype != pdtype:
                 p = tree_cast(p, cdtype)
             if hasattr(op.impl, "apply_cached"):
-                y, entry = op.impl.apply_cached(
+                y, entry, *counted = op.impl.apply_cached(
                     op.conf, p, _as_seq(x), cache.get(op.name), step)
+                counts.extend(counted)
                 if entry is not None:
                     cache[op.name] = entry
                 if x.ndim == 2:     # a one-token walk that arrived 2-D
@@ -480,8 +497,17 @@ def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
     (causal including self). The host-side acceptance mask
     (serving/speculative.py) compares argmax rows against the drafts;
     rejected positions' stale K/V stays invisible until the next verify
-    window overwrites it."""
+    window overwrites it. A layer whose step cannot be unwound (its impl
+    says `rewindable` False: a state, not rows) has no such forgiveness,
+    so a net with one is refused here, when the fn is built."""
     plan = _plan(net)
+    fixed = [f"{op.name} ({type(op.conf).__name__})" for op in plan[2]
+             if op.kind == "layer" and not op.impl.rewindable]
+    if fixed:
+        raise ValueError(
+            "speculative verification writes a window of drafts and "
+            "unwinds the rejected ones; these layers keep a state a step "
+            "cannot be taken out of again: " + ", ".join(fixed))
 
     def verify(params, state, cache, tokens, pos, live=None):
         positions = pos[:, None] + jnp.arange(tokens.shape[1])[None, :]

@@ -717,6 +717,17 @@ class _GenWorker:
     form ({"k","k_scale","v","v_scale"}) through the same three step
     fns — shapes still lattice/page-grid points, ~4x less HBM/slot.
 
+    A NET WHOSE CACHE ENTRY IS A STATE (a running sum a slot, not a row
+    a token: nn/layers/power_retention.py) is served by the same loop
+    and the worker names no layer: the layer itself zeroes a row's state
+    in the chunk that starts at position 0 (a slot's new tenant: stale
+    rows hide behind a key limit, a stale sum would not), adds nothing
+    for a bucket's pad, and leaves the rows `_live` calls idle bit for
+    bit; its steps hand home `state_resets`, which `_split_fetch` puts
+    on the step's span. speculative_k >= 2 is refused for such a net
+    when the worker is built (nn/decode.make_verify_fn raises: a
+    rejected draft cannot be taken out of a sum).
+
     THE CACHE IS DONATED to every step (`donate_argnums` on the cache
     argument of the three jits): the scatter of nn/decode._cache_write
     (what a layer's `apply_cached` reaches through `CacheStep.write`)
@@ -885,7 +896,8 @@ class _GenWorker:
     def _split_fetch(self, fetched, shape: tuple, span) -> np.ndarray:
         """The step's tokens, in `shape`, out of the fetched array; the
         counters behind them (a net with counting layers) become fields
-        of the step's span: `moe_pairs`, `moe_rows`, `moe_max_load`."""
+        of the step's span: `moe_pairs`, `moe_rows`, `moe_max_load` of
+        an expert layer, `state_resets` of a layer that keeps a state."""
         n = len(self.step_counters)
         if not n:
             return fetched

@@ -29,10 +29,13 @@ allocation.
   (nn/decode.cache_specs: {layer: {array: (shape of one slot, dtype
   name)}}): keys and values in the net's compute dtype, int8 codes plus
   one f32 scale per (page, head) under ``kv_dtype="int8"``, one latent
-  row a position for a latent-attention layer. `bytes_per_slot` is the
-  single home for that arithmetic: it bills exactly the arrays the
-  device holds, and the replay artifact's ``slots_per_hbm_byte`` uplift
-  row (gate: >= 1.8x) is computed from it, not re-derived ad hoc.
+  row a position for a latent-attention layer, a STATE of fixed size
+  for a retention layer (an array whose spec carries a third entry,
+  "slot": it does not grow with the capacity and is billed to the slot,
+  never to its positions). `bytes_per_slot` is the single home for that
+  arithmetic: it bills exactly the arrays the device holds, and the
+  replay artifact's ``slots_per_hbm_byte`` uplift row (gate: >= 1.8x)
+  is computed from it, not re-derived ad hoc.
 
 Pure stdlib: importable under the graftlint AST stage's no-jax stubs.
 """
@@ -67,12 +70,23 @@ def _nbytes(shape, dtype: str) -> int:
 
 
 def bytes_per_slot(cache_specs: dict) -> int:
-    """HBM bytes one decode slot's cache rows cost across all attention
-    layers: the bytes of every array of `cache_specs` (nn/decode.py:
-    {layer: {array: (shape of one slot, dtype name)}}), which is what
-    `init_cache` allocates a slot of."""
+    """HBM bytes one decode slot's cache costs across all layers: the
+    bytes of every array of `cache_specs` (nn/decode.py: {layer: {array:
+    (shape of one slot, dtype name[, "slot"])}}), rows and states alike,
+    which is what `init_cache` allocates a slot of."""
     return sum(_nbytes(shape, dtype) for arrays in cache_specs.values()
-               for shape, dtype in arrays.values())
+               for shape, dtype, *_per in arrays.values())
+
+
+def _kinds(cache_specs: dict, per_slot: bool) -> dict:
+    """{array name: bytes a slot holds over all layers that keep such an
+    array}, of the arrays marked "slot" (`per_slot`) or of the others."""
+    out: dict = {}
+    for arrays in cache_specs.values():
+        for name, (shape, dtype, *per) in arrays.items():
+            if bool(per) == per_slot:
+                out[name] = out.get(name, 0) + _nbytes(shape, dtype)
+    return out
 
 
 def row_kinds(cache_specs: dict, capacity: int) -> dict:
@@ -80,12 +94,17 @@ def row_kinds(cache_specs: dict, capacity: int) -> dict:
     such an array}: the kinds of row in the cache ("k", "v"; "ckv", "kpe"
     for a latent row) for the engine's `meta` event and /stats. An array
     with fewer entries than positions (a page's scale) is billed to the
-    positions it covers."""
-    out: dict = {}
-    for arrays in cache_specs.values():
-        for name, (shape, dtype) in arrays.items():
-            out[name] = out.get(name, 0) + _nbytes(shape, dtype) / capacity
-    return {k: round(v, 3) for k, v in out.items()}
+    positions it covers; an array marked "slot" (a state) is no row
+    and is left to `slot_kinds`."""
+    return {k: round(v / capacity, 3)
+            for k, v in _kinds(cache_specs, False).items()}
+
+
+def slot_kinds(cache_specs: dict) -> dict:
+    """{array name: bytes one SLOT holds over all layers that keep such
+    an array} for the arrays marked "slot": a state of fixed size ("s",
+    "z" of a retention layer), whatever the capacity."""
+    return _kinds(cache_specs, True)
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:
@@ -206,7 +225,10 @@ class CachePlan:
     def describe(self, net=None) -> dict:
         """The geometry; with `net`, also what its layers keep in it:
         `rows` ({kind of row: bytes a token over all layers}) and
-        `bytes_per_token`, their sum."""
+        `bytes_per_token`, their sum; `states` ({kind of state: bytes a
+        slot over all layers}) and `state_bytes_per_slot`, their sum.
+        capacity * bytes_per_token + state_bytes_per_slot is
+        `bytes_per_slot`."""
         out = {"n_slots": self.n_slots, "capacity": self.capacity,
                "page_size": self.page_size,
                "pages_per_slot": self.pages_per_slot,
@@ -214,7 +236,10 @@ class CachePlan:
                "max_new_tokens": self.max_new_tokens,
                "kv_dtype": self.kv_dtype}
         if net is not None:
-            rows = row_kinds(self.cache_specs(net), self.capacity)
+            specs = self.cache_specs(net)
+            rows, states = row_kinds(specs, self.capacity), slot_kinds(specs)
             out["rows"] = rows
             out["bytes_per_token"] = round(sum(rows.values()), 3)
+            out["states"] = states
+            out["state_bytes_per_slot"] = sum(states.values())
         return out

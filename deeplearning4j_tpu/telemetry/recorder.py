@@ -94,7 +94,13 @@ rounds x held experts x rows a round, padding included) and
 engine's `meta` event (`cache`) and /stats give, from the attention
 layers' own cache specs, `rows` ({kind of cache row: bytes a token over
 all layers}: "k" and "v", or "ckv" and "kpe" for a latent row) and
-`bytes_per_token`.
+`bytes_per_token`, and for the layers whose entry is a state `states`
+({kind of state: bytes a slot over all layers}: "s" and "z" of a
+retention layer) and `state_bytes_per_slot`. A net with such a layer
+also puts `state_resets` on its steps' spans: the rows whose state the
+step zeroed because they start a sequence (a request's first
+`prefill_chunk` reads 1, so the window's sum is the requests admitted;
+a `decode_step` reads 0).
 
 The generation engine's `meta` event, /stats and each worker's
 `describe()` also say what the engine serves FROM (serving/engine.py

@@ -248,6 +248,33 @@ def test_decode_step_over_paged_cache(as_on_chip, one_chip, lm_shapes):
     assert compiled.memory_analysis() is not None
 
 
+def test_retention_decode_kernel_writes_the_state_in_place(
+        no_persistent_cache, one_chip):
+    """ops/power_retention.py's decode kernel at Brumby-14B's widths
+    (16 slots, 40 queries on 8 states of 128 x 8,320 float32): it
+    compiles for the chip (the 8,320-lane rows, the lane rotations, a
+    2 MB value tile in and out), aliases the whole donated state, 550 MB
+    a layer, and leaves nothing of the state's size beside it: no
+    temporary, no relaid copy (a last axis that is no multiple of 128
+    lanes got one, in and out, every step)."""
+    from deeplearning4j_tpu.ops import power_retention as pr
+
+    B, Hq, Hk, d = 16, 40, 8, 128
+    D = pr.state_dim(d)
+
+    def f32(*shape):
+        return _sds(shape, jnp.float32, one_chip)
+
+    compiled = jax.jit(pr.retention_decode_kernel, donate_argnums=(0, 1)).lower(
+        f32(B, Hk, d, D), f32(B, Hk, D), f32(B, Hq, d), f32(B, Hk, d),
+        f32(B, Hk, d), f32(B, Hk)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == {"retention_decode": 1}
+    assert mem.alias_size_in_bytes == 4 * B * Hk * (d + 1) * D == 549_519_360
+    assert mem.temp_size_in_bytes < 2**20, mem
+    assert not re.search(rf"= f32\[{B},{Hk},{d},{D}\]\S* copy\(", text)
+
+
 def _matrix_shapes(params):
     return {f"[{a.shape[0]},{a.shape[1]}]"
             for a in jax.tree.leaves(params) if a.ndim == 2}

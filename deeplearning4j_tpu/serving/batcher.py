@@ -210,15 +210,19 @@ class GenRequest:
 class _Slot:
     """One decode slot's live state: the request it carries, how far its
     prompt has prefilled (`start`), the position its NEXT token writes
-    (`pos`), and the pages it holds."""
+    (`pos`), the output tokens whose program has been dispatched (`sent`)
+    and the pages it holds. `start`, `pos` and `sent` are the engine's own
+    count and advance when a program is DISPATCHED; `request.emitted` and
+    `last_token` follow when its tokens have come home."""
 
-    __slots__ = ("request", "start", "pos", "pages", "last_token")
+    __slots__ = ("request", "start", "pos", "sent", "pages", "last_token")
 
     def __init__(self, request: GenRequest, pages: int):
         self.request = request
         self.start = 0            # prompt tokens already prefilled
         self.pages = pages
         self.pos = 0              # next write position once decoding
+        self.sent = 0             # output tokens dispatched (>= emitted)
         self.last_token: int | None = None
 
 
@@ -268,10 +272,13 @@ class DecodeSlots:
 
     def decoding(self) -> list:
         """Indices of slots with their whole prompt in cache and output
-        budget left — the decode step's active rows."""
+        budget left — the decode step's active rows. The budget is
+        counted in tokens DISPATCHED (`sent`): completion is by count
+        alone, so the rows of the next step are known before the last
+        step's tokens are."""
         return [i for i, s in enumerate(self.slots)
                 if s is not None and s.start >= s.request.prompt_len
-                and len(s.request.emitted) < s.request.max_new_tokens]
+                and s.sent < s.request.max_new_tokens]
 
     def busy(self) -> bool:
         return any(s is not None for s in self.slots)

@@ -677,6 +677,21 @@ class InferenceEngine:
 
 # --------------------------------------------------------------- generation
 
+class _Flight:
+    """A program the engine thread has dispatched and not yet retired:
+    its sequence number, its fetched array still on the device (`tok`),
+    the consumed cache tree it was handed (array handles only, dropped
+    with `tok` when it is retired) and the (index, slot) rows its tokens
+    belong to: the active rows of a decode step, the one row of a
+    prompt's final chunk, none for a chunk that is not the last."""
+
+    __slots__ = ("program", "tok", "handed", "rows")
+
+    def __init__(self, program: int, tok, handed, rows: list):
+        self.program, self.tok = program, tok
+        self.handed, self.rows = handed, rows
+
+
 class _GenWorker:
     """One generation replica: its own KV-cache allocation, page pool,
     decode-slot state machine, and jit wrappers (own compile cache, own
@@ -687,7 +702,7 @@ class _GenWorker:
     chunk (so a long prefill never starves decoding slots), then one
     decode step over all slots. The decode step's shape is FIXED —
     [n_slots] tokens and positions against the [n_slots, capacity]
-    cache — so it compiles exactly once; inactive rows decode a dummy
+    cache — so it compiles exactly once; inactive rows decode a stale
     token whose K/V write is routed to the scratch position
     (capacity - 1), which any real tenant overwrites before it can ever
     be attended (a token's own K/V lands at its position in the same
@@ -697,6 +712,35 @@ class _GenWorker:
     occupied row can see, not at the scratch position's; a counting
     layer computes nothing for it. Each step's span says how far the
     walk went: `kv_blocks` of `kv_blocks_cap` (`_kv_blocks`).
+
+    THE LOOP RUNS ONE PROGRAM AHEAD. A program (a prompt chunk or a
+    decode step) is RETIRED (its tokens fetched and emitted, its slots
+    completed, its device outputs released) only after the NEXT program
+    has been dispatched; at most one un-retired program (`_flight`)
+    stands behind the one just dispatched. Nothing a step is handed
+    waits for the host: completion is by count alone (no stop token), so
+    positions, the live set and `kv_blocks` come from the host's own
+    count, which advances at DISPATCH (`_Slot.start`, `.pos`, `.sent`),
+    and the slots' last tokens never leave the device: every plain
+    step's fetched array is the [n_slots] token vector (a chunk writes
+    its row into the vector it was handed, a decode step every row) and
+    is the next step's argument as it lies there (`_tokens`; `warmup()`
+    hands it on the same way). A row's entry means something from its
+    prompt's final chunk until its budget is spent, exactly the steps
+    that are told the row is live. `last_token`, `req.emit`,
+    `tokens_out`, `_maybe_complete` and the release of the arrays happen
+    at retirement, from the retired program's OWN array (`_Flight.tok`;
+    its copy home starts at dispatch, `copy_to_host_async`). A slot
+    whose budget step n spends is not live in step n+1 and is RELEASED
+    when step n is retired, which is before the next `_admit`: "release,
+    then admit, then the new tenant's chunk at position 0" holds as it
+    did, which is what keeps a state layer sound. The served streams
+    are the serial loop's token for token. `drain()` and the loop's exit
+    come only after the last program is retired (a pass that dispatches
+    nothing retires it alone: `_land`). Every step's span says whether
+    it was launched over an un-retired program (`ahead`: in a busy
+    server every step but the first after an `idle_wait`), and
+    `describe()` / stats() count them (`steps_ahead`).
 
     SPECULATIVE MODE (speculative_k >= 2): the decode step is replaced
     by a fixed-shape VERIFY step over [n_slots, k] token windows
@@ -712,6 +756,13 @@ class _GenWorker:
     pages stay reserved by the up-front admission reservation (released
     on the same completion/failure path as ever; its stale K/V is
     invisible under key_limit until the next window overwrites it).
+    This path does NOT run ahead and shares none of that logic: its next
+    window is drafted on the host from the tokens just emitted, so each
+    verify step, and each chunk before one, is dispatched, fetched and
+    emitted in one pass as ever (such a chunk's span reads `ahead` false
+    and `fetched` its own number). The two needs conflict (drafts need
+    the tokens on the host; the plain step does not), so they are two
+    paths chosen by how the worker was built, not a switch on one.
 
     kv_dtype="int8" swaps every cache entry for the quantized paged
     form ({"k","k_scale","v","v_scale"}) through the same three step
@@ -739,25 +790,37 @@ class _GenWorker:
     consumed tree only inside that call. Another thread (the memory
     ledger behind /stats) may read the tree's metadata (`nbytes`,
     shapes) and nothing else; reap(), on the supervisor's thread, fails
-    slots and never touches the tree. Two failure cases (`_fail_step`):
-    a step that failed before it ran (an injected fault, a trace-time
-    error) left the cache intact and fails only its own slots; a step
-    that consumed the cache and then failed costs EVERY occupied slot
-    its rows: all of them fail with their pages released, a fresh cache
-    is allocated, `cache_losses` counts it, and the queue is served on.
+    slots and never touches the tree. Failure cases (`_fail_step`), with
+    a program in flight: a step that failed before it ran (an injected
+    fault, a trace-time error) left the cache intact; the program in
+    flight, older than the fault, is retired and its tokens emitted,
+    then only the failed step's own slots fail (`ReplicaKilled` the
+    same, and the thread dies). A step that consumed the cache and then
+    failed costs EVERY occupied slot its rows: the program in flight is
+    still retired first, then all of them fail once with their pages
+    released. A program that fails on the device shows a pass late,
+    when its tokens are fetched: the program dispatched on its output
+    is poisoned with it, the in-flight record and the token vector are
+    dropped, every occupied slot fails once. In both a fresh cache is
+    allocated, `cache_losses` counts ONE, and the queue is served on.
 
     THE LOOP IS NAMED WHOLE (telemetry/recorder.py): each pass is an
-    `admit` span, then per model step `step_prepare`, the step's own
-    span (`prefill_chunk` / `decode_step` / `verify_step`, from just
-    before the jit call to the fetched tokens) with its children
-    `dispatch` and `fetch`, then `emit`; or `idle_wait` when there is
-    nothing to run. These six are leaves, and all but `dispatch` start
-    where the region before them ended (`follows=True`), so the
-    recorder's own emission lies inside them and a device idle gap laid
-    over them says what the host was doing. A plain decode pass emits six
-    events; a request costs one `admit` event, a `page_pool` event at
-    each end and its `request` event on top — nothing per token. With
-    telemetry off every span is one shared no-op object."""
+    `admit` span, then per program `step_prepare`, the step's own span
+    (`prefill_chunk` / `decode_step` / `verify_step`) with its children
+    `dispatch`, of the program the span launches, and `fetch`, of the
+    program BEFORE it (the span says which: `program`, `fetched`), then
+    `emit` of that earlier program; the last program of a busy spell
+    is retired by a `fetch` and an `emit` with no step around them; or
+    `idle_wait` when there is nothing to run. These six are leaves, and
+    all but `dispatch` start where the region before them ended
+    (`follows=True`), so the recorder's own emission lies inside them
+    and a device idle gap laid over them says what the host was doing.
+    Consecutive step spans do not overlap, so the next one's `t0` less
+    this one's `t1` is still the host's time between two steps. A plain
+    decode pass emits six events; a request costs one `admit` event, a
+    `page_pool` event at each end and its `request` event on top —
+    nothing per token. With telemetry off every span is one shared
+    no-op object."""
 
     def __init__(self, index: int, net, lattice: BucketLattice,
                  plan: CachePlan, prefill_chunk: int, max_queue: int,
@@ -800,6 +863,7 @@ class _GenWorker:
         self.cache_losses = 0  # consumed caches rebuilt (_fail_step)
         self.tokens_out = 0
         self.decode_steps_run = 0
+        self.steps_ahead = 0  # programs dispatched over an un-retired one
         self.verify_steps_run = 0
         self.slot_steps = 0  # (active slot, verify step) pairs
         self.accepted_tokens = 0
@@ -827,27 +891,40 @@ class _GenWorker:
         # they ride home behind the tokens, in the one array the host
         # fetches anyway (`_split_fetch` takes them off again)
         self.step_counters = tuple(step_raw.counters)
+        B = plan.n_slots
 
-        def fetched(out):
-            tok = jnp.argmax(out[0], axis=-1).astype(jnp.int32)
+        def fetched(tok, out):
             if self.step_counters:
                 tok = jnp.concatenate([tok.reshape(-1), out[2]])
             return tok, out[1]
 
-        def counted_prefill(params, state, cache, padded_tokens,
+        def argmax(out):
+            return jnp.argmax(out[0], axis=-1).astype(jnp.int32)
+
+        # THE SLOTS' LAST TOKENS STAY ON THE DEVICE (`self._tokens`): a
+        # plain step's fetched array is a [n_slots] vector (counters
+        # behind it), every prefill chunk and decode step takes the one
+        # of the program before it (`last`) and hands on its own. A
+        # chunk writes its row, a decode step every row; a row's entry
+        # means something from its prompt's final chunk until its budget
+        # is spent, which is exactly when a decode step is told the row
+        # is live and reads it. So no step waits for the host to hand it
+        # a token (class docstring, THE LOOP RUNS ONE PROGRAM AHEAD).
+        def counted_prefill(params, state, cache, last, padded_tokens,
                             bucket_kmask, rows, start, last_idx):
             with self._mu:  # trace-time bump: the retrace tell
                 self.trace_count += 1
-            return fetched(prefill_raw(params, state, cache,
-                                       padded_tokens, bucket_kmask,
-                                       rows, start, last_idx))
+            out = prefill_raw(params, state, cache, padded_tokens,
+                              bucket_kmask, rows, start, last_idx)
+            return fetched(jax.lax.dynamic_update_slice(
+                last[:B], argmax(out), (rows[0],)), out)
 
         # `live`: the occupied rows (`_live`)
-        def counted_step(params, state, cache, padded_tokens, pos, live):
+        def counted_step(params, state, cache, last, pos, live):
             with self._mu:
                 self.trace_count += 1
-            return fetched(step_raw(params, state, cache, padded_tokens,
-                                    pos, live))
+            out = step_raw(params, state, cache, last[:B], pos, live)
+            return fetched(argmax(out), out)
 
         # argument 2 is the cache: donated, so the steps' scatters write
         # in place into the buffers they were handed (class docstring)
@@ -864,10 +941,14 @@ class _GenWorker:
                     self.trace_count += 1
                 # [B, k] argmax rows: the acceptance mask's input —
                 # k verification verdicts for one batch-boundary fetch
-                return fetched(verify_raw(params, state, cache,
-                                          padded_windows, pos, live))
+                out = verify_raw(params, state, cache, padded_windows,
+                                 pos, live)
+                return fetched(argmax(out), out)
 
             self._verify_jit = jax.jit(counted_verify, donate_argnums=2)
+        self._tokens = self._alloc_tokens()
+        self._flight: _Flight | None = None  # dispatched, not yet retired
+        self._programs = 0  # sequence number of the last one dispatched
 
     def _live(self, active=()) -> np.ndarray:
         """The decode or verify step's last argument: [n_slots] True for
@@ -934,18 +1015,21 @@ class _GenWorker:
             with self.recorder.span("compile", kind="prefill",
                                     bucket=[1, Tb], replica=self.index,
                                     warmup=True):
-                tok, self.cache = self._prefill_jit(
-                    ws.params, ws.state, self.cache,
+                # the tokens as the loop hands them on: the device
+                # array the program before this one returned
+                self._tokens, self.cache = self._prefill_jit(
+                    ws.params, ws.state, self.cache, self._tokens,
                     np.zeros((1, Tb), np.int32),
                     np.zeros((1, Tb), np.float32), rows, start,
                     np.asarray([Tb - 1], np.int32))
-                np.asarray(tok)  # batch-boundary fetch
+                np.asarray(self._tokens)  # batch-boundary fetch
             self._seen_shapes.add(key)
             compiles += 1
             # warmup-time cost harvest: lower() is a jaxpr-cache hit
             # (no trace-counter bump), the analyses are XLA's own
             self.costbook.record("prefill", [1, Tb], self._prefill_jit,
                                  (ws.params, ws.state, self.cache,
+                                  self._tokens,
                                   np.zeros((1, Tb), np.int32),
                                   np.zeros((1, Tb), np.float32), rows,
                                   start, np.asarray([Tb - 1], np.int32)))
@@ -977,17 +1061,16 @@ class _GenWorker:
             with self.recorder.span("compile", kind="decode",
                                     shape=[B, self.plan.capacity],
                                     replica=self.index, warmup=True):
-                tok, self.cache = self._decode_jit(
-                    ws.params, ws.state, self.cache,
-                    np.zeros(B, np.int32), scratch, self._live())
-                np.asarray(tok)  # batch-boundary fetch
+                self._tokens, self.cache = self._decode_jit(
+                    ws.params, ws.state, self.cache, self._tokens,
+                    scratch, self._live())
+                np.asarray(self._tokens)  # batch-boundary fetch
             self._seen_shapes.add("decode")
             compiles += 1
             self.costbook.record("decode", [B, self.plan.capacity],
                                  self._decode_jit,
                                  (ws.params, ws.state, self.cache,
-                                  np.zeros(B, np.int32), scratch,
-                                  self._live()))
+                                  self._tokens, scratch, self._live()))
         return compiles
 
     # --------------------------------------------------------- admission
@@ -1052,95 +1135,163 @@ class _GenWorker:
 
     # ----------------------------------------------------------- compute
     def _run_prefill_chunk_bucketed(self, slot_idx: int, clock) -> None:
-        """One bucket-shaped prompt chunk for one slot, under the
-        request's trace context — its prefill_chunk spans (and any
-        nested compile) correlate to the request id the final `request`
-        event carries, so a generation's prefill tree reconstructs from
-        the JSONL alone."""
-        req = self.slots.slots[slot_idx].request
-        with self.recorder.trace(req.request_id):
-            self._prefill_chunk_inner(slot_idx, clock)
-
-    def _prefill_chunk_inner(self, slot_idx: int, clock) -> None:
-        """The chunk itself. The argument
-        names and the enclosing span keep the G017/G019 contract
-        visible: the jit sees only padded bucket arrays, and the only
-        host fetch is the one batch-boundary np.asarray of the
-        next-token id."""
+        """One bucket-shaped prompt chunk for one slot: dispatched, and
+        under its span the program before it retired (`_land`'s twin
+        inside a step). The jit sees only padded bucket arrays and the
+        device's token vector (G017/G019); the only host fetch is the
+        one batch-boundary np.asarray of the retired program's tokens.
+        `step_prepare` and the chunk's span run under the request's
+        trace context, so a generation's prefill tree reconstructs from
+        the JSONL alone; the `emit` after them is another program's."""
         rec = self.recorder
-        with rec.span("step_prepare", follows=True, replica=self.index,
-                      kind="prefill"):
-            slot = self.slots.slots[slot_idx]
-            req = slot.request
-            L = req.prompt_len
-            Tc = self._next_chunk_len(L - slot.start)
-            n_real = min(Tc, L - slot.start)
-            padded_tokens = np.zeros((1, Tc), np.int32)
-            padded_tokens[0, :n_real] = req.tokens[slot.start:slot.start
-                                                   + n_real]
-            bucket_kmask = np.zeros((1, Tc), np.float32)
-            bucket_kmask[0, :n_real] = 1.0
-            final = slot.start + n_real >= L
-            key = ("prefill", Tc)
-            first = key not in self._seen_shapes
-            ws = self.weights.current
-            handed = self.cache
-            inputs = (padded_tokens, bucket_kmask,
-                      np.asarray([slot_idx], np.int32),
-                      np.asarray([slot.start], np.int32),
-                      np.asarray([n_real - 1], np.int32))
-        compiling = (rec.span("compile", kind="prefill", bucket=[1, Tc],
-                              replica=self.index)
-                     if first else contextlib.nullcontext())
-        try:
-            with rec.span("prefill_chunk", bucket=[1, Tc],
-                          start=slot.start, replica=self.index,
-                          final=final, n_real=n_real,
-                          **self._kv_blocks(slot.start)) as step, compiling:
-                with rec.span("dispatch"):
-                    tok, self.cache = self._prefill_jit(
-                        ws.params, ws.state, handed, *inputs)
-                with rec.span("fetch", follows=True):
-                    toks = np.asarray(tok)  # batch-boundary fetch
-                toks = self._split_fetch(toks, (1,), step)
-        except Exception as exc:
-            self._fail_step(handed, [slot_idx], exc, clock)
+        prev = self._flight
+        slot = self.slots.slots[slot_idx]
+        req = slot.request
+        flight = None
+        with rec.trace(req.request_id):
+            with rec.span("step_prepare", follows=True, replica=self.index,
+                          kind="prefill"):
+                L = req.prompt_len
+                Tc = self._next_chunk_len(L - slot.start)
+                n_real = min(Tc, L - slot.start)
+                padded_tokens = np.zeros((1, Tc), np.int32)
+                padded_tokens[0, :n_real] = req.tokens[slot.start:slot.start
+                                                       + n_real]
+                bucket_kmask = np.zeros((1, Tc), np.float32)
+                bucket_kmask[0, :n_real] = 1.0
+                final = slot.start + n_real >= L
+                key = ("prefill", Tc)
+                first = key not in self._seen_shapes
+                ws = self.weights.current
+                handed = self.cache
+                inputs = (padded_tokens, bucket_kmask,
+                          np.asarray([slot_idx], np.int32),
+                          np.asarray([slot.start], np.int32),
+                          np.asarray([n_real - 1], np.int32))
+            compiling = (rec.span("compile", kind="prefill", bucket=[1, Tc],
+                                  replica=self.index)
+                         if first else contextlib.nullcontext())
+            try:
+                with rec.span("prefill_chunk", bucket=[1, Tc],
+                              start=slot.start, replica=self.index,
+                              final=final, n_real=n_real,
+                              ahead=prev is not None,
+                              **self._kv_blocks(slot.start)) as step, \
+                        compiling:
+                    with rec.span("dispatch"):
+                        tok, self.cache = self._prefill_jit(
+                            ws.params, ws.state, handed, self._tokens,
+                            *inputs)
+                    # the prompt's last forward row IS the first
+                    # generated token: only a final chunk has one
+                    flight = self._took_off(
+                        tok, handed, [(slot_idx, slot)] if final else [],
+                        step)
+                    self._seen_shapes.add(key)
+                    slot.start += n_real
+                    if final:
+                        slot.pos, slot.sent = L, 1
+                    if self._verify_jit is not None:
+                        # the verify step drafts on the host from the
+                        # tokens emitted: nothing runs ahead of it, and
+                        # the chunk is retired under its own span
+                        prev, self._flight = flight, None
+                    toks = self._fetch(prev, step)
+            except Exception as exc:
+                self._fail_step(handed, [slot_idx], exc, clock,
+                                lost=flight is not None)
+                return
+        self._emit(prev, toks, clock)
+
+    def _took_off(self, tok, handed, rows: list, step: dict) -> "_Flight":
+        """The jit call has returned: the program is on the device's
+        queue. Its record becomes the one in flight (the caller holds
+        the one before, to retire it), its token vector the next
+        program's, and the copy home starts now, so that `fetch` finds
+        the tokens on the host when the device is done."""
+        tok.copy_to_host_async()
+        with self._mu:
+            self._programs += 1
+            if self._flight is not None:
+                self.steps_ahead += 1
+        step["program"] = self._programs
+        self._tokens = tok
+        self._flight = _Flight(self._programs, tok, handed, rows)
+        return self._flight
+
+    def _fetch(self, flight: "_Flight | None", step: dict | None = None):
+        """The one batch-boundary np.asarray of a dispatched program's
+        tokens, under a `fetch` span: inside `step`, the span of the
+        program dispatched after it, or alone in a pass that dispatches
+        nothing. The counters behind the tokens land where they came
+        home, and `fetched` says whose they are. None for no program
+        (the first dispatch after an `idle_wait` retires nothing)."""
+        if flight is None:
+            return None
+        alone = {"replica": self.index} if step is None else {}
+        with self.recorder.span("fetch", follows=True, **alone) as sp:
+            toks = np.asarray(flight.tok)  # batch-boundary fetch
+            home = sp if step is None else step
+            home["fetched"] = flight.program
+            return self._split_fetch(toks, (self.plan.n_slots,), home)
+
+    def _emit(self, flight: "_Flight | None", toks, clock) -> None:
+        """Retire a fetched program: hand each of its rows its token
+        (the stream put, the completion and its `request` event), then
+        drop the program's device outputs."""
+        self.current_batch = None
+        if flight is None:
             return
-        with rec.span("emit", follows=True, replica=self.index,
-                      tokens=int(final)) as sp:
-            if first:
-                self._seen_shapes.add(key)
-            slot.start += n_real
-            if final:
-                # the prompt's last forward row IS the first generated
-                # token: TTFT is this chunk's completion
-                slot.pos = L
-                slot.last_token = int(toks[0])
-                now = clock()
-                req.emit(slot.last_token, now)
-                with self._mu:
-                    self.tokens_out += 1
-                self._maybe_complete(slot_idx, clock)
+        with self.recorder.span("emit", follows=True, replica=self.index,
+                                tokens=len(flight.rows),
+                                program=flight.program) as sp:
+            now = clock()
+            for i, slot in flight.rows:
+                if self.slots.slots[i] is not slot:
+                    continue  # reaped from the supervisor's thread
+                slot.last_token = int(toks[i])
+                slot.request.emit(slot.last_token, now)
+                self._maybe_complete(i, clock)
+            with self._mu:
+                self.tokens_out += len(flight.rows)
+            # dropping a program's device outputs is a call into the
+            # runtime, and the first place after the stream puts where
+            # the engine thread lets go of the interpreter (PERF.md
+            # section 7 (d)), so it happens here, inside a named span
+            # and with a field of its own, not in a frame's teardown;
+            # the consumed tree's 2 x layers array handles go with it
             t_release = time.perf_counter()
-            del tok, handed  # as in the plain decode step
+            flight.tok = flight.handed = None
             sp["release_s"] = round(time.perf_counter() - t_release, 6)
+
+    def _land(self, clock) -> None:
+        """A pass that dispatches nothing: the program in flight is
+        retired alone, its `fetch` the child of no step."""
+        flight = self._flight
+        toks = self._fetch(flight)
+        self._flight = None
+        self._emit(flight, toks, clock)
 
     def _decode_batch_step(self, active: list, clock) -> None:
         """One fixed-shape decode step over every slot row; `active`
-        names the rows whose outputs are real. One np.asarray for the
-        whole [n_slots] next-token vector — the batch-boundary fetch —
-        then host-side distribution to the slots. `decode_step.slots`
-        names the rows: with the `admit` events (id -> slot) a request's
-        decode steps join to its id."""
+        names the rows whose outputs are real. Its tokens come from the
+        device (`self._tokens`: the vector the program before it left
+        there), its positions and its live set from the host's own
+        count, so it is dispatched BEFORE that program's tokens are
+        fetched: under this step's span the host then makes the one
+        np.asarray of the program before (the batch-boundary fetch) and,
+        after the span, distributes those tokens to their slots.
+        `decode_step.slots` names this step's rows: with the `admit`
+        events (id -> slot) a request's decode steps join to its id."""
         rec = self.recorder
+        prev = self._flight
+        flight = None
         with rec.span("step_prepare", follows=True, replica=self.index,
                       kind="decode"):
             B = self.plan.n_slots
-            padded_tokens = np.zeros(B, np.int32)
             pos = np.full(B, self.plan.capacity - 1, np.int32)  # scratch
-            for i in active:
-                slot = self.slots.slots[i]
-                padded_tokens[i] = slot.last_token
+            rows = [(i, self.slots.slots[i]) for i in active]
+            for i, slot in rows:
                 pos[i] = slot.pos
             ws = self.weights.current
             handed = self.cache
@@ -1150,6 +1301,7 @@ class _GenWorker:
         try:
             with rec.span("decode_step", replica=self.index,
                           n_active=len(active), slots=self.current_batch,
+                          ahead=prev is not None,
                           **self._kv_blocks(
                               pos[active].max() + 1)) as step:
                 if self.faults is not None:
@@ -1157,47 +1309,31 @@ class _GenWorker:
                                       self.decode_steps_run)
                 with rec.span("dispatch"):
                     tok, self.cache = self._decode_jit(
-                        ws.params, ws.state, handed,
-                        padded_tokens, pos, self._live(active))
-                with rec.span("fetch", follows=True):
-                    toks = np.asarray(tok)  # batch-boundary fetch
-                toks = self._split_fetch(toks, (B,), step)
+                        ws.params, ws.state, handed, self._tokens, pos,
+                        self._live(active))
+                flight = self._took_off(tok, handed, rows, step)
+                for _i, slot in rows:
+                    slot.pos += 1
+                    slot.sent += 1
+                toks = self._fetch(prev, step)
         except ReplicaKilled as exc:
-            # injected mid-decode death: every active slot fails (pages
-            # released by _fail_slot), the thread dies; the supervisor
+            # injected mid-decode death, before the call: the program in
+            # flight is retired, then every active slot fails (pages
+            # released by _fail_slot) and the thread dies; the supervisor
             # respawns — pending requests stay queued with the worker.
             # Death is marked BEFORE the futures complete so a waiter
             # that saw the failure also sees the dead worker.
             self.current_batch = None
             self.alive = False
             self.lifecycle = "dead"
-            for i in active:
-                self._fail_slot(i, exc, clock)
+            self._fail_step(handed, active, exc, clock)
             raise
         except Exception as exc:
-            self._fail_step(handed, active, exc, clock)
+            self._fail_step(handed, active, exc, clock,
+                            lost=flight is not None)
             self.current_batch = None
             return
-        with rec.span("emit", follows=True, replica=self.index,
-                      tokens=len(active)) as sp:
-            self.current_batch = None
-            now = clock()
-            for i in active:
-                slot = self.slots.slots[i]
-                slot.pos += 1
-                slot.last_token = int(toks[i])
-                slot.request.emit(slot.last_token, now)
-                with self._mu:
-                    self.tokens_out += 1
-                self._maybe_complete(i, clock)
-            # dropping the step's device outputs blocks (about a
-            # millisecond for the token vector on a v5e: PERF.md section
-            # 5), so it happens here, inside a named span and with a
-            # field of its own, not in the frame's teardown after it;
-            # the consumed tree's 2 x layers array handles go with it
-            t_release = time.perf_counter()
-            del tok, handed
-            sp["release_s"] = round(time.perf_counter() - t_release, 6)
+        self._emit(prev, toks, clock)
 
     def _speculative_batch_step(self, active: list, clock) -> None:
         """One fixed-shape VERIFY step over every slot row: each active
@@ -1209,7 +1345,10 @@ class _GenWorker:
         never ran. Draft proposal cost is metered host-side
         (`draft_overhead_us`) and the per-step `draft` telemetry event
         is what the replay's accepted_tokens_per_step headline
-        reconstructs from."""
+        reconstructs from. Serial by nature: the next window is drafted
+        from the tokens this step emits, so the step is dispatched,
+        fetched and emitted here, in one pass, and nothing of the plain
+        path's running ahead (`_flight`, `_took_off`, `_land`) is used."""
         rec = self.recorder
         with rec.span("step_prepare", follows=True, replica=self.index,
                       kind="verify"):
@@ -1278,6 +1417,7 @@ class _GenWorker:
                     with self._mu:
                         self.tokens_out += 1
                 slot.pos += take
+                slot.sent += take
                 slot.last_token = int(emitted[take - 1])
                 step_emitted += take
                 step_accepted += take - 1  # drafts accepted (bonus aside)
@@ -1350,27 +1490,51 @@ class _GenWorker:
                                       self.plan.kv_dtype,
                                       self.plan.page_size)
 
-    def _fail_step(self, handed, own: list, exc: Exception,
-                   clock) -> None:
+    def _alloc_tokens(self):
+        """The slots' last tokens on the device, before any program has
+        written one: [n_slots] ids and the step's counters behind them,
+        the shape of every plain step's fetched array."""
+        import jax.numpy as jnp
+
+        return jnp.zeros(self.plan.n_slots + len(self.step_counters),
+                         jnp.int32)
+
+    def _fail_step(self, handed, own: list, exc: Exception, clock,
+                   lost: bool = False) -> None:
         """Containment of a failed step, by what became of the cache it
         was handed. A step that raised before it ran left `handed` live:
-        only its `own` slots fail. One that ran has consumed it: the
-        donated buffers are deleted and the outputs poisoned, so the
-        rows of every occupied slot are gone, those of prefilling slots
-        too. All of them fail (pages released), a fresh cache is
-        allocated as in __init__, one `error` names the loss,
-        `cache_losses` counts it, and the worker serves on."""
+        the program in flight, which ran before it on a sound cache, is
+        retired first (its tokens are real and reach their requests),
+        then only the step's `own` slots fail. One that ran has consumed
+        it: the donated buffers are deleted and the outputs poisoned, so
+        the rows of every occupied slot are gone, those of prefilling
+        slots too (the program in flight is still retired first: it is
+        older than the fault). `lost` says the caller knows worse: the
+        FETCH of the program in flight raised, so that program failed on
+        the device and whatever was dispatched on its output is poisoned
+        with it. Either way every occupied slot fails once (pages
+        released), the in-flight record is dropped, a fresh cache and
+        token vector are allocated as in __init__, one `error` names the
+        loss, `cache_losses` counts it once, and the worker serves on."""
         import jax
 
-        if not any(leaf.is_deleted() for leaf in jax.tree.leaves(handed)):
+        if not lost:
+            lost = any(leaf.is_deleted() for leaf in jax.tree.leaves(handed))
+            try:
+                if self._flight is not None:
+                    self._land(clock)
+            except Exception:
+                lost = True  # the program before had failed too
+        if not lost:
             for i in own:
                 self._fail_slot(i, exc, clock)
             return
         # drop the failed step's outputs first: beside a fresh cache
         # they would hold the cache's bytes twice
-        self.cache = None
+        self._flight = self.cache = None
         self._fail_occupied(exc, clock, cache_lost=True)
         self.cache = self._alloc_cache()
+        self._tokens = self._alloc_tokens()
         with self._mu:
             self.cache_losses += 1
         self.recorder.error(f"gen-replica:{self.index}", exc=exc,
@@ -1415,6 +1579,15 @@ class _GenWorker:
                         else:
                             self._decode_batch_step(active, clock)
                         progressed = True
+                    if not progressed and self._flight is not None:
+                        # the last program of a busy spell: nothing to
+                        # dispatch over it, so it is retired alone, and
+                        # the slots it frees are admitted to next pass
+                        try:
+                            self._land(clock)
+                        except Exception as exc:
+                            self._fail_step(None, [], exc, clock, lost=True)
+                        progressed = True
                 except ReplicaKilled:
                     return  # dead: the fleet supervisor respawns
                 if progressed:
@@ -1447,6 +1620,7 @@ class _GenWorker:
         self.alive = True
         self.lifecycle = "warming"
         self.current_batch = None
+        self._flight = None  # a wedged thread's: its slots were reaped
         with self._mu:
             self.decode_steps_run = 0
         self.warmup(clock)
@@ -1488,6 +1662,7 @@ class _GenWorker:
                    "failed": self.failed,
                    "cache_losses": self.cache_losses,
                    "decode_steps_run": self.decode_steps_run,
+                   "steps_ahead": self.steps_ahead,
                    **self.weights_facts}
             if self.speculative_k >= 2:
                 out["verify_steps_run"] = self.verify_steps_run
@@ -1717,6 +1892,7 @@ class GenerationEngine:
             "served": self.served,
             "failed": self.failed,
             "tokens_out": sum(w.tokens_out for w in self._workers),
+            "steps_ahead": sum(w.steps_ahead for w in self._workers),
             "queue_depth": sum(w.depth for w in self._workers),
             "trace_count": self.trace_count,
             "restored_step": self.restored_step,

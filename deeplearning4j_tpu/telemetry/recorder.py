@@ -84,8 +84,10 @@ for it. The engine's `meta` event gives the block length,
 `decode_block_k` (null for a net whose layers own their cached
 forward). Where the
 served net has a counting layer (the dropless expert layer,
-nn/layers/moe.py `DroplessMoELayer`), all three carry what the step's
-program counted, handed home behind the tokens in the one fetch:
+nn/layers/moe.py `DroplessMoELayer`), all three carry what a step's
+program counted, handed home behind the tokens in the one fetch (a
+plain step's span carries the counters of the program it `fetched`, the
+one before its own: the host loop's paragraph below):
 `moe_pairs` (the (token, held selected expert) pairs the step had to
 compute, over all expert layers; the pad of a bucket and idle slots
 select nothing), `moe_rows` (the expert rows it did compute for them:
@@ -99,8 +101,8 @@ all layers}: "k" and "v", or "ckv" and "kpe" for a latent row) and
 retention layer) and `state_bytes_per_slot`. A net with such a layer
 also puts `state_resets` on its steps' spans: the rows whose state the
 step zeroed because they start a sequence (a request's first
-`prefill_chunk` reads 1, so the window's sum is the requests admitted;
-a `decode_step` reads 0).
+`prefill_chunk` counts 1, so the window's sum is the requests admitted;
+a `decode_step` counts 0; read on the span that `fetched` the program).
 
 The generation engine's `meta` event, /stats and each worker's
 `describe()` also say what the engine serves FROM (serving/engine.py
@@ -116,24 +118,49 @@ dtype, cast once when it is built: nn/decode.serving_params):
 The engine thread's host loop (serving/engine.py `_GenWorker`) is named
 whole: every instant between two model steps lies in one of six LEAF
 spans, so a device idle gap laid over them names what the host was
-doing. Per pass of the loop: `admit` (one admission pass under the
-queue lock — `admitted`, `pending` = left waiting, `blocked` =
-"slots" / "pages" / null, why the head of the queue stayed), then per
-model step `step_prepare` (the numpy build of the step's arguments —
-`kind` "prefill" / "decode" / "verify"), inside the
-`prefill_chunk` / `decode_step` / `verify_step` span its two children
-`dispatch` (until the jit call returns: argument flattening and the
-enqueue) and `fetch` (the one batch-boundary `np.asarray`, which waits
-for the device), and `emit` (from the fetch's return to the step's last
-completion: the release of the step's device outputs, the per-slot
-stream puts, completions, `request` events — `tokens`, `release_s` =
-the part spent dropping the fetched device arrays); or, with nothing
-to run, `idle_wait` (the `_cv.wait`: an idle
-device under it has an idle engine, not a slow one). `admit`,
-`step_prepare`, `fetch`, `emit` and `idle_wait` are opened with
-`follows=True`: each starts where the region before it ended, so the
-recorder's own emission lies inside a named leaf; the model step's span
-and its `dispatch` keep their own start, just before the jit call.
+doing. THE LOOP RUNS ONE PROGRAM AHEAD (PR 36): a program (a prompt
+chunk or a decode step) is fetched and emitted only after the next one
+has been dispatched, so a plain step's span holds parts of TWO
+programs, and says which. Per pass of the loop: `admit` (one admission
+pass under the queue lock — `admitted`, `pending` = left waiting,
+`blocked` = "slots" / "pages" / null, why the head of the queue
+stayed), then per program `step_prepare` (the numpy build of the
+step's arguments — `kind` "prefill" / "decode" / "verify"), then the
+`prefill_chunk` / `decode_step` span of the program being LAUNCHED
+(`program` = its sequence number on this replica; `n_active`, `slots`,
+`kv_blocks`, `bucket`, `start`, `n_real` are ITS rows and positions, as
+the host counts them at dispatch; `ahead` = true when it was dispatched
+while the program before it was un-retired, which in a busy server is
+every step but the first after an `idle_wait`) with two children:
+`dispatch` (of the launched program, until the jit call returns:
+argument flattening and the enqueue) and `fetch` (of the program BEFORE
+it, `fetched` = that program's number: the one batch-boundary
+`np.asarray`, which waits for the device only as long as that earlier
+program still runs; the counters behind its tokens — `moe_pairs`,
+`moe_rows`, `moe_max_load`, `state_resets` — land on the span under
+which they came home, so a step's span carries the counters of program
+`fetched`, not of `program`; a step with `ahead` false has no `fetch`
+and no counters), and after the span `emit` of that earlier program
+(`program` = its number; from the fetch's return to its last
+completion: the per-slot stream puts, completions, `request` events,
+the release of its device outputs — `tokens`, `release_s` = the part
+spent dropping the fetched device arrays). So a step's span is no
+longer "dispatch to ITS fetched tokens" but one pass of the loop's
+device-facing part, and consecutive step spans still do not overlap:
+the next `t0` less this `t1` is the host's time between two steps.
+The last program of a busy spell is retired in a pass that dispatches
+nothing: a `fetch` (`replica`, `fetched`, its counters) and an `emit`
+that are children of no step. Or, with nothing to run and nothing in
+flight, `idle_wait` (the `_cv.wait`: an idle device under it has an
+idle engine, not a slow one). A `verify_step` (speculative mode) does
+not run ahead: its span holds its own `dispatch` and `fetch`, as does
+the span of a chunk on such a worker (`fetched` equals `program`).
+`admit`, `step_prepare`, `fetch`, `emit` and `idle_wait` are opened
+with `follows=True`: each starts where the region before it ended, so
+the recorder's own emission lies inside a named leaf; the model step's
+span and its `dispatch` keep their own start, just before the jit call.
+`GenerationEngine.stats()` (`steps_ahead`, and per worker in `fleet`)
+counts the programs dispatched `ahead`.
 
 The input pipeline (data/pipeline.py) names an ``input_wait`` span
 around EVERY batch dequeue in the fit loops: `pipelined` (false = the

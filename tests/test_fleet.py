@@ -531,7 +531,10 @@ def test_gen_worker_kill_mid_decode_releases_pages_and_respawns(tmp_path):
     req = engine.submit_generate(prompt, max_new_tokens=6)
     assert req.wait(DEADLINE_S), "killed generation missed its deadline"
     assert req.error is not None and "ReplicaKilled" in req.error
+    # step 1 was in flight when the kill fired before step 2's call: it
+    # was retired first, so the request holds the chunk's token and its
     worker = engine.fleet_workers()[0]
+    assert len(req.emitted) == 2 and worker._flight is None
     assert worker.lifecycle == "dead"
     assert worker.pool.describe()["pages_in_use"] == 0, \
         "a dead slot leaked its pages"
@@ -544,6 +547,32 @@ def test_gen_worker_kill_mid_decode_releases_pages_and_respawns(tmp_path):
     rec.close()
     kinds = [e["kind"] for e in _events(tpath, "fault")]
     assert kinds == ["replica-kill", "replica-dead", "replica-respawn"]
+
+
+def test_gen_worker_kill_retires_the_program_in_flight_first():
+    """A kill before decode step 3's call, with step 2 in flight over
+    two slots: step 2 spent the short request's budget, so that request
+    is not among step 3's rows. It must not be left holding its slot on
+    a dead worker: step 2 is retired (the short request completes, its
+    pages come back), then the rows of the step that died fail."""
+    from deeplearning4j_tpu.serving.engine import GenerationEngine
+
+    engine = GenerationEngine(
+        replay._tiny_lm(24), BucketLattice(batch_sizes=(1,), seq_lens=(8,)),
+        slots=2, max_new_tokens=8, page_size=4, faults="r0:kill@decode3")
+    engine.warmup()
+    # short's chunk, step 1 (short); long's chunk, step 2 (both); [step 3]
+    short = engine.submit_generate(np.arange(5, dtype=np.int32), 3)
+    long = engine.submit_generate(np.arange(8, dtype=np.int32), 6)
+    engine.start()
+    assert long.wait(DEADLINE_S) and short.wait(DEADLINE_S)
+    worker = engine.fleet_workers()[0]
+    assert worker.lifecycle == "dead" and worker._flight is None
+    assert "ReplicaKilled" in long.error and len(long.emitted) == 2
+    assert short.error is None and len(short.emitted) == 3
+    assert (engine.failed, engine.served) == (1, 1)
+    assert not worker.slots.busy()
+    assert worker.pool.describe()["pages_in_use"] == 0
 
 
 # --------------------------------------------- scale up / drain down

@@ -218,7 +218,7 @@ def test_decode_slots_state_machine():
     s1.start = 4
     assert slots.next_prefill() == 1
     assert slots.decoding() == [0]
-    r1.emitted = [1, 2]  # budget spent: no longer decoding
+    s1.sent = 2  # budget dispatched: no longer decoding
     assert slots.decoding() == []
     assert slots.release(0) == 2
     assert slots.free_index() == 0
@@ -483,7 +483,7 @@ def test_cache_is_rebound_at_dispatch_and_readers_see_it_whole():
     worker = engine.fleet_workers()[0]
     nbytes = tree_bytes(worker.cache)
     wrong = []  # a sink's exception is swallowed: collect, assert below
-    seen = {"handed": worker.cache, "steps": 0}
+    seen = {"handed": worker.cache, "steps": 0, "over": 0}
 
     def at_dispatch(ev):
         if ev.get("event") != "span" or ev.get("name") != "dispatch":
@@ -492,6 +492,15 @@ def test_cache_is_rebound_at_dispatch_and_readers_see_it_whole():
             wrong.append("a consumed cache is bound after dispatch")
         if worker.cache is seen["handed"] or not _consumed(seen["handed"]):
             wrong.append("the tree the step was handed was not consumed")
+        # the program before this one is still the one in flight: its
+        # tokens are on the device and the tree IT consumed is its own to
+        # drop, at its retirement
+        before = worker._flight
+        if before is not None:
+            seen["over"] += 1
+            if before.tok is None or before.tok.is_deleted() \
+                    or not _consumed(before.handed):
+                wrong.append("the program in flight was already retired")
         seen["handed"] = worker.cache
         seen["steps"] += 1
 
@@ -528,6 +537,11 @@ def test_cache_is_rebound_at_dispatch_and_readers_see_it_whole():
     assert wrong == []
     # one prefill chunk a request, then one decode step a further token
     assert seen["steps"] == tokens
+    # each request alone: every program but its first was dispatched over
+    # the one before it
+    assert seen["over"] == tokens - len(_MIXED) \
+        == engine.stats()["steps_ahead"]
+    assert worker._flight is None
     assert polls and set(polls) == {nbytes}
     assert not _consumed(worker.cache)
 
@@ -569,6 +583,100 @@ def test_donated_replay_is_bit_identical_to_undonated_jits():
         "the replay's streams do not depend on the prompt"
     assert [len(out) for out in donated] == [olen for _, olen in _MIXED]
     assert min(aliased) > 0 and unaliased == [0, 0, 0]
+
+
+def _family_net(family):
+    """(net, vocabulary, kv_dtype) of a tiny net of each served family."""
+    if family in ("gpt2", "gpt2_int8"):
+        return (_prompt_dependent_lm(24), 64,
+                "int8" if family == "gpt2_int8" else "f32")
+    if family == "latent_moe":
+        from tests import test_latent_moe as tiny
+    else:
+        from tests import test_power_retention as tiny
+    return tiny.tiny_net(tiny.seeded_weights(31)), tiny.DIMS["V"], "f32"
+
+
+# (prompt length, new tokens): 9-16 take two chunks of 8; on three slots
+# the slots end mid-batch, get later tenants, and chunks fall between steps
+_TENANTS = ((3, 2), (16, 8), (5, 3), (11, 1), (8, 5), (13, 6), (1, 4),
+            (9, 8))
+
+
+@pytest.mark.parametrize("family", ["gpt2", "gpt2_int8", "latent_moe",
+                                    "retention"])
+def test_replay_one_program_ahead_serves_what_each_request_gets_alone(
+        family):
+    """The same work: with every program dispatched before the one
+    before it is fetched, with positions and the live set advanced at
+    dispatch and slots released at retirement, a replay of mixed lengths
+    on fewer slots than requests serves each request, token for token,
+    what a fresh engine serves it alone. One decode program, compiled at
+    warmup, whatever the source of a row's token."""
+    net, vocab, kv_dtype = _family_net(family)
+    rng = np.random.default_rng(36)
+    prompts = [rng.integers(0, vocab, plen).astype(np.int32)
+               for plen, _ in _TENANTS]
+
+    def engine_of():
+        engine = GenerationEngine(
+            net, BucketLattice(batch_sizes=(1,), seq_lens=(8, 16)), slots=3,
+            max_new_tokens=8, page_size=8, prefill_chunk=8,
+            kv_dtype=kv_dtype)
+        return engine, engine.warmup()
+
+    engine, warm = engine_of()
+    reqs = [engine.submit_generate(p, olen)
+            for p, (_, olen) in zip(prompts, _TENANTS)]
+    engine.start()
+    for req in reqs:
+        assert req.wait(120) and req.error is None
+    stats = engine.stats()
+    engine.drain()
+    assert engine.trace_count == warm, "a step retraced after warmup"
+    assert (engine.failed, engine.served) == (0, len(_TENANTS))
+    programs = stats["fleet"][0]["decode_steps_run"] + sum(
+        -(-plen // 8) for plen, _ in _TENANTS)
+    # busy from the first admission to the last token: every program but
+    # the first was dispatched over an un-retired one
+    assert stats["steps_ahead"] == programs - 1
+    served = [list(r.emitted) for r in reqs]
+    assert [len(out) for out in served] == [olen for _, olen in _TENANTS]
+    assert len({tuple(out[:1]) for out in served}) > 2, \
+        "the streams do not depend on the prompt"
+    for prompt, (_, olen), out in zip(prompts, _TENANTS, served):
+        alone, _ = engine_of()
+        alone.start()
+        assert alone.generate(prompt, olen, timeout=120) == out
+        alone.drain()
+
+
+def test_drain_returns_after_the_last_program_in_flight_is_emitted():
+    """drain() with work queued and a program in flight: it returns only
+    when every request holds all its tokens, the last program was retired
+    in a pass of its own (nothing was left to dispatch over it) and its
+    `emit` is on the record before the `drain`."""
+    rec = Recorder(path=None, keep=100_000)
+    engine = _gen_engine(_prompt_dependent_lm(24), rec)
+    engine.warmup()
+    worker = engine.fleet_workers()[0]
+    rng = np.random.default_rng(3)
+    reqs = [engine.submit_generate(rng.integers(0, 64, plen), olen)
+            for plen, olen in ((5, 8), (12, 6), (3, 7))]
+    engine.start()
+    engine.drain()  # no wait on any request first
+    assert all(r.done.is_set() and r.error is None for r in reqs)
+    assert [len(r.emitted) for r in reqs] == [8, 6, 7]
+    assert worker._flight is None
+    assert worker.pool.describe()["pages_in_use"] == 0
+    spans = [e for e in rec.events if e["event"] == "span"]
+    steps = [e for e in spans if e["name"] in ("prefill_chunk", "decode_step")]
+    emits = [e for e in spans if e["name"] == "emit"]
+    assert [e["program"] for e in emits] == sorted(
+        e["program"] for e in steps)
+    [drain] = [e for e in spans if e["name"] == "drain"]
+    assert emits[-1]["seq"] < drain["seq"]
+    assert (drain["served"], drain["failed"]) == (3, 0)
 
 
 def _two_chunk_engine(net, rec, **kw):
@@ -665,6 +773,142 @@ def test_fault_before_the_call_fails_only_its_slots_and_keeps_the_cache():
     assert worker.pool.describe()["pages_in_use"] == 0
     assert worker.describe()["cache_losses"] == 0
     assert not [e for e in rec.events if e.get("lost") == "kv_cache"]
+
+
+def test_consumed_cache_with_a_program_in_flight_fails_every_slot_once():
+    """The third decode step consumes the cache and raises while the
+    second prompt's final chunk is in flight. That chunk ran before the
+    fault on a sound cache: it is retired and its first token reaches
+    its request; then every occupied slot fails ONCE, one cache is
+    allocated, `cache_losses` is 1, every page is back, and the request
+    that waited is served right, without a retrace."""
+    net = _prompt_dependent_lm(24)
+    rec = Recorder(path=None)
+    engine, worker, allocs = _two_chunk_engine(net, rec)
+    warm = engine.trace_count
+    real, calls, in_flight = worker._decode_jit, [], []
+
+    def consume_then_raise(params, state, cache, *inputs):
+        calls.append(1)
+        out = real(params, state, cache, *inputs)
+        if len(calls) == 3:
+            in_flight.append(worker._flight)
+            raise RuntimeError("device fault after the cache was donated")
+        return out
+
+    worker._decode_jit = consume_then_raise
+    rng = np.random.default_rng(5)
+    decoding = engine.submit_generate(rng.integers(0, 64, 5), 6)
+    prefilling = engine.submit_generate(rng.integers(0, 64, 16), 4)
+    prompt = rng.integers(0, 64, 7).astype(np.int32)
+    queued = engine.submit_generate(prompt, 5)  # no slot left: it waits
+    engine.start()
+    for req in (decoding, prefilling):
+        assert req.wait(60) and "device fault" in req.error
+    # chunk + steps 1 and 2; the final chunk that was in flight
+    assert (len(decoding.emitted), len(prefilling.emitted)) == (3, 1)
+    [flight] = in_flight
+    assert flight is not None and [i for i, _ in flight.rows] == [1]
+    assert queued.wait(60) and queued.error is None
+    assert list(queued.emitted) == _greedy_full_forward(net, prompt, 5)
+    engine.drain()
+    assert allocs == [1] and worker._flight is None
+    assert not _consumed(worker.cache)
+    assert engine.trace_count == warm, "the fresh cache retraced a step"
+    assert worker.pool.describe()["pages_in_use"] == 0
+    assert (engine.failed, engine.served) == (2, 1)
+    assert worker.describe()["cache_losses"] == 1
+    assert len([e for e in rec.events if e.get("event") == "request"
+                and not e["ok"]]) == 2     # once each
+    assert len([e for e in rec.events if e.get("lost") == "kv_cache"]) == 1
+
+
+def test_a_fetch_that_raises_loses_the_cache_and_what_flew_on_it():
+    """A program that fails on the device shows when its tokens are
+    fetched, a pass late: what was dispatched on its output is poisoned
+    with it. Every occupied slot fails once, the in-flight record and the
+    poisoned token vector go, one cache is allocated, and the worker
+    serves the next request right."""
+    net = _prompt_dependent_lm(24)
+    rec = Recorder(path=None)
+    engine, worker, allocs = _two_chunk_engine(net, rec)
+    real, calls = worker._decode_jit, []
+
+    class Poisoned:
+        """The second decode step's tokens: the next program can be
+        enqueued on them, the host cannot have them."""
+
+        def __init__(self, tok):
+            self.tok = tok
+
+        def copy_to_host_async(self):
+            pass
+
+        def __array__(self, *a, **kw):
+            raise RuntimeError("the program failed on the device")
+
+    def second_step_fails_late(params, state, cache, last, *inputs):
+        calls.append(1)
+        tok, out = real(params, state, cache, getattr(last, "tok", last),
+                        *inputs)
+        return (Poisoned(tok) if len(calls) == 2 else tok), out
+
+    worker._decode_jit = second_step_fails_late
+    rng = np.random.default_rng(5)
+    first = engine.submit_generate(rng.integers(0, 64, 5), 6)
+    second = engine.submit_generate(rng.integers(0, 64, 4), 6)
+    prompt = rng.integers(0, 64, 7).astype(np.int32)
+    queued = engine.submit_generate(prompt, 5)
+    engine.start()
+    for req in (first, second):
+        assert req.wait(60) and "failed on the device" in req.error
+    assert queued.wait(60) and queued.error is None
+    assert list(queued.emitted) == _greedy_full_forward(net, prompt, 5)
+    engine.drain()
+    assert allocs == [1] and worker._flight is None
+    assert not _consumed(worker.cache)
+    assert len(calls) >= 3  # the third step was dispatched on the lost one
+    assert (engine.failed, engine.served) == (2, 1)
+    assert worker.describe()["cache_losses"] == 1
+    assert worker.pool.describe()["pages_in_use"] == 0
+    assert len([e for e in rec.events if e.get("lost") == "kv_cache"]) == 1
+
+
+def test_fault_before_the_call_emits_the_tokens_of_the_program_in_flight():
+    """The third decode step's fault fires before its jit call, with the
+    second step in flight: that step is retired first, so both requests
+    hold its token when they fail (the chunk's, step 1's and step 2's:
+    three), the cache is kept, and the next request is served right."""
+    net = _prompt_dependent_lm(24)
+    rec = Recorder(path=None)
+    engine, worker, allocs = _two_chunk_engine(net, rec)
+    in_flight = []
+
+    def check(index, unit, count):
+        if unit == "decode" and count == 4:
+            in_flight.append(worker._flight)
+            raise RuntimeError("injected before the call")
+
+    worker.faults = types.SimpleNamespace(check=check)
+    rng = np.random.default_rng(5)
+    # one chunk each: chunk a, chunk b + step 1 (a alone), steps 2, 3, [4]
+    a = engine.submit_generate(rng.integers(0, 64, 5), 6)
+    b = engine.submit_generate(rng.integers(0, 64, 4), 6)
+    prompt = rng.integers(0, 64, 7).astype(np.int32)
+    queued = engine.submit_generate(prompt, 5)
+    engine.start()
+    for req in (a, b):
+        assert req.wait(60) and "injected before" in req.error
+    [flight] = in_flight
+    assert sorted(i for i, _ in flight.rows) == [0, 1]
+    assert (len(a.emitted), len(b.emitted)) == (4, 3)
+    assert queued.wait(60) and queued.error is None
+    assert list(queued.emitted) == _greedy_full_forward(net, prompt, 5)
+    engine.drain()
+    assert allocs == [] and worker._flight is None
+    assert (engine.failed, engine.served) == (2, 1)
+    assert worker.describe()["cache_losses"] == 0
+    assert worker.pool.describe()["pages_in_use"] == 0
 
 
 @pytest.mark.parametrize("consumed", [False, True])

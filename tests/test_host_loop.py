@@ -2,7 +2,9 @@
 pass of `_GenWorker.loop` shows as named leaf spans, one request id
 joins the front door, the queue, the prefill chunks, the decode steps
 and the stream, spans stand on `perf_counter`, and with telemetry off
-the loop builds nothing and `/metrics` still counts requests."""
+the loop builds nothing and `/metrics` still counts requests. Since
+ISSUE 36 the loop runs one program ahead: a step's span holds the
+`dispatch` of its own program and the `fetch` of the one before."""
 
 import contextlib
 import json
@@ -76,12 +78,13 @@ def served_run():
     assert not any(t.is_alive() for t in threads)
     server.stop()
     assert engine.trace_count == warm  # no field reached a jitted argument
-    return list(rec.events), answers
+    assert engine.fleet_workers()[0]._flight is None  # all retired
+    return list(rec.events), answers, engine.stats()
 
 
 @pytest.mark.parametrize("rid,plen,new_tokens", REQUESTS)
 def test_request_timeline_joins_on_its_id(served_run, rid, plen, new_tokens):
-    events, answers = served_run
+    events, answers, _ = served_run
     assert answers[rid][-1]["done"] and answers[rid][-1]["id"] == rid
     assert len(answers[rid][-1]["tokens"]) == new_tokens
     [admit] = [e for e in events if e["event"] == "admit" and e["id"] == rid]
@@ -95,11 +98,21 @@ def test_request_timeline_joins_on_its_id(served_run, rid, plen, new_tokens):
     assert all(e["n_real"] <= e["bucket"][1] for e in chunks)
     assert [e["final"] for e in chunks] == [False] * (len(chunks) - 1) + [True]
     # decode: between admission and completion the slot is this request's
+    # (a step names the rows it DISPATCHED: the step launched over the
+    # request's last one no longer holds the slot, and the slot is released
+    # only when that last one is retired, so no later tenant's step is here)
     steps = [e for e in _spans(events, "decode_step")
              if admit["seq"] < e["seq"] < done["seq"]
              and admit["slot"] in e["slots"]]
     assert len(steps) == new_tokens - 1   # the first token is the prefill's
     assert all(e["n_active"] == len(e["slots"]) for e in steps)
+    # its tokens: the `emit` spans of exactly those programs and of the
+    # prompt's final chunk, one token each for this request
+    emits = {e["program"]: e for e in _spans(events, "emit")}
+    mine = [chunks[-1]["program"]] + [e["program"] for e in steps]
+    assert all(emits[p]["tokens"] >= 1 for p in mine)
+    # and the request ends inside the emit of its last step's program
+    assert done["parent_id"] == emits[mine[-1]]["span_id"]
     # stream: one record, a lag per token line, on the engine's clock
     assert stream["n"] == new_tokens == len(stream["lag_s"])
     assert all(0 <= lag < 60 for lag in stream["lag_s"])
@@ -107,24 +120,41 @@ def test_request_timeline_joins_on_its_id(served_run, rid, plen, new_tokens):
 
 
 def test_every_pass_of_the_loop_is_on_the_record_in_order(served_run):
-    events, _ = served_run
+    events, _, _ = served_run
     leaf = sorted((e for e in _spans(events) if e["name"] in LEAF),
                   key=lambda e: e["t0"])
     passes = "".join(LEAF[e["name"]] for e in leaf)
-    # a pass admits, then runs a prefill chunk and / or a decode step
-    # (prepare, dispatch, fetch, emit), or waits
-    assert re.fullmatch(r"(a(pdfe){1,2}|aw)+", passes), passes
-    assert passes.count("pdfe") == len(_spans(events, "prefill_chunk")) \
-        + len(_spans(events, "decode_step"))
+    # a pass admits, then launches a prefill chunk and / or a decode step
+    # (prepare, dispatch; where a program was in flight, its fetch inside
+    # the new step's span and its emit after it), or retires the last
+    # program of a busy spell alone (fetch, emit), or waits
+    assert re.fullmatch(r"(a(pd(fe)?){1,2}|afe|aw)+", passes), passes
+    steps = _spans(events, "prefill_chunk") + _spans(events, "decode_step")
+    assert passes.count("pd") == len(steps)
+    # every program is retired exactly once, in the order it was dispatched
+    assert passes.count("fe") == passes.count("f") == passes.count("e") \
+        == len(steps)
+    programs = sorted(e["program"] for e in steps)
+    assert programs == list(range(1, len(steps) + 1))
+    assert [e["program"] for e in _spans(events, "emit")] == programs
     # no two leaves overlap: they are one thread's consecutive regions
     assert all(x["t1"] <= y["t0"] + 2e-6 for x, y in zip(leaf, leaf[1:]))
-    # dispatch and fetch are children of the model step's own span
+    # a dispatch is a child of the model step it launches; a fetch is a
+    # child of the step launched over the program it brings home, or of
+    # nothing (that program was the last of a busy spell)
     by_id = {e["span_id"]: e for e in _spans(events) if "span_id" in e}
+    alone = 0
     for e in leaf:
-        if e["name"] in ("dispatch", "fetch"):
+        if e["name"] == "dispatch" or (e["name"] == "fetch"
+                                       and "parent_id" in e):
             assert by_id[e["parent_id"]]["name"] in ("prefill_chunk",
                                                      "decode_step")
-    # what an emit spends dropping the step's device arrays is its own field
+        elif e["name"] == "fetch":
+            assert e["replica"] == 0 and e["fetched"] in programs
+            alone += 1
+    assert alone == passes.count("afe") >= 1
+    # what an emit spends dropping the program's device arrays is its own
+    # field
     assert all(0 <= e["release_s"] <= e["seconds"] + 2e-6
                for e in _spans(events, "emit"))
     admits = _spans(events, "admit")
@@ -134,8 +164,49 @@ def test_every_pass_of_the_loop_is_on_the_record_in_order(served_run):
     assert any(e["blocked"] == "slots" and e["pending"] >= 1 for e in admits)
 
 
+def test_a_program_is_dispatched_before_the_one_before_it_is_fetched(
+        served_run):
+    """The mechanism on the record: a step with `ahead` true was launched
+    while its predecessor's tokens were still on the device: its
+    `dispatch` ends before the `fetch` of that predecessor begins, inside
+    one span, and `stats()` counts such steps. A step with `ahead` false
+    found nothing in flight: it fetches nothing and is the first program
+    after an `idle_wait` (or of the run)."""
+    events, _, stats = served_run
+    spans = _spans(events)
+    steps = sorted((e for e in spans
+                    if e["name"] in ("prefill_chunk", "decode_step")),
+                   key=lambda e: e["program"])
+    kids = {}
+    for e in spans:
+        if e["name"] in ("dispatch", "fetch") and "parent_id" in e:
+            kids.setdefault(e["parent_id"], {})[e["name"]] = e
+    ahead = [e for e in steps if e["ahead"]]
+    assert len(ahead) >= len(steps) - 3 and len(ahead) < len(steps)
+    for e in ahead:
+        mine = kids[e["span_id"]]
+        assert e["fetched"] == e["program"] - 1
+        assert e["t0"] <= mine["dispatch"]["t0"]
+        assert mine["dispatch"]["t1"] <= mine["fetch"]["t0"] + 2e-6
+        assert mine["fetch"]["t1"] <= e["t1"]
+    waits = [e["t1"] for e in spans if e["name"] == "idle_wait"]
+    for e in steps:
+        if not e["ahead"]:
+            assert "fetched" not in e and "fetch" not in kids[e["span_id"]]
+            before = [s for s in steps if s["program"] == e["program"] - 1]
+            assert not before or any(
+                before[0]["t1"] <= w <= e["t0"] for w in waits)
+    # consecutive step spans do not overlap (the next `t0` less this `t1`
+    # is the host's time between two steps)
+    assert all(x["t1"] <= y["t0"] for x, y in zip(steps, steps[1:]))
+    assert stats["steps_ahead"] == len(ahead) \
+        == stats["fleet"][0]["steps_ahead"]
+    assert stats["fleet"][0]["decode_steps_run"] == len(
+        [e for e in steps if e["name"] == "decode_step"])
+
+
 def test_leaf_spans_cover_the_engine_threads_time(served_run):
-    events, _ = served_run
+    events, _, _ = served_run
     leaf = [e for e in _spans(events) if e["name"] in LEAF]
     start = min(e["t0"] for e in leaf if e["name"] == "admit"
                 and e["admitted"])
@@ -146,7 +217,7 @@ def test_leaf_spans_cover_the_engine_threads_time(served_run):
 
 
 def test_span_events_stand_on_the_monotonic_clock(served_run):
-    events, _ = served_run
+    events, _, _ = served_run
     spans = [e for e in _spans(events) if "t0" in e]
     assert len(spans) > 50 and len(spans) == len(
         [e for e in _spans(events) if e["name"] != "drain"])
@@ -157,8 +228,10 @@ def test_span_events_stand_on_the_monotonic_clock(served_run):
 
 def test_a_decode_step_costs_six_events():
     """One request alone: every pass between two decode steps is the
-    same six records (admit, step_prepare, dispatch, fetch, decode_step,
-    emit), under the budget of eight; nothing is recorded per token."""
+    same six records (the emit of the program before the last, admit,
+    step_prepare, dispatch, the fetch of the last program, decode_step),
+    under the budget of eight; nothing is recorded per token, and running
+    one program ahead added no record."""
     net = replay._tiny_lm(24)
     rec = Recorder(path=None, keep=100_000)
     lat = BucketLattice(batch_sizes=(1,), seq_lens=(8, 16))
@@ -180,6 +253,12 @@ def test_a_decode_step_costs_six_events():
              if first < e["seq"] <= last]
     assert names[:6] == ["emit", "admit", "step_prepare", "dispatch",
                          "fetch", "decode_step"]
+    steps = _spans(events, "decode_step")
+    assert all(e["ahead"] and e["fetched"] == e["program"] - 1 for e in steps)
+    # the last step's tokens come home in a pass of their own
+    assert [e["fetched"] for e in _spans(events, "fetch")
+            if "parent_id" not in e] == [steps[-1]["program"]]
+    assert engine.stats()["steps_ahead"] == 15
 
 
 @pytest.mark.parametrize("plen", [1, 7, 8, 9, 16, 21, 24])

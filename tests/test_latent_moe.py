@@ -399,13 +399,21 @@ def test_engine_serves_the_block_over_http_in_bfloat16(W):
     assert engine.failed == 0
     steps = _spans(rec, "decode_step") + _spans(rec, "prefill_chunk")
     assert len(_spans(rec, "prefill_chunk")) == 5   # 1 + 2 + 2 chunks of 8
-    for e in steps:     # a lone token may select no held expert at all
-        assert 0 <= e["moe_max_load"] <= e["moe_pairs"] <= e["moe_rows"], e
-        assert (e["moe_pairs"] == 0) == (e["moe_rows"] == 0), e
-    assert all(e["moe_pairs"] > 0 for e in _spans(rec, "prefill_chunk"))
+    # a program's counters come home with its tokens, under the span that
+    # says it `fetched` them: the step dispatched after it, or a lone fetch
+    home = {e["fetched"]: e for e in rec.events
+            if e.get("event") == "span" and e.get("fetched") is not None}
+    assert sorted(home) == sorted(e["program"] for e in steps)
+    counted = {e["program"]: home[e["program"]] for e in steps}
+    for c in counted.values():  # a lone token may select no held expert
+        assert 0 <= c["moe_max_load"] <= c["moe_pairs"] <= c["moe_rows"], c
+        assert (c["moe_pairs"] == 0) == (c["moe_rows"] == 0), c
+    assert all(counted[e["program"]]["moe_pairs"] > 0
+               for e in _spans(rec, "prefill_chunk"))
     # a decode step of one live slot: 2 expert layers, at most 3 held
     # experts selected in each, one round of 3 x 8 rows where any is
-    assert all(e["moe_pairs"] <= 6 and e["moe_rows"] in (0, 24, 48)
+    assert all(counted[e["program"]]["moe_pairs"] <= 6
+               and counted[e["program"]]["moe_rows"] in (0, 24, 48)
                for e in _spans(rec, "decode_step") if e["n_active"] == 1)
 
 

@@ -374,11 +374,18 @@ def test_engine_serves_the_block_over_http_in_bfloat16(W):
     assert engine.failed == 0
     chunks = _spans(rec, "prefill_chunk")
     assert len(chunks) == 1 + 2 + 2 + 1 + 2         # chunks of 8
-    assert [e["state_resets"] for e in chunks] == [int(e["start"] == 0)
-                                                   for e in chunks]
+    # a program's counter comes home with its tokens, under the span that
+    # says it `fetched` them: the step dispatched after it, or a lone fetch
+    resets = {e["fetched"]: e["state_resets"] for e in rec.events
+              if e.get("event") == "span" and e.get("fetched") is not None}
+    assert [resets[e["program"]] for e in chunks] == [int(e["start"] == 0)
+                                                      for e in chunks]
     admitted = [e for e in rec.events if e.get("event") == "admit"]
-    assert sum(e["state_resets"] for e in chunks) == len(admitted) == len(asked)
-    assert all(e["state_resets"] == 0 for e in _spans(rec, "decode_step"))
+    assert sum(resets[e["program"]] for e in chunks) \
+        == len(admitted) == len(asked)
+    assert all(resets[e["program"]] == 0
+               for e in _spans(rec, "decode_step"))
+    assert sum(resets.values()) == len(asked)   # and nothing counted twice
 
 
 def test_a_second_request_in_a_slot_gets_the_tokens_a_fresh_engine_gives(W):

@@ -132,8 +132,8 @@ def test_equal_dtypes_serve_the_nets_own_arrays(build):
     assert engine.stats()["weights_bytes"] == tree_bytes(net.params)
 
     n = worker.plan.n_slots
-    step = (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
-            jnp.ones(n, bool))
+    # the slots' last tokens as the device holds them, positions, `live`
+    step = (worker._tokens, jnp.zeros(n, jnp.int32), jnp.ones(n, bool))
     text = [worker._decode_jit.lower(p, net.state, worker.cache, *step)
             .as_text() for p in (served, net.params)]
     assert text[0] == text[1]

@@ -28,24 +28,25 @@ from deeplearning4j_tpu.nn.conf import (
     NeuralNetConfiguration,
     RMSNormalization,
     RnnOutputLayer,
+    ScaleVertexConf,
     Updater,
 )
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 from deeplearning4j_tpu.nn.layers.moe import DroplessMoELayer
 
 
-def latent_moe_lm(vocab_size: int, d_model: int, n_heads: int, n_layers: int,
-                  *, q_rank: int, kv_rank: int, nope_dim: int, rope_dim: int,
-                  v_dim: int, d_ff: int, n_dense_layers: int = 1,
-                  n_experts: int = 8, top_k: int = 2, d_expert: int = 0,
-                  n_shared: int = 1, first_expert: int = 0, n_held: int = 0,
-                  routed_scaling: float = 1.0, rope_theta: float = 10000.0,
-                  eps: float = 1e-5, seed: int = 12345,
-                  learning_rate: float = 3e-4, dtype: str = "float32",
-                  param_dtype: str = "float32") -> ComputationGraph:
-    """`dtype` is the compute type, `param_dtype` the type the weights
-    are held in (a server holds them in the compute type: no cast a
-    step)."""
+def sandwich_moe_lm(attention, vocab_size: int, d_model: int, n_layers: int,
+                    *, d_ff: int, n_dense_layers: int, n_experts: int,
+                    top_k: int, d_expert: int, n_shared: int,
+                    first_expert: int, n_held: int, routed_scaling: float,
+                    selection_bias: bool = False, embed_scale: float = 1.0,
+                    eps: float, seed: int, learning_rate: float, dtype: str,
+                    param_dtype: str) -> ComputationGraph:
+    """The sandwich block around any attention: `attention(i)` gives
+    layer i's attention conf (vertex `blk{i}_attn`); the feed-forward
+    half, the four norms, the embedding (times `embed_scale` where that
+    is not 1) and the head are this function's. `latent_moe_lm` and
+    `models.grouped_moe.grouped_moe_lm` are this with their attention."""
     g = (
         NeuralNetConfiguration.builder()
         .seed(seed)
@@ -67,13 +68,12 @@ def latent_moe_lm(vocab_size: int, d_model: int, n_heads: int, n_layers: int,
                                         activation="identity", has_bias=False),
                 "tokens")
     prev = "embed"
+    if embed_scale != 1.0:
+        g.add_vertex("embed_scaled", ScaleVertexConf(scale=embed_scale), prev)
+        prev = "embed_scaled"
     for i in range(n_layers):
         b = f"blk{i}"
-        g.add_layer(f"{b}_attn", LatentAttentionLayer(
-            n_in=d_model, n_out=d_model, n_heads=n_heads, q_rank=q_rank,
-            kv_rank=kv_rank, nope_dim=nope_dim, rope_dim=rope_dim,
-            v_dim=v_dim, rope_theta=rope_theta, eps=eps,
-            activation="identity"), norm(f"{b}_n1", prev))
+        g.add_layer(f"{b}_attn", attention(i), norm(f"{b}_n1", prev))
         g.add_vertex(f"{b}_res1", ElementWiseVertexConf(op="add"),
                      prev, norm(f"{b}_n2", f"{b}_attn"))
         src = norm(f"{b}_n3", f"{b}_res1")
@@ -86,7 +86,8 @@ def latent_moe_lm(vocab_size: int, d_model: int, n_heads: int, n_layers: int,
                 n_in=d_model, n_out=d_model, n_experts=n_experts,
                 top_k=top_k, d_hidden=d_expert, n_shared=n_shared,
                 first_expert=first_expert, n_held=n_held,
-                routed_scaling=routed_scaling, activation="silu"), src)
+                routed_scaling=routed_scaling,
+                selection_bias=selection_bias, activation="silu"), src)
         g.add_vertex(f"{b}_res2", ElementWiseVertexConf(op="add"),
                      f"{b}_res1", norm(f"{b}_n4", f"{b}_ff"))
         prev = f"{b}_res2"
@@ -96,3 +97,28 @@ def latent_moe_lm(vocab_size: int, d_model: int, n_heads: int, n_layers: int,
     g.set_outputs("out")
     g.set_input_types(tokens=InputType.recurrent(1))
     return ComputationGraph(g.build())
+
+
+def latent_moe_lm(vocab_size: int, d_model: int, n_heads: int, n_layers: int,
+                  *, q_rank: int, kv_rank: int, nope_dim: int, rope_dim: int,
+                  v_dim: int, d_ff: int, n_dense_layers: int = 1,
+                  n_experts: int = 8, top_k: int = 2, d_expert: int = 0,
+                  n_shared: int = 1, first_expert: int = 0, n_held: int = 0,
+                  routed_scaling: float = 1.0, rope_theta: float = 10000.0,
+                  eps: float = 1e-5, seed: int = 12345,
+                  learning_rate: float = 3e-4, dtype: str = "float32",
+                  param_dtype: str = "float32") -> ComputationGraph:
+    """`dtype` is the compute type, `param_dtype` the type the weights
+    are held in (a server holds them in the compute type: no cast a
+    step)."""
+    return sandwich_moe_lm(
+        lambda i: LatentAttentionLayer(
+            n_in=d_model, n_out=d_model, n_heads=n_heads, q_rank=q_rank,
+            kv_rank=kv_rank, nope_dim=nope_dim, rope_dim=rope_dim,
+            v_dim=v_dim, rope_theta=rope_theta, eps=eps,
+            activation="identity"),
+        vocab_size, d_model, n_layers, d_ff=d_ff,
+        n_dense_layers=n_dense_layers, n_experts=n_experts, top_k=top_k,
+        d_expert=d_expert, n_shared=n_shared, first_expert=first_expert,
+        n_held=n_held, routed_scaling=routed_scaling, eps=eps, seed=seed,
+        learning_rate=learning_rate, dtype=dtype, param_dtype=param_dtype)

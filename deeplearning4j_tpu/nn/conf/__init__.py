@@ -34,6 +34,7 @@ from deeplearning4j_tpu.nn.conf.layers import (  # noqa: F401
     FeedForwardLayer,
     GatedDenseLayer,
     GravesBidirectionalLSTM,
+    GroupedAttentionLayer,
     GravesLSTM,
     GRU,
     Layer,

@@ -438,6 +438,35 @@ class PowerRetentionLayer(BaseRecurrentLayer):
             self.n_out = self.n_in
 
 
+@register_config
+@dataclasses.dataclass
+class GroupedAttentionLayer(BaseRecurrentLayer):
+    """Causal softmax attention with grouped heads, no biases
+    (nn/layers/grouped_attention.py holds the equations): `n_heads`
+    queries of `head_dim` read `n_kv_heads` key-value heads,
+    `n_heads / n_kv_heads` queries a head. `window` keys at most are
+    seen by a query, its own among them (0: every earlier key);
+    `rope_theta` turns queries and keys by their position (0: no
+    position at all). Each head's query and each head's key pass an RMS
+    norm with one learned gain vector, and the heads' output is
+    multiplied by sigmoid(x Wg) before the output projection. A layer
+    with a window keeps a RING of `window` rows a sequence in a serving
+    cache, whatever the cache's capacity."""
+
+    n_heads: int = 8
+    n_kv_heads: int = 0         # defaults to n_heads
+    head_dim: int = 0           # defaults to n_out / n_heads
+    window: int = 0             # 0: full attention
+    rope_theta: float = 0.0     # 0: no position
+    eps: float = 1e-5           # of the query's and the key's RMS norm
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in == 0:
+            self.n_in = input_type.flat_size()
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+
 def _conv_out_hw(h, w, kernel, stride, padding, mode, dilation):
     kh = (kernel[0] - 1) * dilation[0] + 1
     kw = (kernel[1] - 1) * dilation[1] + 1

@@ -11,7 +11,8 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   expert layer), or where it carries its own cached forward
   (`apply_cached`, causal where its conf has the word: attention and
   the positional encodings, nn/layers/attention.py,
-  nn/layers/latent_attention.py and nn/layers/power_retention.py).
+  nn/layers/latent_attention.py, nn/layers/grouped_attention.py and
+  nn/layers/power_retention.py).
   Elementwise, merge, scale and subset vertices ride along. Anything
   else (LSTMs, convolutions over time, bidirectional attention) raises
   when the plan is built, with the layer named. This module names no
@@ -31,7 +32,14 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   retention) uses neither; it reads the step itself, because a sum
   forgives nothing a row does: a row whose first position is 0 starts a
   sequence and its state is zeroed first, a token with `keep` 0 adds and
-  decays nothing, a row not `live` keeps its state bit for bit.
+  decays nothing, a row not `live` keeps its state bit for bit. A layer
+  whose entry is a RING of rows (grouped attention with a window: its
+  own head-major arrays, position p at row p % rows) reads the step
+  likewise: a row not `live` writes nothing (the scratch position would
+  land on a row a tenant needs), a chunk attends the ring as it found
+  it and writes after, `keep` 0 writes nothing, and the position a ring
+  row holds is arithmetic on `positions`, so a new tenant needs no
+  reset; it walks its entry through ops/decode_attention.py itself.
 * **The entry functions** build the `CacheStep`, walk, and pick the
   output rows. ``make_decode_fn``: ``(params, state, cache, token,
   pos[, live]) -> (probs, cache)``, one token a cache row, positions
@@ -40,7 +48,9 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   speculative verification step (serving/speculative.py accepts on the
   host); the decode step is this at K = 1. It raises, with the layer
   named, for a net with a layer whose step cannot be unwound (the impl
-  says `rewindable` False: a rejected draft's share of a state stays).
+  says `rewindable(conf)` False: a rejected draft's share of a state
+  stays, its rows in a ring have overwritten rows the accepted position
+  still sees).
   ``make_prefill_fn``: ``(params, state, cache, tokens, kmask, rows,
   start, last_idx) -> (probs_last, cache)``, a bucket-shaped chunk of a
   prompt into the cache rows `rows` from position `start` on, so that a
@@ -53,10 +63,12 @@ take ``kv_dtype`` ("f32": rows as the net computes them; "int8") and
 ``page_size``.
 
 A net with counting layers (an impl with `counters`: the expert layer
-through `apply_counted`, a state layer as the third value its
-`apply_cached` returns) makes each step return a third value, an int32
-vector in the order of the fn's ``counters`` attribute (empty, and two
-values returned, for any other net). ``live`` [B] bool, the
+through `apply_counted`, a state layer or a grouped-attention layer as
+the third value its `apply_cached` returns) makes each step return a
+third value, an int32 vector in the order of the fn's ``counters``
+attribute: each kind of counting layer's names, the kinds in the order
+the plan meets them (empty, and two values returned, for any other
+net). ``live`` [B] bool, the
 optional last argument of the decode and verify fns, is the caller's
 word on which rows hold a request (the serving engine pads its batch
 with idle rows): the others attend no key (key_limit 0, so an idle
@@ -167,14 +179,16 @@ def _decodable_layer(lc, impl) -> bool:
 
 
 def _mark_counters(fn, plan):
-    """`fn.counting`: the impl of the plan's counting layers (one with
+    """`fn.counting`: the impls of the plan's counting layers (one with
     `counters` names and `merge_counts`: the expert layer's, the
-    retention layer's) or None; `fn.counters`: the names of the int32
-    vector the step returns as its third value, () where there is no
-    such layer."""
-    fn.counting = next((op.impl for op in plan[2] if op.kind == "layer"
-                        and hasattr(op.impl, "counters")), None)
-    fn.counters = tuple(fn.counting.counters) if fn.counting else ()
+    retention layer's, the grouped-attention layer's), each kind once,
+    in the order the plan meets them; `fn.counters`: the names of the
+    int32 vector the step returns as its third value, kind after kind,
+    () where there is no such layer."""
+    fn.counting = list(dict.fromkeys(
+        op.impl for op in plan[2]
+        if op.kind == "layer" and hasattr(op.impl, "counters")))
+    fn.counters = tuple(n for impl in fn.counting for n in impl.counters)
     return fn
 
 
@@ -185,19 +199,26 @@ def cache_specs(net, capacity: int, kv_dtype: str = "f32",
     `cache_arrays` gives it: what `init_cache` allocates a batch of and
     what the serving allocator bills (serving/kvcache.bytes_per_slot).
     An array that is no row a position (a state) carries a third entry,
-    "slot"."""
+    "slot"; an array of rows that holds fewer positions than the
+    capacity (a ring) carries their number."""
     if kv_dtype == "int8" and capacity % page_size != 0:
         raise ValueError(
             f"int8 cache needs page-quantized capacity; {capacity} "
             f"is not a multiple of page_size {page_size}")
     _, _, ops = _plan(net)
-    return {op.name: {
-        arr: (tuple(shape), jnp.dtype(dt).name, *per)
-        for arr, (shape, dt, *per) in op.impl.cache_arrays(
-            op.conf, capacity, kv_dtype, page_size,
-            net.compute_dtype).items()}
-        for op in ops
-        if op.kind == "layer" and hasattr(op.impl, "cache_arrays")}
+
+    def arrays_of(op):
+        try:
+            return op.impl.cache_arrays(op.conf, capacity, kv_dtype,
+                                        page_size, net.compute_dtype)
+        except ValueError as e:     # a stored format the layer lacks
+            raise ValueError(
+                f"{op.name} ({type(op.conf).__name__}): {e}") from None
+
+    return {op.name: {arr: (tuple(shape), jnp.dtype(dt).name, *per)
+                      for arr, (shape, dt, *per) in arrays_of(op).items()}
+            for op in ops
+            if op.kind == "layer" and hasattr(op.impl, "cache_arrays")}
 
 
 def init_cache(net, batch: int, capacity: int, kv_dtype: str = "f32",
@@ -307,7 +328,8 @@ def _walk(net, plan, params, state, cache, x0, step, valid):
     `apply_cached` is called with its entry of the cache (None for a
     layer that keeps none) and `step`, and may return its counters
     behind (y, entry); a counting layer is told which tokens are real
-    (`valid`). Mirrors the containers' _forward dtype
+    (`valid`); the counters come back as (impl, counts) pairs. Mirrors
+    the containers' _forward dtype
     policy: float inputs and per-layer params cast to the compute dtype
     where the two differ."""
     in_name, out_name, ops = plan
@@ -330,14 +352,14 @@ def _walk(net, plan, params, state, cache, x0, step, valid):
             if hasattr(op.impl, "apply_cached"):
                 y, entry, *counted = op.impl.apply_cached(
                     op.conf, p, _as_seq(x), cache.get(op.name), step)
-                counts.extend(counted)
+                counts.extend((op.impl, c) for c in counted)
                 if entry is not None:
                     cache[op.name] = entry
                 if x.ndim == 2:     # a one-token walk that arrived 2-D
                     y = y[:, 0, :]  # stays so (see `_as_seq`)
             elif hasattr(op.impl, "apply_counted"):
                 y, c = op.impl.apply_counted(op.conf, p, x, valid)
-                counts.append(c)
+                counts.append((op.impl, c))
             else:
                 y, _ = op.impl.apply(op.conf, p, state.get(op.name, {}),
                                      x, train=False, rng=None)
@@ -422,12 +444,15 @@ def _as_seq(x):
 
 
 def _finish(fn, counts, out, cache):
-    """A step's return: (out, cache), and the layers' counters merged
-    into one int32 vector in the order of `fn.counters` where the plan
-    has counting layers."""
+    """A step's return: (out, cache), and the layers' counters merged,
+    each kind of counting layer by its own `merge_counts`, into one
+    int32 vector in the order of `fn.counters` where the plan has
+    counting layers."""
     if not fn.counters:
         return out, cache
-    total = fn.counting.merge_counts(counts)
+    total = {}
+    for impl in fn.counting:
+        total.update(impl.merge_counts([c for i, c in counts if i is impl]))
     return out, cache, jnp.stack(
         [total[n] for n in fn.counters]).astype(jnp.int32)
 
@@ -498,16 +523,16 @@ def make_verify_fn(net, kv_dtype: str = "f32", page_size: int = 16):
     (serving/speculative.py) compares argmax rows against the drafts;
     rejected positions' stale K/V stays invisible until the next verify
     window overwrites it. A layer whose step cannot be unwound (its impl
-    says `rewindable` False: a state, not rows) has no such forgiveness,
-    so a net with one is refused here, when the fn is built."""
+    says `rewindable(conf)` False: a state, or a ring of rows) has no such
+    forgiveness, so a net with one is refused here, when the fn is built."""
     plan = _plan(net)
     fixed = [f"{op.name} ({type(op.conf).__name__})" for op in plan[2]
-             if op.kind == "layer" and not op.impl.rewindable]
+             if op.kind == "layer" and not op.impl.rewindable(op.conf)]
     if fixed:
         raise ValueError(
             "speculative verification writes a window of drafts and "
-            "unwinds the rejected ones; these layers keep a state a step "
-            "cannot be taken out of again: " + ", ".join(fixed))
+            "unwinds the rejected ones; these layers keep a state or a "
+            "ring a step cannot be taken out of again: " + ", ".join(fixed))
 
     def verify(params, state, cache, tokens, pos, live=None):
         positions = pos[:, None] + jnp.arange(tokens.shape[1])[None, :]

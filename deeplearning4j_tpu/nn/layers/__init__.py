@@ -12,6 +12,7 @@ import deeplearning4j_tpu.nn.layers.convolution  # noqa: F401
 import deeplearning4j_tpu.nn.layers.recurrent  # noqa: F401
 import deeplearning4j_tpu.nn.layers.attention  # noqa: F401
 import deeplearning4j_tpu.nn.layers.latent_attention  # noqa: F401
+import deeplearning4j_tpu.nn.layers.grouped_attention  # noqa: F401
 import deeplearning4j_tpu.nn.layers.moe  # noqa: F401
 import deeplearning4j_tpu.nn.layers.power_retention  # noqa: F401
 import deeplearning4j_tpu.nn.layers.nested  # noqa: F401

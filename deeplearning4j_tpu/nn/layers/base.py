@@ -70,10 +70,14 @@ class LayerImpl:
     # sequence. A layer that has to know where its tokens stand carries
     # `apply_cached` instead (nn/layers/attention.py).
     per_position = False
-    # False where a serving step cannot be taken out of the layer's cache
-    # entry again (a state, not rows): nn/decode.make_verify_fn refuses
-    # such a net (nn/layers/power_retention.py)
-    rewindable = True
+
+    @staticmethod
+    def rewindable(conf) -> bool:
+        """False where a serving step cannot be taken out of this
+        layer's cache entry again (a state, a ring of rows: not rows a
+        later write hides): nn/decode.make_verify_fn refuses such a net
+        (nn/layers/power_retention.py, nn/layers/grouped_attention.py)."""
+        return True
 
     def init(self, conf, rng, dtype):
         return {}, {}

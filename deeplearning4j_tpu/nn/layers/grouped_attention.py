@@ -1,0 +1,283 @@
+"""Grouped-query softmax attention with an optional sliding window, rotary
+position, per-head norms on query and key and an output gate
+(`GroupedAttentionLayer`, nn/conf/layers.py): the attention of models
+that mix window and full layers in one stack.
+
+For a token u at position t (no projection has a bias; Hq query heads
+and Hk key-value heads of d; query head h reads key-value head
+h // (Hq / Hk)):
+
+    q = Nq(u Wq)   [Hq, d]        Nq, Nk: RMS norm over the d of a head,
+    k = Nk(u Wk)   [Hk, d]        one learned gain vector each
+    v = u Wv       [Hk, d]
+    g = sigmoid(u Wg)   [Hq * d]
+    q, k = rot(q, t), rot(k, t)   where `rope_theta` > 0; else NO position
+    o_t = softmax_j(q_t . k_j / sqrt(d)) v_j   over the keys
+          t - window < j <= t  (`window` > 0: `window` keys at most, the
+          query's own among them), or every j <= t (`window` 0);
+          scores and softmax in float32
+    out = (concat_h(o) * g) Wo    the gate multiplies BEFORE the output
+                                  projection
+
+`rot` is `latent_attention.rotary` (the pairs (i, i + d / 2)). `apply` (a
+whole sequence) is plain `jnp` with the window as a mask: this layer is
+served, it has no flash path and no custom backward.
+
+THE CACHE ENTRY is the layer's own and lies head-major, a key-value
+head's rows together ([B, Hk, rows, d]: what `ops/decode_attention.
+gqa_decode` reads):
+
+* `window` 0: {"k", "v"}, `capacity` rows a slot, position p at row p;
+* `window` > 0: {"k_win", "v_win"}, min(capacity, window) rows a slot
+  WHATEVER THE CAPACITY, A RING: position p lies at row p % rows, and the
+  position a row holds is arithmetic on the step's own position, never
+  stored. The spec says how many positions the arrays hold
+  (serving/kvcache.py bills a ring to its own rows).
+
+What rows forgive and a ring does not, all read from `nn/decode.
+CacheStep`:
+
+* a row the step says is not `live` WRITES NOTHING (the engine feeds an
+  idle slot the scratch position capacity - 1; in a ring that lands on a
+  row some tenant may need);
+* a prefill chunk attends the ring AS IT FOUND IT, masked per query by
+  the position each row holds (start - window < p < start for the ring's
+  half, plain causal with the window inside the chunk), and writes
+  AFTER: its first query sees back to start - window + 1, rows its own
+  last tokens overwrite. Of a chunk longer than the ring only the last
+  `rows` tokens are written;
+* a token with `keep` 0 (the pad of a bucket) writes nothing and is seen
+  by nothing;
+* a slot's new tenant needs no reset: a ring row whose position by that
+  arithmetic is negative or beyond the query is masked;
+* a step cannot be unwound from a ring (a rejected draft's rows have
+  overwritten the oldest rows, which the accepted position still sees):
+  `rewindable(conf)` is False where the entry is a ring, so
+  `nn/decode.make_verify_fn` refuses the net with the layer named;
+* `kv_dtype="int8"` is refused (a ring of requantised pages is its own
+  work).
+
+The layer counts, through the `counters` road of nn/decode.py:
+`attn_rows_seen`, the cache rows some query of the step could see, and
+`attn_wrapped`, the live rows of the step whose context is past the
+window.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn.conf.layers import GroupedAttentionLayer
+from deeplearning4j_tpu.nn.layers.attention import rms_norm
+from deeplearning4j_tpu.nn.layers.base import (
+    LayerImpl,
+    apply_dropout,
+    register_impl,
+)
+from deeplearning4j_tpu.nn.layers.latent_attention import rotary
+from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.ops.activations import get_activation
+from deeplearning4j_tpu.ops.decode_attention import (
+    gqa_decode,
+    group_queries,
+    ring_attention,
+    ungroup_queries,
+)
+from deeplearning4j_tpu.ops.flash_attention import lse_combine
+
+_NEG_INF = -1e30
+
+
+def _sizes(conf):
+    Hq = conf.n_heads
+    Hk = conf.n_kv_heads or Hq
+    return Hq, Hk, conf.head_dim or conf.n_out // Hq
+
+
+def entry_names(conf) -> tuple:
+    return ("k_win", "v_win") if conf.window else ("k", "v")
+
+
+def _project(conf, params, x, positions):
+    """x [b, T, n_in] at `positions` [b, T] -> q [b, T, Hq, d], k, v
+    [b, T, Hk, d] in x's dtype."""
+    b, T, _ = x.shape
+    Hq, Hk, d = _sizes(conf)
+    q = (x @ params["Wq"]).reshape(b, T, Hq, d)
+    k = (x @ params["Wk"]).reshape(b, T, Hk, d)
+    v = (x @ params["Wv"]).reshape(b, T, Hk, d)
+    q = rms_norm(q, params["q_norm"], conf.eps)
+    k = rms_norm(k, params["k_norm"], conf.eps)
+    if conf.rope_theta:
+        q = rotary(q, positions, conf.rope_theta)
+        k = rotary(k, positions, conf.rope_theta)
+    return q, k, v
+
+
+def _output(conf, params, x, o):
+    """o [b, T, Hq * d], the heads' outputs side by side -> [b, T, n_out]."""
+    gate = jax.nn.sigmoid((x @ params["Wg"]).astype(jnp.float32))
+    o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+    return get_activation(conf.activation or "identity")(o @ params["Wo"])
+
+
+def masked_attention_lse(conf, q, k, v, qpos, kpos, kmask=None):
+    """q [b, T, Hq, d] at qpos [b, T] against the call's own k, v
+    [b, S, Hk, d] at kpos [b, S]: causal, inside the window, `kmask`
+    [b, S] 0 hiding a key. Plain products, float32 softmax. ->
+    (o [b, Hk, G * T, d] float32, lse [b, Hk, G * T]) in the grouped
+    order of `ops/decode_attention.group_queries`."""
+    Hq, Hk, d = _sizes(conf)
+    G = Hq // Hk
+    qg = group_queries(q.transpose(0, 2, 1, 3), Hk)         # [b, Hk, G*T, d]
+    s = jnp.einsum("bhqd,bkhd->bhqk", qg, k,
+                   preferred_element_type=jnp.float32) / jnp.sqrt(
+                       jnp.float32(d))
+    seen = kpos[:, None, :] <= qpos[:, :, None]             # [b, T, S]
+    if conf.window:
+        seen = seen & (kpos[:, None, :] > qpos[:, :, None] - conf.window)
+    if kmask is not None:
+        seen = seen & (kmask[:, None, :] > 0)
+    seen = jnp.tile(seen, (1, G, 1))[:, None]               # [b, 1, G*T, S]
+    s = jnp.where(seen, s, _NEG_INF)
+    m = s.max(-1)
+    p = jnp.where(seen, jnp.exp(s - m[..., None]), 0.0)
+    l = p.sum(-1)
+    o = jnp.einsum("bhqk,bkhd->bhqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    o = jnp.where(l[..., None] > 0.0, o / jnp.maximum(l, 1e-30)[..., None],
+                  0.0)
+    return o, m + jnp.log(jnp.maximum(l, 1e-30))
+
+
+def _heads_out(o, conf, dtype):
+    """[b, Hk, G * T, d] -> [b, T, Hq * d]."""
+    Hq, _, d = _sizes(conf)
+    o = ungroup_queries(o, Hq)                              # [b, Hq, T, d]
+    b, _, T, _ = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(b, T, Hq * d).astype(dtype)
+
+
+@register_impl(GroupedAttentionLayer)
+class GroupedAttentionImpl(LayerImpl):
+    counters = ("attn_rows_seen", "attn_wrapped")
+
+    @staticmethod
+    def rewindable(conf) -> bool:
+        """A ring cannot be unwound (module docstring)."""
+        return not conf.window
+
+    @staticmethod
+    def merge_counts(counts: list) -> dict:
+        """Rows seen add up over the layers; the rows past the window
+        are the same rows in every window layer (a full layer says 0)."""
+        return {"attn_rows_seen": sum(c["attn_rows_seen"] for c in counts),
+                "attn_wrapped": jnp.max(jnp.stack(
+                    [c["attn_wrapped"] for c in counts]))}
+
+    def init(self, conf, rng, dtype):
+        Hq, Hk, d = _sizes(conf)
+        if Hq % Hk or d % 2:
+            raise ValueError(
+                f"GroupedAttentionLayer needs n_heads a multiple of "
+                f"n_kv_heads and an even head_dim; got {Hq}, {Hk}, {d}")
+        k = jax.random.split(rng, 5)
+
+        def w(key, shape):
+            return init_weights(key, shape, conf.weight_init, conf.dist, dtype)
+
+        return {"Wq": w(k[0], (conf.n_in, Hq * d)),
+                "Wk": w(k[1], (conf.n_in, Hk * d)),
+                "Wv": w(k[2], (conf.n_in, Hk * d)),
+                "Wg": w(k[4], (conf.n_in, Hq * d)),
+                "Wo": w(k[3], (Hq * d, conf.n_out)),
+                "q_norm": jnp.ones((d,), dtype),
+                "k_norm": jnp.ones((d,), dtype)}, {}
+
+    def apply(self, conf, params, state, x, *, train=False, rng=None,
+              mask=None):
+        if conf.dropout:
+            x = apply_dropout(x, conf.dropout, rng, train=train)
+        b, T, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(T)[None, :], (b, T))
+        q, k, v = _project(conf, params, x, positions)
+        o, _ = masked_attention_lse(conf, q, k, v, positions, positions, mask)
+        return _output(conf, params, x, _heads_out(o, conf, x.dtype)), state
+
+    def cache_arrays(self, conf, capacity, kv_dtype, page_size, dtype):
+        """The arrays one decode slot of this layer holds, head-major:
+        {name: (shape, dtype)} of `capacity` rows for a full layer;
+        {name: (shape, dtype, rows)} for a ring, the third entry the
+        positions it holds, min(capacity, window)."""
+        if kv_dtype == "int8":
+            raise ValueError(
+                "no int8 form of this layer's cache entry: a ring of "
+                "requantised pages is its own work")
+        _, Hk, d = _sizes(conf)
+        kn, vn = entry_names(conf)
+        if not conf.window:
+            return {kn: ((Hk, capacity, d), dtype),
+                    vn: ((Hk, capacity, d), dtype)}
+        rows = min(capacity, conf.window)
+        return {kn: ((Hk, rows, d), dtype, rows),
+                vn: ((Hk, rows, d), dtype, rows)}
+
+    def apply_cached(self, conf, params, x, entry, step):
+        """One serving step through this layer's cache entry (module
+        docstring: what it takes from `step`). -> (y, entry, counts)."""
+        b, T, _ = x.shape
+        Hq, Hk, d = _sizes(conf)
+        G, W = Hq // Hk, conf.window
+        kn, vn = entry_names(conf)
+        R = entry[kn].shape[2]
+        pos = step.positions
+        q, k, v = _project(conf, params, x, pos)
+        rows = jnp.arange(b) if step.rows is None else step.rows
+        live = (jnp.ones((b,), bool) if step.live is None
+                else jnp.asarray(step.live, bool))
+        keep = (jnp.ones((b, T), bool) if step.keep is None
+                else step.keep > 0) & live[:, None]
+        # of a call longer than the ring, the last R kept tokens stay
+        last = jnp.max(jnp.where(keep, pos, -1), axis=1, keepdims=True)
+        at = jnp.where(keep & (pos > last - R), pos % R, R)  # R: dropped
+        n_kept = jnp.sum(keep, axis=1)
+
+        def written():
+            idx = (rows[:, None, None], jnp.arange(Hk)[None, :, None],
+                   at[:, None, :])
+            return {kn: entry[kn].at[idx].set(
+                        k.transpose(0, 2, 1, 3).astype(entry[kn].dtype),
+                        mode="drop"),
+                    vn: entry[vn].at[idx].set(
+                        v.transpose(0, 2, 1, 3).astype(entry[vn].dtype),
+                        mode="drop")}
+
+        if step.chunk:
+            start = pos[:, 0]
+            qg = group_queries(q.transpose(0, 2, 1, 3), Hk)
+            o, lse = masked_attention_lse(conf, q, k, v, pos, pos, keep)
+            before = jnp.tile(jnp.broadcast_to(start[:, None], (b, T)),
+                              (1, G))
+            o, _ = lse_combine(o, lse, *ring_attention(
+                qg, entry[kn], entry[vn], before, start, rows,
+                jnp.tile(pos - W + 1, (1, G)) if W else None))
+            entry = written()
+            prior = jnp.minimum(start, W - 1) if W else start
+            seen = jnp.sum(jnp.where(n_kept > 0, prior + n_kept, 0))
+            ends = start + n_kept
+        elif T == 1:
+            entry = written()
+            o = gqa_decode(q[:, 0], entry[kn], entry[vn], pos[:, 0], live)
+            o = o.reshape(b, Hk, G, d)                      # grouped, T = 1
+            ends = jnp.where(live, pos[:, 0] + 1, 0)
+            seen = jnp.sum(jnp.minimum(ends, R))
+        else:
+            raise ValueError(
+                "a GroupedAttentionLayer decodes one token a row a step or "
+                "prefills a chunk; a window of drafts is not served")
+        counts = {"attn_rows_seen": seen.astype(jnp.int32),
+                  "attn_wrapped": jnp.sum(ends > W, dtype=jnp.int32)
+                  if W else jnp.int32(0)}
+        return (_output(conf, params, x, _heads_out(o, conf, x.dtype)),
+                entry, counts)

@@ -321,6 +321,9 @@ class DroplessMoELayer(FeedForwardLayer):
     which experts it holds.
 
         s = sigmoid(x_f32 Wg_f32)               all `n_experts` scores
+        the top_k are the largest of s, or, with `selection_bias`, of
+        s + bsel (`bsel` [n_experts] float32: a bias that CHOOSES and
+        never weighs; training moves it towards a balanced load)
         w = s_top / (sum of the top_k + 1e-20) * routed_scaling
         y = sum over the selected experts HELD HERE of w_e E_e(x)
             + Shared(x)
@@ -342,6 +345,7 @@ class DroplessMoELayer(FeedForwardLayer):
     n_held: int = 0
     n_shared: int = 0
     routed_scaling: float = 1.0
+    selection_bias: bool = False    # hold `bsel` and select by s + bsel
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:
@@ -371,14 +375,20 @@ def round_rows(n_tokens: int, top_k: int, n_experts: int) -> int:
     return min(max(8, -(-c // 8) * 8), max(8, -(-n_tokens // 8) * 8))
 
 
-def route_sigmoid_topk(x2d, Wg, top_k, routed_scaling):
+def route_sigmoid_topk(x2d, Wg, top_k, routed_scaling, bsel=None):
     """(expert ids [N, k], weights [N, k] float32). The router's product
     and the sigmoid in float32 at full precision, as the published
     models compute them: a bfloat16 rounding of a score picks another
-    expert than the reference where two scores lie close."""
+    expert than the reference where two scores lie close. With `bsel`
+    [E] the top k are those of s + bsel and are weighed by s alone."""
     logits = jnp.matmul(x2d.astype(jnp.float32), Wg.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    if bsel is None:
+        top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    else:
+        s = jax.nn.sigmoid(logits)
+        _, top_i = jax.lax.top_k(s + bsel.astype(jnp.float32), top_k)
+        top_s = jnp.take_along_axis(s, top_i, axis=-1)
     w = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
     return top_i, w * routed_scaling
 
@@ -412,7 +422,7 @@ def dropless_moe(conf, params, x2d, valid=None):
     held = conf.n_held or conf.n_experts
     act = conf.activation or "silu"
     top_i, w = route_sigmoid_topk(x2d, params["Wg"], conf.top_k,
-                                  conf.routed_scaling)
+                                  conf.routed_scaling, params.get("bsel"))
     local = top_i - conf.first_expert                        # [N, k]
     mine = (local >= 0) & (local < held)
     if valid is not None:
@@ -488,6 +498,8 @@ class DroplessMoEImpl(LayerImpl):
                   "We_gate": w(k[1], (held, D, F), D, F),
                   "We_up": w(k[2], (held, D, F), D, F),
                   "We_down": w(k[3], (held, F, O), F, O)}
+        if conf.selection_bias:
+            params["bsel"] = jnp.zeros((conf.n_experts,), jnp.float32)
         if conf.n_shared:
             Fs = conf.n_shared * F
             params.update(Ws_gate=w(k[4], (D, Fs), D, Fs),

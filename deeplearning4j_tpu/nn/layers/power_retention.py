@@ -44,7 +44,7 @@ this layer takes from `nn/decode.CacheStep`:
 * a token with `keep` 0 (the pad of a prefill bucket) adds nothing and
   decays nothing: it cannot be taken out again afterwards;
 * a row the step says is not `live` leaves its state bit for bit;
-* a step cannot be unwound (`rewindable` False): a rejected draft's
+* a step cannot be unwound (`rewindable(conf)` is False): a rejected draft's
   keys are overwritten, its share of a sum is not, so
   `nn/decode.make_verify_fn` refuses a net with this layer.
 """
@@ -111,8 +111,11 @@ def _output(conf, params, y):
 
 @register_impl(PowerRetentionLayer)
 class PowerRetentionImpl(LayerImpl):
-    rewindable = False              # a step's share of the state stays
     counters = ("state_resets",)
+
+    @staticmethod
+    def rewindable(conf) -> bool:
+        return False                # a step's share of the state stays
 
     @staticmethod
     def merge_counts(counts: list) -> dict:

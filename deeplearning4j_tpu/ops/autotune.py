@@ -116,6 +116,16 @@ DEFAULT_NEG_SOFTMAX_ROW_BLOCK = 128
 # 80-380: PERF.md section 6, PR 32); all four lie within 3 %.
 DEFAULT_DECODE_BLOCK_K = 128
 
+# Grouped decode attention (`ops/decode_attention.gqa_decode`, PR 37):
+# the rows of every key-value head one program instance of the kernel
+# holds. A block of all 8 heads at 512 rows of 128 is 2 MB of keys and
+# values (2.6 us at a v5e's 819 GB/s against some 0.35 us a grid step;
+# 4 MB of VMEM double-buffered); each slot stops at its own last live
+# block, so a shorter block reads fewer rows past a slot's length and
+# costs more steps. The cap feeds a divisor search over the entry's rows
+# (4,096 in a ring, the capacity in a full layer). Not swept yet.
+DEFAULT_GQA_BLOCK_K = 512
+
 # Kernel-proven chunk-tile lengths for the long-context loop, largest
 # first (the single home for the tiling envelope quoted in error
 # messages). 8192 is the monolithic kernels' VMEM envelope at

@@ -51,6 +51,32 @@ inflate the fresh page's maxabs), recompute page scales, requantize,
 scatter codes + scales back. Re-rounding a page whose scale did not
 change is EXACT (round(code*s/s) == code), so settled pages do not
 drift as their neighbors fill in.
+
+GROUPED HEADS, A WINDOW, A RING (nn/layers/grouped_attention.py): the
+last section of this file, its own walk and kernel, which the row-major
+walk above knows nothing of. An entry there lies HEAD-MAJOR,
+[B, H, S, D], a key-value head's rows together, and is read as a RING of
+its S rows: position p lies at row p % S and the sequence has written
+the positions below `head`, so row r holds p_r = (head - 1) - ((head - 1
+- r) mod S): arithmetic on the step's own position, never stored; a row
+whose p_r is negative was never written by this sequence and is seen by
+no query (an entry of `capacity` rows that never wraps is the same
+arithmetic). Grouped heads need no walk of their own: the G queries
+that read one key-value head are G more query rows of that head
+(`group_queries`).
+
+`ring_attention` is that walk in `jnp`, with a lower limit beside the
+upper one (a window): a prefill chunk's cross-chunk half, and the CPU's
+decode step. `gqa_decode` is the decode step, one new token a cache row:
+ONE PALLAS KERNEL on a TPU, named `gqa_decode` in the device trace. A
+program instance is a slot and a block of `block_k` rows of all its
+key-value heads; each head's G queries meet that head's rows where they
+lie, flash running max and sum in float32. Each slot stops at ITS OWN
+last live block: the block counts are prefetched scalars, the index map
+of a block past a slot's last repeats the last (no copy is issued for
+it) and the body is skipped; a slot that is not live visits none. Its
+`jnp` twin (`ring_attention` at one query a row) serves the CPU and the
+tests.
 """
 
 from __future__ import annotations
@@ -59,8 +85,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops import autotune
+from deeplearning4j_tpu.util.compat import tpu_compiler_params
 
 _NEG_INF = -1e30
 
@@ -277,3 +306,218 @@ def cache_attention_q8(q, k_codes, v_codes, k_scale, v_scale, key_limit,
     return _cache_attention_blocked_q8(q, k_codes, v_codes, k_scale,
                                        v_scale, key_limit, bk, page_size,
                                        rows)
+
+
+# ------------------------------------------------- grouped decode kernel
+
+GQA_BLOCK_K = autotune.DEFAULT_GQA_BLOCK_K
+
+
+def group_queries(q, n_kv_heads: int):
+    """q [b, Hq, Tq, D] -> [b, Hk, G * Tq, D]: the G = Hq / Hk queries
+    that read key-value head c (query heads c * G .. c * G + G - 1) as G
+    more query rows of that head, row g * Tq + t. Per-query limits go
+    with them as jnp.tile(limit, (1, G)); `ungroup_queries` is the way
+    back."""
+    b, Hq, Tq, D = q.shape
+    return q.reshape(b, n_kv_heads, (Hq // n_kv_heads) * Tq, D)
+
+
+def ungroup_queries(o, n_heads: int):
+    """[b, Hk, G * Tq, ...] -> [b, Hq, Tq, ...]."""
+    b, Hk, GT = o.shape[:3]
+    return o.reshape((b, n_heads, GT // (n_heads // Hk)) + o.shape[3:])
+
+
+def _use_kernel() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def gqa_block(R: int, block_k: int = GQA_BLOCK_K) -> int:
+    """The kernel's block of an entry of R rows: the largest divisor of
+    R that is at most `block_k` and a multiple of 16 (a bfloat16 tile's
+    sublanes), else R whole."""
+    for bk in range(min(block_k, R) // 16 * 16, 0, -16):
+        if R % bk == 0:
+            return bk
+    return R
+
+
+@functools.partial(jax.jit, static_argnames=("block_k",))
+def _ring_attention_blocked(q, k, v, key_limit, head, block_k, rows,
+                            key_floor):
+    b, H, Tq, D = q.shape
+    S = k.shape[2]
+    sm_scale = 1.0 / jnp.sqrt(jnp.float32(D))
+    newest = head[:, None] - 1
+    n_live = (jnp.minimum(jnp.max(head), S) + block_k - 1) // block_k
+
+    def load(x, j):
+        blk = jax.lax.dynamic_slice_in_dim(x, j * block_k, block_k, axis=2)
+        return blk if rows is None else jnp.take(blk, rows, axis=0)
+
+    def body(j, carry):
+        m, l, acc = carry
+        # blocks stay in the entry's own type: bfloat16 operands and a
+        # float32 sum give the products of the same numbers at a third
+        # of the passes of a float32 product
+        k_j, v_j = load(k, j), load(v, j)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(k_j.dtype), k_j,
+                       preferred_element_type=jnp.float32) * sm_scale
+        row = j * block_k + jnp.arange(block_k)
+        p_r = (newest - jnp.mod(newest - row[None, :], S))[:, None, None, :]
+        visible = (p_r >= 0) & (p_r < key_limit[:, None, :, None])
+        if key_floor is not None:
+            visible = visible & (p_r >= key_floor[:, None, :, None])
+        s = jnp.where(visible, s, _NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(visible, jnp.exp(s - m_new[..., None]), 0.0)
+        l_new = l * alpha + p.sum(-1)
+        acc_new = acc * alpha[..., None] + jnp.einsum(
+            "bhqk,bhkd->bhqd", p.astype(v_j.dtype), v_j,
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_live, body, (jnp.full((b, H, Tq), _NEG_INF, jnp.float32),
+                          jnp.zeros((b, H, Tq), jnp.float32),
+                          jnp.zeros((b, H, Tq, D), jnp.float32)))
+    out = jnp.where(l[..., None] > 0.0,
+                    acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
+    return out.astype(q.dtype), m + jnp.log(jnp.maximum(l, 1e-30))
+
+
+def ring_attention(q, k, v, key_limit, head, rows=None, key_floor=None):
+    """The walk over a head-major entry read as a ring (module
+    docstring), in blocks of `gqa_block(S)`, flash running max and sum
+    in float32. q [b, H, Tq, D]; k, v [B, H, S, D]; `head` [b]: the
+    sequence has written the positions below it; query (r, t) sees the
+    positions key_floor[r, t] <= p < key_limit[r, t] ([b, Tq] each; no
+    floor: from 0); rows [b] the cache rows the queries attend (None:
+    b == B, row for row). The loop's bound is traced: the blocks the
+    fullest ring has filled. A query that sees no key gets a zero row and
+    an lse at the mask floor. -> (out [b, H, Tq, D] in q.dtype,
+    lse [b, H, Tq] float32)."""
+    return _ring_attention_blocked(q, k, v, key_limit, head,
+                                   gqa_block(k.shape[2]), rows, key_floor)
+
+
+def gqa_decode_jnp(q, k, v, pos, live=None):
+    """`gqa_decode` in plain `jnp`: the ring walk at one query a row."""
+    B, Hq, D = q.shape
+    Hk = k.shape[1]
+    limit = pos + 1
+    if live is not None:
+        limit = jnp.where(jnp.asarray(live, bool), limit, 0)
+    o, _ = ring_attention(
+        group_queries(q[:, :, None, :], Hk), k, v,
+        jnp.tile(limit[:, None], (1, Hq // Hk)), limit)
+    return ungroup_queries(o, Hq)[:, :, 0, :]
+
+
+def _gqa_kernel(nblk_ref, at_ref, wrapped_ref, q_ref, k_ref, v_ref, o_ref,
+                m_ref, l_ref, acc_ref, *, block_k, scale):
+    """One (slot, block of rows): q_ref [1, Hk, G8, D] holds each
+    key-value head's queries (G padded to a sublane tile), k_ref and
+    v_ref [1, Hk, block_k, D] that block of every head's rows."""
+    f32 = jnp.float32
+    b, j = pl.program_id(0), pl.program_id(1)
+    Hk = q_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, f32)
+        l_ref[...] = jnp.zeros(l_ref.shape, f32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    @pl.when(j < nblk_ref[b])
+    def _():
+        # ring row r holds the newest position p <= pos with p % R == r:
+        # it has been written iff r <= pos % R or the ring has wrapped
+        row = j * block_k + jax.lax.broadcasted_iota(jnp.int32,
+                                                     (1, block_k), 1)
+        seen = (row <= at_ref[b]) | (wrapped_ref[b] > 0)
+        for h in range(Hk):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_ref[0, h], (((1,), (1,)), ((), ())),
+                preferred_element_type=f32) * scale          # [G8, block_k]
+            s = jnp.where(seen, s, _NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = alpha * acc_ref[h] + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, h],
+                (((1,), (0,)), ((), ())), preferred_element_type=f32)
+            m_ref[h] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        l = l_ref[...]
+        o_ref[0] = jnp.where(l > 0.0, acc_ref[...] / jnp.maximum(l, 1e-30),
+                             0.0).astype(o_ref.dtype)
+
+
+def gqa_decode_kernel(q, k, v, pos, live=None, *, interpret=False,
+                      block_k=GQA_BLOCK_K):
+    """`gqa_decode_jnp` as one Pallas kernel (module docstring)."""
+    B, Hq, D = q.shape
+    Hk, R = k.shape[1], k.shape[2]
+    G = Hq // Hk
+    G8 = -(-G // 8) * 8
+    bk = gqa_block(R, block_k)
+    n_blocks = R // bk
+    pos = pos.astype(jnp.int32)
+    rows = jnp.minimum(pos + 1, R)                # ring rows written
+    if live is not None:
+        rows = jnp.where(jnp.asarray(live, bool), rows, 0)
+    nblk = (rows + bk - 1) // bk
+    qg = q.reshape(B, Hk, G, D)
+    if G8 != G:
+        qg = jnp.concatenate(
+            [qg, jnp.zeros((B, Hk, G8 - G, D), q.dtype)], axis=2)
+
+    def whole(b, j, nblk, at, wrapped):
+        return b, 0, 0, 0
+
+    def block(b, j, nblk, at, wrapped):
+        # past a slot's last live block the index repeats: no new copy
+        return b, 0, jnp.maximum(jnp.minimum(j, nblk[b] - 1), 0), 0
+
+    itemsize = jnp.dtype(k.dtype).itemsize
+    out = pl.pallas_call(
+        functools.partial(_gqa_kernel, block_k=bk, scale=1.0 / D ** 0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, n_blocks),
+            in_specs=[pl.BlockSpec((1, Hk, G8, D), whole),
+                      pl.BlockSpec((1, Hk, bk, D), block),
+                      pl.BlockSpec((1, Hk, bk, D), block)],
+            out_specs=pl.BlockSpec((1, Hk, G8, D), whole),
+            scratch_shapes=[pltpu.VMEM((Hk, G8, 1), jnp.float32),
+                            pltpu.VMEM((Hk, G8, 1), jnp.float32),
+                            pltpu.VMEM((Hk, G8, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, Hk, G8, D), q.dtype),
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * B * Hq * R * D, transcendentals=B * Hq * R,
+            bytes_accessed=2 * B * Hk * R * D * itemsize),
+        name="gqa_decode",
+        interpret=interpret,
+    )(nblk, pos % R, (pos >= R).astype(jnp.int32), qg, k, v)
+    return out[:, :, :G].reshape(B, Hq, D)
+
+
+def gqa_decode(q, k, v, pos, live=None):
+    """One new token a cache row: q [B, Hq, D] at position pos [B]
+    against the entry k, v [B, Hk, R, D] read as a ring of R rows, the
+    token's own row already written at pos % R; query head h reads
+    key-value head h // (Hq / Hk). A row not `live` [B] sees nothing and
+    gets zeros. The kernel on a TPU, its `jnp` twin elsewhere. ->
+    [B, Hq, D] in q.dtype."""
+    if _use_kernel():
+        return gqa_decode_kernel(q, k, v, pos, live)
+    return gqa_decode_jnp(q, k, v, pos, live)

@@ -779,6 +779,22 @@ class _GenWorker:
     when the worker is built (nn/decode.make_verify_fn raises: a
     rejected draft cannot be taken out of a sum).
 
+    A NET WHOSE CACHE ENTRY IS A RING (grouped attention with a window:
+    nn/layers/grouped_attention.py keeps the newest `window` rows a
+    slot, position p at row p % window) is served by the same loop and
+    the worker names no layer either: the layer writes nothing for the
+    rows `_live` calls idle (their scratch position capacity - 1 would
+    land on a row some tenant needs), lets a chunk attend the ring as it
+    found it and write after, and needs no reset for a slot's new
+    tenant (a row's position is arithmetic on the step's own, and one
+    that comes out negative is masked); its steps hand home
+    `attn_rows_seen` and `attn_wrapped`. speculative_k >= 2 and
+    kv_dtype="int8" are refused for such a net when the worker is
+    built, with the layer named. `plan.describe(net)` (the `meta` event,
+    /stats) says which kinds of row are rings (`windows`) and what a
+    slot is allocated (`bytes_per_slot`); the page pool still counts one
+    kind of page, `prompt + max_new` of them a request.
+
     THE CACHE IS DONATED to every step (`donate_argnums` on the cache
     argument of the three jits): the scatter of nn/decode._cache_write
     (what a layer's `apply_cached` reaches through `CacheStep.write`)
@@ -978,7 +994,9 @@ class _GenWorker:
         """The step's tokens, in `shape`, out of the fetched array; the
         counters behind them (a net with counting layers) become fields
         of the step's span: `moe_pairs`, `moe_rows`, `moe_max_load` of
-        an expert layer, `state_resets` of a layer that keeps a state."""
+        an expert layer, `state_resets` of a layer that keeps a state,
+        `attn_rows_seen` and `attn_wrapped` of a grouped-attention
+        layer."""
         n = len(self.step_counters)
         if not n:
             return fetched

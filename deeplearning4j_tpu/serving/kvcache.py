@@ -32,7 +32,9 @@ allocation.
   row a position for a latent-attention layer, a STATE of fixed size
   for a retention layer (an array whose spec carries a third entry,
   "slot": it does not grow with the capacity and is billed to the slot,
-  never to its positions). `bytes_per_slot` is the single home for that
+  never to its positions), a RING of rows for an attention layer with a
+  window (an array whose spec carries the positions it holds, fewer
+  than the capacity: it is billed to those). `bytes_per_slot` is the single home for that
   arithmetic: it bills exactly the arrays the device holds, and the
   replay artifact's ``slots_per_hbm_byte`` uplift row (gate: >= 1.8x)
   is computed from it, not re-derived ad hoc.
@@ -78,33 +80,46 @@ def bytes_per_slot(cache_specs: dict) -> int:
                for shape, dtype, *_per in arrays.values())
 
 
-def _kinds(cache_specs: dict, per_slot: bool) -> dict:
-    """{array name: bytes a slot holds over all layers that keep such an
-    array}, of the arrays marked "slot" (`per_slot`) or of the others."""
+def _kinds(cache_specs: dict, per_slot: bool, capacity: int = 1) -> dict:
+    """{array name: bytes over all layers that keep such an array}, of
+    the arrays marked "slot" (`per_slot`: bytes a slot) or of the others
+    (bytes a position the array holds: `capacity` of them, or the number
+    its spec gives)."""
     out: dict = {}
     for arrays in cache_specs.values():
         for name, (shape, dtype, *per) in arrays.items():
-            if bool(per) == per_slot:
-                out[name] = out.get(name, 0) + _nbytes(shape, dtype)
+            if (per == ["slot"]) == per_slot:
+                held = 1 if per_slot else (per[0] if per else capacity)
+                out[name] = out.get(name, 0) + _nbytes(shape, dtype) / held
     return out
 
 
 def row_kinds(cache_specs: dict, capacity: int) -> dict:
     """{array name: bytes one position holds over all layers that keep
     such an array}: the kinds of row in the cache ("k", "v"; "ckv", "kpe"
-    for a latent row) for the engine's `meta` event and /stats. An array
-    with fewer entries than positions (a page's scale) is billed to the
-    positions it covers; an array marked "slot" (a state) is no row
-    and is left to `slot_kinds`."""
-    return {k: round(v / capacity, 3)
-            for k, v in _kinds(cache_specs, False).items()}
+    for a latent row; "k_win", "v_win" for a window layer's ring) for
+    the engine's `meta` event and /stats. An array with fewer entries
+    than positions (a page's scale) is billed to the positions it
+    covers; a ring to the positions IT holds (`window_kinds`), whatever
+    the capacity; an array marked "slot" (a state) is no row and is left
+    to `slot_kinds`."""
+    return {k: round(v, 3)
+            for k, v in _kinds(cache_specs, False, capacity).items()}
+
+
+def window_kinds(cache_specs: dict) -> dict:
+    """{array name: rows a slot} of the row arrays whose spec says that
+    they hold fewer positions than the capacity: the rings."""
+    return {name: int(per[0]) for arrays in cache_specs.values()
+            for name, (_shape, _dtype, *per) in arrays.items()
+            if per and per != ["slot"]}
 
 
 def slot_kinds(cache_specs: dict) -> dict:
     """{array name: bytes one SLOT holds over all layers that keep such
     an array} for the arrays marked "slot": a state of fixed size ("s",
     "z" of a retention layer), whatever the capacity."""
-    return _kinds(cache_specs, True)
+    return {k: int(v) for k, v in _kinds(cache_specs, True).items()}
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:
@@ -225,10 +240,14 @@ class CachePlan:
     def describe(self, net=None) -> dict:
         """The geometry; with `net`, also what its layers keep in it:
         `rows` ({kind of row: bytes a token over all layers}) and
-        `bytes_per_token`, their sum; `states` ({kind of state: bytes a
-        slot over all layers}) and `state_bytes_per_slot`, their sum.
-        capacity * bytes_per_token + state_bytes_per_slot is
-        `bytes_per_slot`."""
+        `bytes_per_token`, their sum: what a token costs while every
+        layer still holds it; `windows` ({kind of row: rows a slot}) for
+        the kinds that are rings and hold fewer positions than the
+        capacity; `states` ({kind of state: bytes a slot over all
+        layers}) and `state_bytes_per_slot`, their sum; `bytes_per_slot`,
+        what `init_cache` allocates a slot of: every kind of row times
+        the positions it holds (its window, else the capacity) plus the
+        states."""
         out = {"n_slots": self.n_slots, "capacity": self.capacity,
                "page_size": self.page_size,
                "pages_per_slot": self.pages_per_slot,
@@ -240,6 +259,8 @@ class CachePlan:
             rows, states = row_kinds(specs, self.capacity), slot_kinds(specs)
             out["rows"] = rows
             out["bytes_per_token"] = round(sum(rows.values()), 3)
+            out["windows"] = window_kinds(specs)
             out["states"] = states
             out["state_bytes_per_slot"] = sum(states.values())
+            out["bytes_per_slot"] = bytes_per_slot(specs)
         return out

@@ -103,6 +103,16 @@ also puts `state_resets` on its steps' spans: the rows whose state the
 step zeroed because they start a sequence (a request's first
 `prefill_chunk` counts 1, so the window's sum is the requests admitted;
 a `decode_step` counts 0; read on the span that `fetched` the program).
+A net with grouped-attention layers (nn/layers/grouped_attention.py)
+puts `attn_rows_seen` (the cache rows some query of the step could see,
+over all its layers: a window layer's are the `window` newest at the
+most) and `attn_wrapped` (the live rows of the step whose context is
+past the window) on the same spans, before the `moe_*` three, and its
+`cache` gives `windows` ({kind of row: rows a slot} for the kinds that
+are rings: "k_win", "v_win") and `bytes_per_slot` (what a slot's cache
+is allocated: every kind of row times the positions it holds, plus the
+states) beside `rows`, whose sum `bytes_per_token` is what a token
+costs while every layer still holds it.
 
 The generation engine's `meta` event, /stats and each worker's
 `describe()` also say what the engine serves FROM (serving/engine.py
@@ -137,7 +147,8 @@ argument flattening and the enqueue) and `fetch` (of the program BEFORE
 it, `fetched` = that program's number: the one batch-boundary
 `np.asarray`, which waits for the device only as long as that earlier
 program still runs; the counters behind its tokens — `moe_pairs`,
-`moe_rows`, `moe_max_load`, `state_resets` — land on the span under
+`moe_rows`, `moe_max_load`, `state_resets`, `attn_rows_seen`,
+`attn_wrapped` — land on the span under
 which they came home, so a step's span carries the counters of program
 `fetched`, not of `program`; a step with `ahead` false has no `fetch`
 and no counters), and after the span `emit` of that earlier program

@@ -275,6 +275,32 @@ def test_retention_decode_kernel_writes_the_state_in_place(
     assert not re.search(rf"= f32\[{B},{Hk},{d},{D}\]\S* copy\(", text)
 
 
+@pytest.mark.parametrize("rows", [4096, 17408], ids=["ring", "full"])
+def test_gqa_decode_kernel_reads_the_cache_where_it_lies(
+        no_persistent_cache, one_chip, rows):
+    """ops/decode_attention.py's grouped decode kernel at Trinity's
+    widths (32 slots, 48 queries on 8 key-value heads of 128, bfloat16),
+    over a window layer's ring of 4,096 rows and a full layer's 17,408:
+    it compiles for the chip (a block of 512 rows of all 8 heads, 6
+    queries padded to a sublane tile, the per-slot block counts
+    prefetched as scalars), and takes the entry as it lies: no copy and
+    no temporary of an entry's size (the entry is 268 MB and 1.14 GB a
+    layer)."""
+    from deeplearning4j_tpu.ops import decode_attention as da
+
+    B, Hq, Hk, d = 32, 48, 8, 128
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(da.gqa_decode_kernel).lower(
+        _sds((B, Hq, d), bf16, one_chip), _sds((B, Hk, rows, d), bf16, one_chip),
+        _sds((B, Hk, rows, d), bf16, one_chip), _sds((B,), jnp.int32, one_chip),
+        _sds((B,), jnp.bool_, one_chip)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == {"gqa_decode": 1}
+    assert da.gqa_block(rows) == 512
+    assert mem.temp_size_in_bytes < 2**20, mem
+    assert not re.search(rf"= bf16\[{B},{Hk},{rows},{d}\]\S* copy\(", text)
+
+
 def _matrix_shapes(params):
     return {f"[{a.shape[0]},{a.shape[1]}]"
             for a in jax.tree.leaves(params) if a.ndim == 2}

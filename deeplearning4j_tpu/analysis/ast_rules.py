@@ -1224,21 +1224,24 @@ def g022_handrolled_placement(tree, imports, path):
 # blessed home of new kinds/names is the registry itself: telemetry/
 # is exempt (it IS the schema), and dynamic names (f-strings like the
 # bench sweep's `mode:<name>` spans) are uncheckable statically and
-# stay silent.
+# stay silent. The same holds for the regions of a compiled program: a
+# `named_scope("...")` literal or a layer impl's `region = "..."` that
+# REGION_NAMES lacks is a region no reader of the device trace knows.
 _G023_EXEMPT = ("deeplearning4j_tpu/telemetry/",)
 _G023_SETS: dict = {}
 
 
 def _g023_registered():
-    """(EVENT_KINDS, SPAN_NAMES) from the registry, cached; resolves
-    under the stage-1 no-jax stubs (telemetry/ is stdlib-pure). An
-    unresolvable registry disables the rule rather than crashing the
-    lint."""
+    """(EVENT_KINDS, SPAN_NAMES, REGION_NAMES) from the registry,
+    cached; resolves under the stage-1 no-jax stubs (telemetry/ is
+    stdlib-pure). An unresolvable registry disables the rule rather than
+    crashing the lint."""
     if "sets" not in _G023_SETS:
         try:
             from deeplearning4j_tpu.telemetry.recorder import (EVENT_KINDS,
+                                                               REGION_NAMES,
                                                                SPAN_NAMES)
-            _G023_SETS["sets"] = (EVENT_KINDS, SPAN_NAMES)
+            _G023_SETS["sets"] = (EVENT_KINDS, SPAN_NAMES, REGION_NAMES)
         except Exception:  # pragma: no cover - broken stub layouts
             _G023_SETS["sets"] = None
     return _G023_SETS["sets"]
@@ -1249,21 +1252,47 @@ def _g023_str_arg(node: ast.AST):
                           and isinstance(node.value, str)) else None
 
 
+def _g023_regions(tree, region_names) -> list:
+    """Region literals: the first argument of a `named_scope(...)` call
+    and a class-level `region = "..."`."""
+    found = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and node.args and (
+                getattr(node.func, "attr", None) == "named_scope"
+                or getattr(node.func, "id", None) == "named_scope"):
+            found.append((node, _g023_str_arg(node.args[0])))
+        elif isinstance(node, ast.ClassDef):
+            found.extend(
+                (st, _g023_str_arg(st.value)) for st in node.body
+                if isinstance(st, ast.Assign)
+                and any(getattr(t, "id", None) == "region"
+                        for t in st.targets))
+    return [("G023", node,
+             f"region {lit!r} is not in the registered schema "
+             "(telemetry/recorder.py REGION_NAMES): no reader of a "
+             "program's device time by region knows it",
+             "register the region in REGIONS first, or reuse an "
+             "existing one")
+            for node, lit in found
+            if lit is not None and lit not in region_names]
+
+
 def g023_unregistered_telemetry_names(tree, imports, path):
     """An `<obj>.event("<kind>")` whose kind literal is not a
     registered EVENT_KIND, or an `<obj>.span("<name>")` /
     `event("span", name="<name>")` whose name literal is not a
-    registered SPAN_NAME, outside telemetry/. Non-literal (variable /
-    f-string) names and non-string first arguments (`re.Match.span(0)`)
-    never flag."""
+    registered SPAN_NAME, or a `named_scope("<region>")` / a class's
+    `region = "<region>"` whose literal is not a registered REGION_NAME,
+    outside telemetry/. Non-literal (variable / f-string) names and
+    non-string first arguments (`re.Match.span(0)`) never flag."""
     norm = path.replace("\\", "/")
     if any(b in norm for b in _G023_EXEMPT):
         return []
     sets = _g023_registered()
     if sets is None:
         return []
-    event_kinds, span_names = sets
-    out = []
+    event_kinds, span_names, region_names = sets
+    out = _g023_regions(tree, region_names)
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call) \
                 or not isinstance(node.func, ast.Attribute) \

@@ -90,6 +90,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.nn.layers.base import input_region, region_scope
 from deeplearning4j_tpu.nn.training import tree_cast
 from deeplearning4j_tpu.ops.decode_attention import (
     cache_attention,
@@ -233,21 +234,6 @@ def init_cache(net, batch: int, capacity: int, kv_dtype: str = "f32",
                                             page_size).items()}
 
 
-def walk_block(net, capacity: int, kv_dtype: str = "f32",
-               page_size: int = 16) -> int | None:
-    """The key-block length in which the net's cached attention walks a
-    cache of `capacity` positions, by the first layer that says one
-    (`impl.cache_block`), so the host can know how many blocks a step
-    visits without asking the program. None where no layer walks the
-    cache in blocks (the latent layer reads its entry its own way)."""
-    _, _, ops = _plan(net)
-    for op in ops:
-        if op.kind == "layer" and hasattr(op.impl, "cache_block"):
-            return op.impl.cache_block(op.conf, capacity, kv_dtype,
-                                       page_size)
-    return None
-
-
 class CacheStep:
     """What a layer that carries `apply_cached` is told about the
     serving step it is called in: `rows` [b] the cache rows the call's
@@ -305,18 +291,20 @@ def _cache_write(entry, k_new, v_new, rows, positions, kv_dtype,
     positions (the engine's inactive-row scratch / a speculative tail
     past capacity) are dropped on both paths: the plain scatter by jax's
     out-of-bounds default, the int8 path inside
-    quantized_cache_update."""
-    if kv_dtype == "int8":
-        ck, ks = quantized_cache_update(entry["k"], entry["k_scale"],
-                                        k_new, rows, positions, page_size)
-        cv, vs = quantized_cache_update(entry["v"], entry["v_scale"],
-                                        v_new, rows, positions, page_size)
-        return {"k": ck, "k_scale": ks, "v": cv, "v_scale": vs}
-    ck = entry["k"].at[rows[:, None], positions].set(
-        k_new.astype(entry["k"].dtype))
-    cv = entry["v"].at[rows[:, None], positions].set(
-        v_new.astype(entry["v"].dtype))
-    return {"k": ck, "v": cv}
+    quantized_cache_update. Its ops lie in the region
+    `attention/cache_write`."""
+    with jax.named_scope("cache_write"):
+        if kv_dtype == "int8":
+            ck, ks = quantized_cache_update(entry["k"], entry["k_scale"],
+                                            k_new, rows, positions, page_size)
+            cv, vs = quantized_cache_update(entry["v"], entry["v_scale"],
+                                            v_new, rows, positions, page_size)
+            return {"k": ck, "k_scale": ks, "v": cv, "v_scale": vs}
+        ck = entry["k"].at[rows[:, None], positions].set(
+            k_new.astype(entry["k"].dtype))
+        cv = entry["v"].at[rows[:, None], positions].set(
+            v_new.astype(entry["v"].dtype))
+        return {"k": ck, "v": cv}
 
 
 # -------------------------------------------------------------- the walk
@@ -328,45 +316,54 @@ def _walk(net, plan, params, state, cache, x0, step, valid):
     `apply_cached` is called with its entry of the cache (None for a
     layer that keeps none) and `step`, and may return its counters
     behind (y, entry); a counting layer is told which tokens are real
-    (`valid`); the counters come back as (impl, counts) pairs. Mirrors
-    the containers' _forward dtype
+    (`valid`); the counters come back as (impl, counts) pairs. Each
+    layer runs under its impl's region (`region_scope`: a named scope of
+    the compiled program), a vertex under the region of its latest
+    input (`input_region`). Mirrors the containers' _forward dtype
     policy: float inputs and per-layer params cast to the compute dtype
     where the two differ."""
     in_name, out_name, ops = plan
     cache, counts = dict(cache), []
-    cdtype = net.compute_dtype
-    pdtype = net.param_dtype
     x0 = jnp.asarray(x0)
     if jnp.issubdtype(x0.dtype, jnp.floating):
-        x0 = x0.astype(cdtype)
-    acts = {in_name: x0}
-    for op in ops:
+        x0 = x0.astype(net.compute_dtype)
+    acts, regions = {in_name: x0}, {}
+    for at, op in enumerate(ops):
         inputs = [acts[i] for i in op.inputs]
-        if op.kind == "layer":
-            x = inputs[0]
-            if op.preproc is not None:
-                x = op.preproc.pre_process(x)
-            p = params.get(op.name, {})
-            if cdtype != pdtype:
-                p = tree_cast(p, cdtype)
-            if hasattr(op.impl, "apply_cached"):
-                y, entry, *counted = op.impl.apply_cached(
-                    op.conf, p, _as_seq(x), cache.get(op.name), step)
-                counts.extend((op.impl, c) for c in counted)
-                if entry is not None:
-                    cache[op.name] = entry
-                if x.ndim == 2:     # a one-token walk that arrived 2-D
-                    y = y[:, 0, :]  # stays so (see `_as_seq`)
-            elif hasattr(op.impl, "apply_counted"):
-                y, c = op.impl.apply_counted(op.conf, p, x, valid)
-                counts.append((op.impl, c))
-            else:
-                y, _ = op.impl.apply(op.conf, p, state.get(op.name, {}),
-                                     x, train=False, rng=None)
-            acts[op.name] = y
-        else:
-            acts[op.name] = _vertex(op.conf, inputs)
+        region = (op.impl.region if op.kind == "layer"
+                  else input_region(op.inputs, regions))
+        regions[op.name] = (at, region)
+        with region_scope(region):
+            acts[op.name] = (_layer(net, op, params, state, cache, counts,
+                                    inputs[0], step, valid)
+                             if op.kind == "layer"
+                             else _vertex(op.conf, inputs))
     return _as_seq(acts[out_name]), cache, counts
+
+
+def _layer(net, op, params, state, cache, counts, x, step, valid):
+    """One layer of the walk (`_walk`): its output; what it writes goes
+    into `cache`, what it counts onto `counts`."""
+    if op.preproc is not None:
+        x = op.preproc.pre_process(x)
+    p = params.get(op.name, {})
+    if net.compute_dtype != net.param_dtype:
+        p = tree_cast(p, net.compute_dtype)
+    if hasattr(op.impl, "apply_cached"):
+        y, entry, *counted = op.impl.apply_cached(
+            op.conf, p, _as_seq(x), cache.get(op.name), step)
+        counts.extend((op.impl, c) for c in counted)
+        if entry is not None:
+            cache[op.name] = entry
+        if x.ndim == 2:     # a one-token walk that arrived 2-D
+            y = y[:, 0, :]  # stays so (see `_as_seq`)
+        return y
+    if hasattr(op.impl, "apply_counted"):
+        y, c = op.impl.apply_counted(op.conf, p, x, valid)
+        counts.append((op.impl, c))
+        return y
+    return op.impl.apply(op.conf, p, state.get(op.name, {}), x,
+                         train=False, rng=None)[0]
 
 
 _cast_tree = jax.jit(tree_cast, static_argnums=1)

@@ -40,7 +40,9 @@ from deeplearning4j_tpu.nn.conf.layers import (
     validate_layer_names,
 )
 from deeplearning4j_tpu.nn.layers import get_impl, l1_l2_penalty
-from deeplearning4j_tpu.nn.layers.base import pop_aux_losses
+from deeplearning4j_tpu.nn.layers.base import (input_region,
+                                                pop_aux_losses,
+                                                region_scope)
 from deeplearning4j_tpu.nn.training import make_train_step, tree_cast
 from deeplearning4j_tpu.nn.updater import build_optimizer
 
@@ -226,47 +228,60 @@ class ComputationGraph:
         names = [n for n in self.topo if n not in self.conf.network_inputs]
         rngs = (jax.random.split(rng, max(len(names), 1)) if rng is not None
                 else [None] * len(names))
-        for name, k in zip(names, rngs):
+        regions = {}
+        for at, (name, k) in enumerate(zip(names, rngs)):
             vconf = self.conf.vertices[name]
             inputs = [acts[i] for i in self.conf.vertex_inputs[name]]
-            if isinstance(vconf, LayerVertexConf):
-                x = inputs[0]
-                if vconf.preprocessor is not None:
-                    x = vconf.preprocessor.pre_process(x)
-                p = params.get(name, {})
-                if cdtype != self.param_dtype:
-                    p = tree_cast(p, cdtype)
-                in_mask = masks.get(self.conf.vertex_inputs[name][0])
-                want_carry = (carries is not None
-                              and isinstance(vconf.layer, BaseRecurrentLayer)
-                              and hasattr(self.impls[name], "initial_carry"))
-                kw = ({"initial_carry": carries.get(name), "return_carry": True}
-                      if want_carry else {})
+            # each layer's ops (and, through transpose(jvp(...)), its
+            # backward) under its impl's region; a vertex under its
+            # latest input's
+            region = (self.impls[name].region
+                      if isinstance(vconf, LayerVertexConf)
+                      else input_region(self.conf.vertex_inputs[name],
+                                        regions))
+            regions[name] = (at, region)
+            with region_scope(region):
+                if isinstance(vconf, LayerVertexConf):
+                    x = inputs[0]
+                    if vconf.preprocessor is not None:
+                        x = vconf.preprocessor.pre_process(x)
+                    p = params.get(name, {})
+                    if cdtype != self.param_dtype:
+                        p = tree_cast(p, cdtype)
+                    in_mask = masks.get(self.conf.vertex_inputs[name][0])
+                    want_carry = (
+                        carries is not None
+                        and isinstance(vconf.layer, BaseRecurrentLayer)
+                        and hasattr(self.impls[name], "initial_carry"))
+                    kw = ({"initial_carry": carries.get(name),
+                           "return_carry": True} if want_carry else {})
 
-                def run(p_, s_, x_, _impl=self.impls[name], _lc=vconf.layer,
-                        _rng=k, _mask=in_mask, _kw=kw):
-                    return _impl.apply(_lc, p_, s_, x_, train=train,
-                                       rng=_rng, mask=_mask, **_kw)
+                    def run(p_, s_, x_, _impl=self.impls[name],
+                            _lc=vconf.layer, _rng=k, _mask=in_mask, _kw=kw):
+                        return _impl.apply(_lc, p_, s_, x_, train=train,
+                                           rng=_rng, mask=_mask, **_kw)
 
-                if self.conf.conf.remat:
-                    # jax.checkpoint per vertex: activations inside the
-                    # vertex are recomputed in the backward instead of
-                    # living in HBM for the whole step — the long-context
-                    # lever (seq-16k at batch 16 OOMs a 16GB chip without
-                    # it; the MultiLayerNetwork container has the same
-                    # per-layer policy at multilayer.py:169)
-                    run = jax.checkpoint(run)
-                out = run(p, state.get(name, {}), x)
-                if want_carry:
-                    y, s, carry = out
-                    new_carries[name] = carry
+                    if self.conf.conf.remat:
+                        # jax.checkpoint per vertex: activations inside
+                        # the vertex are recomputed in the backward instead
+                        # of living in HBM for the whole step — the
+                        # long-context lever (seq-16k at batch 16 OOMs a
+                        # 16GB chip without it; the MultiLayerNetwork
+                        # container has the same per-layer policy at
+                        # multilayer.py:169)
+                        run = jax.checkpoint(run)
+                    out = run(p, state.get(name, {}), x)
+                    if want_carry:
+                        y, s, carry = out
+                        new_carries[name] = carry
+                    else:
+                        y, s = out
+                    acts[name] = y
+                    new_state[name] = s
                 else:
-                    y, s = out
-                acts[name] = y
-                new_state[name] = s
-            else:
-                acts[name] = self._vertex_forward(
-                    name, vconf, inputs, params, state, train, k, masks, acts)
+                    acts[name] = self._vertex_forward(
+                        name, vconf, inputs, params, state, train, k, masks,
+                        acts)
             # propagate time masks along the DAG (reference
             # setLayerMaskArrays/feedForwardMaskArrays semantics): a
             # time-preserving vertex carries its first input's mask so
@@ -325,9 +340,10 @@ class ComputationGraph:
             p_out = params[out_name]
             if cdtype != self.param_dtype:
                 p_out = tree_cast(p_out, cdtype)
-            loss = loss + self.impls[out_name].loss(
-                vconf.layer, p_out, x, labels, train=train, rng=k_out,
-                mask=lmask)
+            with region_scope(self.impls[out_name].region):
+                loss = loss + self.impls[out_name].loss(
+                    vconf.layer, p_out, x, labels, train=train, rng=k_out,
+                    mask=lmask)
         for name, v in self.layer_vertices.items():
             loss = loss + l1_l2_penalty(v.layer, params[name])
         aux, new_state = pop_aux_losses(new_state)

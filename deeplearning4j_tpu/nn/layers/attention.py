@@ -57,13 +57,13 @@ from deeplearning4j_tpu.nn.conf.layers import (
 )
 from deeplearning4j_tpu.nn.layers.base import LayerImpl, apply_dropout, register_impl
 from deeplearning4j_tpu.nn.weights import init_weights
-from deeplearning4j_tpu.ops import autotune
 from deeplearning4j_tpu.ops.activations import get_activation
 
 
 @register_impl(LayerNormalization)
 class LayerNormImpl(LayerImpl):
     per_position = True
+    region = "norm"
 
     def init(self, conf, rng, dtype):
         n = conf.n_out or conf.n_in
@@ -99,6 +99,7 @@ def rms_norm(x, gamma, eps):
 @register_impl(RMSNormalization)
 class RMSNormImpl(LayerImpl):
     per_position = True
+    region = "norm"
 
     def init(self, conf, rng, dtype):
         return {"gamma": jnp.ones((conf.n_out or conf.n_in,), dtype)}, {}
@@ -137,6 +138,8 @@ def sinusoidal(positions, d, dtype):
 
 @register_impl(PositionalEncodingLayer)
 class PositionalEncodingImpl(LayerImpl):
+    region = "embed"
+
     def init(self, conf, rng, dtype):
         if conf.learned:
             pe = 0.02 * jax.random.normal(
@@ -239,6 +242,8 @@ def chunk_attention_lse(qh, kh, vh, kmask):
 
 @register_impl(SelfAttentionLayer)
 class SelfAttentionImpl(LayerImpl):
+    region = "attention"
+
     def init(self, conf, rng, dtype):
         k1, k2 = jax.random.split(rng)
         n_in, n = conf.n_in, conf.n_out
@@ -263,15 +268,6 @@ class SelfAttentionImpl(LayerImpl):
             return {"k": (row, jnp.int8), "k_scale": (scale, jnp.float32),
                     "v": (row, jnp.int8), "v_scale": (scale, jnp.float32)}
         return {"k": (row, dtype), "v": (row, dtype)}
-
-    def cache_block(self, conf, capacity, kv_dtype, page_size):
-        """The key-block length in which a step walks this layer's entry
-        of `capacity` positions (ops/decode_attention.py resolves the
-        same from the capacity and the head size alone)."""
-        D = conf.n_out // conf.n_heads
-        if kv_dtype == "int8":
-            return autotune.decode_block_q8(capacity, D, page_size)
-        return autotune.decode_block(capacity, D)
 
     def apply_cached(self, conf, params, x, entry, step):
         """One serving step through this layer's cache entry (module

@@ -17,6 +17,8 @@ the layer input at BaseLayer) is implemented here once, with keyed PRNG.
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -71,6 +73,12 @@ class LayerImpl:
     # `apply_cached` instead (nn/layers/attention.py).
     per_position = False
 
+    # The region of a compiled program this layer's ops lie in (one of
+    # telemetry/recorder.py REGIONS): the containers' forwards and the
+    # serving walk call the layer under `region_scope(region)`. None:
+    # unscoped, its device time reads as `other`.
+    region = None
+
     @staticmethod
     def rewindable(conf) -> bool:
         """False where a serving step cannot be taken out of this
@@ -88,6 +96,24 @@ class LayerImpl:
     # pretrain interface (AutoEncoder/RBM): returns (loss, params-grad-ready fn)
     def pretrain_loss(self, conf, params, x, rng):
         raise NotImplementedError(f"{type(self).__name__} is not a pretrain layer")
+
+
+def region_scope(region):
+    """`jax.named_scope(region)` around a layer's call, or no scope where
+    `region` is None. A scope is HLO metadata (`op_name`): the compiled
+    instructions are the same with it and without it."""
+    return contextlib.nullcontext() if region is None \
+        else jax.named_scope(region)
+
+
+def input_region(inputs, regions):
+    """The region of a vertex that is no layer (a residual add, a merge):
+    that of its input computed last, so that an operation the compiler
+    fuses into the vertex's (a block's last product with the residual
+    add) reads as the layer that fed it. `regions`: {name: (position in
+    the forward, region)} of what was computed before."""
+    seen = [regions[i] for i in inputs if i in regions]
+    return max(seen)[1] if seen else None
 
 
 def apply_dropout(x, rate, rng, *, train):

@@ -64,6 +64,7 @@ def gated_ffn(x, w_gate, w_up, w_down, activation="silu"):
 @register_impl(GatedDenseLayer)
 class GatedDenseImpl(LayerImpl):
     per_position = True
+    region = "ffn"
 
     def init(self, conf, rng, dtype):
         D, O = conf.n_in, conf.n_out or conf.n_in
@@ -86,6 +87,7 @@ class GatedDenseImpl(LayerImpl):
 @register_impl(DenseLayer)
 class DenseImpl(LayerImpl):
     per_position = True
+    region = "ffn"
 
     def init(self, conf, rng, dtype):
         return _dense_init(conf, rng, dtype)
@@ -102,6 +104,7 @@ class OutputImpl(LayerImpl):
     computes the softmax/loss delta jointly)."""
 
     per_position = True
+    region = "head"
 
     def init(self, conf, rng, dtype):
         params, state = _dense_init(conf, rng, dtype)
@@ -191,6 +194,7 @@ class EmbeddingImpl(LayerImpl):
     gather; grads are scatter-adds. Input: int [batch] or [batch, 1]."""
 
     per_position = True
+    region = "embed"
 
     def init(self, conf, rng, dtype):
         params, _ = _dense_init(conf, rng, dtype)

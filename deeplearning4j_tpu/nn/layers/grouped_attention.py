@@ -161,6 +161,7 @@ def _heads_out(o, conf, dtype):
 
 @register_impl(GroupedAttentionLayer)
 class GroupedAttentionImpl(LayerImpl):
+    region = "attention"
     counters = ("attn_rows_seen", "attn_wrapped")
 
     @staticmethod
@@ -246,12 +247,13 @@ class GroupedAttentionImpl(LayerImpl):
         def written():
             idx = (rows[:, None, None], jnp.arange(Hk)[None, :, None],
                    at[:, None, :])
-            return {kn: entry[kn].at[idx].set(
-                        k.transpose(0, 2, 1, 3).astype(entry[kn].dtype),
-                        mode="drop"),
-                    vn: entry[vn].at[idx].set(
-                        v.transpose(0, 2, 1, 3).astype(entry[vn].dtype),
-                        mode="drop")}
+            with jax.named_scope("cache_write"):
+                return {kn: entry[kn].at[idx].set(
+                            k.transpose(0, 2, 1, 3).astype(entry[kn].dtype),
+                            mode="drop"),
+                        vn: entry[vn].at[idx].set(
+                            v.transpose(0, 2, 1, 3).astype(entry[vn].dtype),
+                            mode="drop")}
 
         if step.chunk:
             start = pos[:, 0]

@@ -205,14 +205,15 @@ def latent_attention(conf, params, x, positions, *, cache=None, rows=None,
         prefill_attention.use_kernel()
     limit = T
     if cache is not None:
-        if keep is not None:
-            ckv = ckv * keep[..., None].astype(ckv.dtype)
-            kpe = kpe * keep[..., None].astype(kpe.dtype)
         at = (jnp.arange(b) if rows is None else rows)[:, None]
-        cache = {"ckv": cache["ckv"].at[at, positions].set(
-                     ckv.astype(cache["ckv"].dtype)),
-                 "kpe": cache["kpe"].at[at, positions].set(
-                     kpe.astype(cache["kpe"].dtype))}
+        with jax.named_scope("cache_write"):
+            if keep is not None:
+                ckv = ckv * keep[..., None].astype(ckv.dtype)
+                kpe = kpe * keep[..., None].astype(kpe.dtype)
+            cache = {"ckv": cache["ckv"].at[at, positions].set(
+                         ckv.astype(cache["ckv"].dtype)),
+                     "kpe": cache["kpe"].at[at, positions].set(
+                         kpe.astype(cache["kpe"].dtype))}
         ckv, kpe = cache["ckv"], cache["kpe"]
         if rows is not None and not kernel:
             ckv, kpe = ckv[rows], kpe[rows]
@@ -232,6 +233,8 @@ def latent_attention(conf, params, x, positions, *, cache=None, rows=None,
 
 @register_impl(LatentAttentionLayer)
 class LatentAttentionImpl(LayerImpl):
+    region = "attention"
+
     def init(self, conf, rng, dtype):
         H, d = conf.n_heads, conf.n_in
         c, n, r, v = conf.kv_rank, conf.nope_dim, conf.rope_dim, conf.v_dim

@@ -269,6 +269,8 @@ def moe_apply_routed(params, x2d, *, top_k, capacity_factor, activation,
 
 @register_impl(MixtureOfExpertsLayer)
 class MixtureOfExpertsImpl(LayerImpl):
+    region = "moe"
+
     def init(self, conf, rng, dtype):
         E = conf.n_experts
         D, O = conf.n_in, conf.n_out or conf.n_in
@@ -417,29 +419,35 @@ def dropless_moe(conf, params, x2d, valid=None):
     counts (int32 scalars): `moe_pairs` the (token, held selected
     expert) pairs, `moe_rows` the expert rows computed for them (rounds
     x held x round_rows: padding included), `moe_max_load` the most pairs
-    on one held expert."""
+    on one held expert.
+
+    Its ops lie in three child regions of the layer's `moe`: the
+    routing and the dispatch's bookkeeping in `router`, the rounds of
+    the held experts' products in `experts`, the shared expert in
+    `shared_expert`."""
     N, D = x2d.shape
     held = conf.n_held or conf.n_experts
     act = conf.activation or "silu"
-    top_i, w = route_sigmoid_topk(x2d, params["Wg"], conf.top_k,
-                                  conf.routed_scaling, params.get("bsel"))
-    local = top_i - conf.first_expert                        # [N, k]
-    mine = (local >= 0) & (local < held)
-    if valid is not None:
-        mine = mine & valid.reshape(N, 1).astype(bool)
-    onehot = (local[:, :, None] == jnp.arange(held)) & mine[:, :, None]
-    sel = jnp.any(onehot, axis=1)                            # [N, held]
-    gate = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1)
-    rank = jnp.cumsum(sel.astype(jnp.int32), axis=0) - 1
-    max_load = jnp.max(jnp.sum(sel.astype(jnp.int32), axis=0))
     C = round_rows(N, conf.top_k, conf.n_experts)
-    rounds = (max_load + C - 1) // C
-    # per buffer row j: its expert's column of the [N, held] grids, and
-    # the rank within the round that it serves
-    rank_r = jnp.repeat(jnp.where(sel, rank, -1), C, axis=1)  # [N, held*C]
-    gate_r = jnp.repeat(gate, C, axis=1)
-    slot = jnp.tile(jnp.arange(C), held)
     O = params["We_down"].shape[-1]
+    with jax.named_scope("router"):
+        top_i, w = route_sigmoid_topk(x2d, params["Wg"], conf.top_k,
+                                      conf.routed_scaling, params.get("bsel"))
+        local = top_i - conf.first_expert                    # [N, k]
+        mine = (local >= 0) & (local < held)
+        if valid is not None:
+            mine = mine & valid.reshape(N, 1).astype(bool)
+        onehot = (local[:, :, None] == jnp.arange(held)) & mine[:, :, None]
+        sel = jnp.any(onehot, axis=1)                        # [N, held]
+        gate = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1)
+        rank = jnp.cumsum(sel.astype(jnp.int32), axis=0) - 1
+        max_load = jnp.max(jnp.sum(sel.astype(jnp.int32), axis=0))
+        rounds = (max_load + C - 1) // C
+        # per buffer row j: its expert's column of the [N, held] grids,
+        # and the rank within the round that it serves
+        rank_r = jnp.repeat(jnp.where(sel, rank, -1), C, axis=1)
+        gate_r = jnp.repeat(gate, C, axis=1)
+        slot = jnp.tile(jnp.arange(C), held)
 
     def one_round(r, y):
         here = rank_r == slot + r * C                        # [N, held*C]
@@ -453,14 +461,16 @@ def dropless_moe(conf, params, x2d, valid=None):
             "nj,jo->no", jnp.where(here, gate_r, 0.0).astype(out.dtype),
             out.reshape(held * C, O), preferred_element_type=jnp.float32)
 
-    y = jax.lax.fori_loop(
-        0, -(-N // C),
-        lambda r, y: jax.lax.cond(r < rounds, one_round,
-                                  lambda _r, y: y, r, y),
-        jnp.zeros((N, O), jnp.float32))
+    with jax.named_scope("experts"):
+        y = jax.lax.fori_loop(
+            0, -(-N // C),
+            lambda r, y: jax.lax.cond(r < rounds, one_round,
+                                      lambda _r, y: y, r, y),
+            jnp.zeros((N, O), jnp.float32))
     if conf.n_shared:
-        y = y + gated_ffn(x2d, params["Ws_gate"], params["Ws_up"],
-                          params["Ws_down"], act).astype(jnp.float32)
+        with jax.named_scope("shared_expert"):
+            y = y + gated_ffn(x2d, params["Ws_gate"], params["Ws_up"],
+                              params["Ws_down"], act).astype(jnp.float32)
     counts = {"moe_pairs": jnp.sum(sel.astype(jnp.int32)),
               "moe_rows": rounds * (held * C),
               "moe_max_load": max_load}
@@ -469,6 +479,7 @@ def dropless_moe(conf, params, x2d, valid=None):
 
 @register_impl(DroplessMoELayer)
 class DroplessMoEImpl(LayerImpl):
+    region = "moe"
     counters = COUNTERS
 
     @staticmethod

@@ -111,6 +111,12 @@ def _output(conf, params, y):
 
 @register_impl(PowerRetentionLayer)
 class PowerRetentionImpl(LayerImpl):
+    """The token mixer of its block, in place of attention: its ops lie
+    in the region `attention` (telemetry/recorder.py REGIONS), as every
+    token mixer's do, so a retention step's state pass reads where a
+    softmax layer's walk over its rows does."""
+
+    region = "attention"
     counters = ("state_resets",)
 
     @staticmethod
@@ -180,8 +186,9 @@ class PowerRetentionImpl(LayerImpl):
                 jnp.where(fresh[..., None], 0, entry["s"][rows]),
                 jnp.where(fresh, 0, entry["z"][rows]),
                 keep=step.keep, eps=conf.sum_eps)
-            entry = {"s": entry["s"].at[rows].set(s),
-                     "z": entry["z"].at[rows].set(z)}
+            with jax.named_scope("cache_write"):
+                entry = {"s": entry["s"].at[rows].set(s),
+                         "z": entry["z"].at[rows].set(z)}
         elif x.shape[1] != 1:
             raise ValueError(
                 "PowerRetentionLayer decodes one token a row a step: a "

@@ -115,6 +115,14 @@ def make_train_step(loss_fn, tx, layer_confs_by_name, mesh=None,
     weight-update placement is unchanged. The overlap step does not
     thread TBPTT carries (extras is always empty).
     """
+    def update(grads, params, opt_state):
+        # the forward and backward lie under `loss` (each layer in its
+        # own region inside it), the update under `optimizer`
+        with jax.named_scope("optimizer"):
+            grads = normalize_gradients(grads, layer_confs_by_name)
+            updates, opt_state = tx.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state
+
     if overlap is not None:
         if mesh is None:
             raise ValueError("overlap=BucketPlan requires a mesh")
@@ -129,22 +137,19 @@ def make_train_step(loss_fn, tx, layer_confs_by_name, mesh=None,
         core = _make_overlap_core(loss_fn, mesh, overlap, data_axis)
 
         def step(params, opt_state, state, rng, batch):
-            loss, grads, new_state = core(params, state, rng, batch)
-            grads = normalize_gradients(grads, layer_confs_by_name)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("loss"):
+                loss, grads, new_state = core(params, state, rng, batch)
+            params, opt_state = update(grads, params, opt_state)
             return params, opt_state, new_state, loss, {}
     else:
         def step(params, opt_state, state, rng, batch):
             # GSPMD cannot partition a Pallas kernel: the kernels run
             # per device over this mesh (ops/partition.py)
-            with kernel_mesh(mesh):
+            with kernel_mesh(mesh), jax.named_scope("loss"):
                 (loss, aux), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, state, rng, batch)
             new_state, extras = aux if isinstance(aux, tuple) else (aux, {})
-            grads = normalize_gradients(grads, layer_confs_by_name)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params, opt_state = update(grads, params, opt_state)
             return params, opt_state, new_state, loss, extras
 
     donate_argnums = (0, 1, 2) if donate else ()

@@ -710,8 +710,7 @@ class _GenWorker:
     (`_live`): an idle row attends no key, so the walk over the cache's
     key blocks (ops/decode_attention.py) ends at the last block an
     occupied row can see, not at the scratch position's; a counting
-    layer computes nothing for it. Each step's span says how far the
-    walk went: `kv_blocks` of `kv_blocks_cap` (`_kv_blocks`).
+    layer computes nothing for it.
 
     THE LOOP RUNS ONE PROGRAM AHEAD. A program (a prompt chunk or a
     decode step) is RETIRED (its tokens fetched and emitted, its slots
@@ -719,7 +718,7 @@ class _GenWorker:
     has been dispatched; at most one un-retired program (`_flight`)
     stands behind the one just dispatched. Nothing a step is handed
     waits for the host: completion is by count alone (no stop token), so
-    positions, the live set and `kv_blocks` come from the host's own
+    positions and the live set come from the host's own
     count, which advances at DISPATCH (`_Slot.start`, `.pos`, `.sent`),
     and the slots' last tokens never leave the device: every plain
     step's fetched array is the [n_slots] token vector (a chunk writes
@@ -846,7 +845,7 @@ class _GenWorker:
         import jax
         import jax.numpy as jnp
 
-        from deeplearning4j_tpu.nn.decode import serving_params, walk_block
+        from deeplearning4j_tpu.nn.decode import serving_params
 
         self.index = index
         self.net = net
@@ -896,8 +895,6 @@ class _GenWorker:
         self._closed = False
         self._thread: threading.Thread | None = None
 
-        self.kv_block = walk_block(net, plan.capacity, plan.kv_dtype,
-                                   plan.page_size)
         prefill_raw = net.prefill_fn(plan.kv_dtype, plan.page_size)
         step_raw = net.incremental_decode_fn(plan.kv_dtype,
                                              plan.page_size)
@@ -914,8 +911,10 @@ class _GenWorker:
                 tok = jnp.concatenate([tok.reshape(-1), out[2]])
             return tok, out[1]
 
+        # the next token's choice is the head's work
         def argmax(out):
-            return jnp.argmax(out[0], axis=-1).astype(jnp.int32)
+            with jax.named_scope("head"):
+                return jnp.argmax(out[0], axis=-1).astype(jnp.int32)
 
         # THE SLOTS' LAST TOKENS STAY ON THE DEVICE (`self._tokens`): a
         # plain step's fetched array is a [n_slots] vector (counters
@@ -976,19 +975,6 @@ class _GenWorker:
         live = np.zeros(self.plan.n_slots, bool)
         live[list(active)] = True
         return live
-
-    def _kv_blocks(self, key_limit: int) -> dict:
-        """A model step's span fields `kv_blocks` and `kv_blocks_cap`:
-        the key blocks its cached attention visits, given the largest
-        visible-key bound among the step's live queries, and the blocks
-        the capacity holds. Host arithmetic on positions the engine
-        already has: the program is not asked. {} for a net none of
-        whose layers walks the cache in blocks (`kv_block` None)."""
-        if not self.kv_block:
-            return {}
-        cap = self.plan.capacity // self.kv_block
-        return {"kv_blocks": min(-(-int(key_limit) // self.kv_block), cap),
-                "kv_blocks_cap": cap}
 
     def _split_fetch(self, fetched, shape: tuple, span) -> np.ndarray:
         """The step's tokens, in `shape`, out of the fetched array; the
@@ -1193,8 +1179,7 @@ class _GenWorker:
                 with rec.span("prefill_chunk", bucket=[1, Tc],
                               start=slot.start, replica=self.index,
                               final=final, n_real=n_real,
-                              ahead=prev is not None,
-                              **self._kv_blocks(slot.start)) as step, \
+                              ahead=prev is not None) as step, \
                         compiling:
                     with rec.span("dispatch"):
                         tok, self.cache = self._prefill_jit(
@@ -1319,9 +1304,7 @@ class _GenWorker:
         try:
             with rec.span("decode_step", replica=self.index,
                           n_active=len(active), slots=self.current_batch,
-                          ahead=prev is not None,
-                          **self._kv_blocks(
-                              pos[active].max() + 1)) as step:
+                          ahead=prev is not None) as step:
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
@@ -1394,9 +1377,7 @@ class _GenWorker:
         try:
             with rec.span("verify_step", replica=self.index,
                           n_active=len(active), k=K,
-                          slots=self.current_batch,
-                          **self._kv_blocks(
-                              pos[active].max() + K)) as step:
+                          slots=self.current_batch) as step:
                 if self.faults is not None:
                     self.faults.check(self.index, "decode",
                                       self.decode_steps_run)
@@ -1790,7 +1771,6 @@ class GenerationEngine:
                       lattice=lattice.describe(),
                       cache=self.plan.describe(net),
                       prefill_chunk=chunk,
-                      decode_block_k=self._workers[0].kv_block,
                       speculative_k=self.speculative_k,
                       restored_step=self.restored_step,
                       **self._workers[0].weights_facts)

@@ -12,6 +12,8 @@ the tools' no-jax package stubs can import this package.
 from deeplearning4j_tpu.telemetry.recorder import (  # noqa: F401
     ENV_VAR,
     EVENT_KINDS,
+    REGION_NAMES,
+    REGIONS,
     SPAN_NAMES,
     NullRecorder,
     Recorder,

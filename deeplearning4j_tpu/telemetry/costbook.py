@@ -37,9 +37,11 @@ exception on the warmup path.
 
 from __future__ import annotations
 
+import re
 import threading
 
-from deeplearning4j_tpu.telemetry.recorder import NullRecorder, Recorder
+from deeplearning4j_tpu.telemetry.recorder import (REGIONS, NullRecorder,
+                                                   Recorder)
 
 DEFAULT_DRIFT_FACTOR = 8.0
 
@@ -80,6 +82,102 @@ def _first(analysis):
     if isinstance(analysis, (list, tuple)):
         return analysis[0] if analysis else {}
     return analysis or {}
+
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(([^)]*)\)")
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_REF = re.compile(r"%?([\w.\-]+)")
+# a scope in an op_name's path: a name, or a transform of one ("jvp(moe)")
+_SCOPE = re.compile(r"[\w\-]+(\([\w\-()]*\))?")
+# instructions that never run as an operation of their own
+_NOT_RUN = frozenset({"parameter", "constant", "tuple", "get-tuple-element"})
+
+
+def region_path(op_name: str) -> str | None:
+    """The region an `op_name` names: "top" or "top/child" of `REGIONS`,
+    the innermost top-level region in the path ("jit(step)/loss/
+    transpose(jvp(attention))/dot_general" is "attention"), or None. A
+    part of the path that is no name (an argument's, "params['embed']
+    ['W']") names no region."""
+    top = child = None
+    words = [w for part in op_name.split("/") if _SCOPE.fullmatch(part)
+             for w in re.findall(r"[\w\-]+", part)]
+    for word in words:
+        if word in REGIONS:
+            top, child = word, None
+        elif top is not None and word in REGIONS[top]:
+            child = word
+    if top is None:
+        return None
+    return top if child is None else f"{top}/{child}"
+
+
+def hlo_regions(text: str) -> dict:
+    """{"module": the HLO module's name, "ops": {instruction: region}} of
+    a compiled program's text (`Compiled.as_text()`), for the
+    instructions a profiler trace can show: every one outside a fusion's
+    body, but parameters, constants and tuples. An instruction's region
+    is its `op_name`'s (`region_path`); one the compiler made with none
+    (a prefetch, a relayout copy, an async `*-done`) takes the region of
+    its `*-start`, else of the first instruction it feeds that has one,
+    else of the first it reads; one left with none is not listed."""
+    module, comp, fused = "", "", set()
+    insts = []      # (name, opcode, operands, computation)
+    region, users = {}, {}
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            if line.startswith("HloModule "):
+                module = line.split()[1].rstrip(",")
+            elif line.endswith("{"):
+                comp = line.removeprefix("ENTRY ").split()[0].lstrip("%")
+            continue
+        if " = " not in line:
+            continue
+        name, rest = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        m = _OPCODE.search(rest)
+        if m is None:
+            continue
+        name, op = name.lstrip("%"), m.group(1)
+        operands = _REF.findall(m.group(2))
+        if op == "fusion":
+            fused.update(_CALLS.findall(rest))
+        named = _OP_NAME.search(rest)
+        region[name] = region_path(named.group(1)) if named else None
+        for i in operands:
+            users.setdefault(i, []).append(name)
+        insts.append((name, op, operands, comp))
+    for n, op, ins, _c in insts:
+        if region[n] is None and op.endswith("-done") and ins:
+            region[n] = region.get(ins[0])
+    # a parameter, a constant or a tuple neither takes a region nor hands
+    # one on: many layers read one
+    for n, op, _ins, _c in reversed(insts):
+        if region[n] is None and op not in _NOT_RUN:
+            region[n] = next((region[u] for u in users.get(n, ())
+                              if region.get(u)), None)
+    for n, op, ins, _c in insts:
+        if region[n] is None and op not in _NOT_RUN:
+            region[n] = next((region[i] for i in ins if region.get(i)),
+                             None)
+    return {"module": module,
+            "ops": {n: region[n] for n, op, _ins, c in insts
+                    if region[n] and op not in _NOT_RUN and c not in fused}}
+
+
+def _regions_of(lowered, compiled) -> dict:
+    """`hlo_regions` of the compiled program. A persistent compile cache
+    keys a program without its metadata, so the executable it hands back
+    may be one compiled before the program named its regions (the same
+    instructions, no scopes): then the program is compiled once more as
+    it is lowered now, under a key that holds its metadata."""
+    found = hlo_regions(compiled.as_text())
+    if not found["ops"]:
+        from jax._src import config  # the key's switch has no public API
+
+        with config.compilation_cache_include_metadata_in_key(True):
+            found = hlo_regions(lowered.compile().as_text())
+    return found
 
 
 # Fingerprint -> compile-derived field dict. memory_analysis() needs
@@ -141,6 +239,10 @@ def harvest(jitted, *args, **kwargs) -> dict:
         except Exception:
             pass
     try:
+        compiled_fields["regions"] = _regions_of(lowered, compiled)
+    except Exception:
+        pass
+    try:
         ma = compiled.memory_analysis()
         for attr, key in (("temp_size_in_bytes", "peak_temp_bytes"),
                           ("argument_size_in_bytes", "argument_bytes"),
@@ -199,12 +301,16 @@ class CostBook:
             if key in self._book:
                 return {}
         fields = harvest(jitted, *args, **(kwargs or {}))
+        regions = fields.pop("regions", None)
         if not fields:
             return {}
         with self._mu:
             if key in self._book:  # lost a warmup race: keep the first
                 return {}
             self._book[key] = dict(fields)
+        if regions is not None:
+            self.recorder.event("regions", entry=entry, shape=list(key[1]),
+                                **regions)
         return self.recorder.cost(entry, list(key[1]), **fields, **extra)
 
     # ------------------------------------------------------------- lookups

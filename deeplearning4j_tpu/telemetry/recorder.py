@@ -37,6 +37,7 @@ Event schema — one JSON object per line, every event carrying
 | `autoscale` | one fleet-supervisor autoscale tick (serving/fleet.FleetSupervisor): `n_serving`, `n_replicas`, `queue_depth`, `p99_ms` (the decision inputs), `action` (+1 grew / -1 drained / 0), `max_replicas` — the occupancy bench row's only source; replica self-healing rides `fault` events (`replica-kill`/`replica-hang` when an injected fault fires, `replica-dead` with the requeued count when the supervisor reaps, `replica-respawn` with `respawn_ms` on re-admission) |
 | `anomaly` | one detector finding (telemetry/trace.py) put on the record by whoever ran the detector — the elastic supervisor's straggler watch, `tracetool check`, or the bench sweep: `kind` ("straggler" / "retrace" / "input_wait_spike" / "queue_spike" / "leak" / "headroom" / "cost_drift"), `process`, and the kind's evidence fields (`step`+`skew_ms` for stragglers, the offending span's name/seconds for retraces and spikes, byte counts + growth/ratio fields for the memory kinds) |
 | `cost` | one compiled executable's cost-book entry (telemetry/costbook.py), harvested at warmup/compile time from XLA's own `cost_analysis()` / `memory_analysis()` — NEVER on the hot path (it rides the existing `compile` spans): `entry` (the jit wrapper's name: "forward", "prefill", "decode", "verify", "fit_scanned", ...), `shape` (the warmed shape key), `flops`, `bytes_accessed`, `peak_temp_bytes`, `argument_bytes`, `output_bytes`, `alias_bytes` (argument bytes the outputs reuse in place: at least the KV cache's bytes for the serving steps, which donate it; 0 for an executable that donates nothing), `generated_code_bytes` — the denominators behind the MFU gauge and the capacity planner's measured-cost side |
+| `regions` | one compiled executable's map from instruction to region (telemetry/costbook.py, beside its `cost` event, from the same warm-up compile): `entry`, `shape` (as on `cost`), `module` (the HLO module's name, the one a profiler trace's `XLA Modules` line names the program by), `ops` ({instruction name: "top" or "top/child" of `REGIONS`} for every instruction outside a fusion's body that some region holds, by its `op_name` metadata; an instruction the compiler made with no scope takes that of the instruction it feeds) — a profiler trace's `XLA Ops` event names the instruction only, so this table is what lays a program's device time out by region |
 | `cost_drift` | one predicted-vs-measured reconciliation of the placement cost model (reshard/search.py `winner_memory_bytes` vs a measured per-device peak from later `memory`/`cost` events): `predicted_bytes`, `measured_bytes`, `ratio` (measured/predicted), `factor` (the documented tolerance band — outside [1/factor, factor] is an anomaly), `source` — emitted once after the first real step, the calibration loop closing over the search's exact-rational predictions |
 
 **Correlation fields** (the fleet-timeline contract, tools/tracetool.py):
@@ -55,7 +56,9 @@ run id is the implicit root).
 
 **Registered schema** (graftlint G023): `EVENT_KINDS` and `SPAN_NAMES`
 below are the ONLY event kinds / span names code outside `telemetry/`
-may emit as string literals — an unknown literal is a lint finding, so
+may emit as string literals, and `REGION_NAMES` the only regions it may
+name in a `jax.named_scope` or a layer impl's `region` — an unknown
+literal is a lint finding, so
 the fleet-timeline tooling (merge, stats, anomaly detection, Perfetto
 export) never meets a name it cannot classify. Dynamic names
 (f-strings like the bench sweep's `mode:<name>` spans) are exempt from
@@ -73,16 +76,7 @@ decode_step when the engine runs with
 the acceptance accounting); their first execution per shape nests a
 `compile` span exactly like the predict path, and the
 flat-across-prompt-buckets property of the decode_step timings is the
-"decode cost independent of prompt length" gate in tier-1. All three
-carry how far the step's walk over the cache's key blocks went
-(ops/decode_attention.py), where the net's attention walks it in
-blocks: `kv_blocks` (the blocks the loop visits: ceil(largest
-visible-key bound among the step's live queries / block length); 0 for
-a prompt's first chunk) of `kv_blocks_cap` (capacity / block length);
-host arithmetic on the positions the engine holds, nothing is fetched
-for it. The engine's `meta` event gives the block length,
-`decode_block_k` (null for a net whose layers own their cached
-forward). Where the
+"decode cost independent of prompt length" gate in tier-1. Where the
 served net has a counting layer (the dropless expert layer,
 nn/layers/moe.py `DroplessMoELayer`), all three carry what a step's
 program counted, handed home behind the tokens in the one fetch (a
@@ -138,7 +132,7 @@ stayed), then per program `step_prepare` (the numpy build of the
 step's arguments — `kind` "prefill" / "decode" / "verify"), then the
 `prefill_chunk` / `decode_step` span of the program being LAUNCHED
 (`program` = its sequence number on this replica; `n_active`, `slots`,
-`kv_blocks`, `bucket`, `start`, `n_real` are ITS rows and positions, as
+`bucket`, `start`, `n_real` are ITS rows and positions, as
 the host counts them at dispatch; `ahead` = true when it was dispatched
 while the program before it was un-retired, which in a busy server is
 every step but the first after an `idle_wait`) with two children:
@@ -232,7 +226,7 @@ EVENT_KINDS = frozenset({
     "bucket_plan", "kernel_tune", "request", "page_pool", "draft",
     "admit", "stream", "reshard_plan",
     "placement_search", "host_gather", "weight_swap", "autoscale",
-    "anomaly", "cost", "cost_drift",
+    "anomaly", "cost", "cost_drift", "regions",
 })
 
 SPAN_NAMES = frozenset({
@@ -255,6 +249,23 @@ SPAN_NAMES = frozenset({
     # embedding engine + ANN serving (embedding/)
     "gather", "scatter_add", "ann_probe",
 })
+
+# The regions inside a compiled program: `jax.named_scope(<region>)` around
+# each layer's ops (nn/decode._walk, nn/graph._forward: the layer impl's
+# `region`), the engine's argmax and the train step's loss and update. A
+# region is HLO metadata (`op_name`) and nothing else: the compiled
+# instructions are the same with and without it. {top-level region: its
+# child regions}; `cost`'s sibling event `regions` maps each instruction
+# of a compiled program to "top" or "top/child" (telemetry/costbook.py),
+# and an instruction under none reads as `other`. graftlint G023 holds
+# every scope literal outside telemetry/ to REGION_NAMES.
+REGIONS = {
+    "embed": (), "norm": (), "attention": ("cache_write",),
+    "moe": ("router", "experts", "shared_expert"), "ffn": (), "head": (),
+    "loss": (), "optimizer": (),
+}
+REGION_NAMES = frozenset(REGIONS) | frozenset(
+    child for children in REGIONS.values() for child in children)
 
 # Ring-buffer length for the in-memory mirror of emitted events; large
 # enough for a full bench sweep, bounded so a long fit() can't grow RSS.

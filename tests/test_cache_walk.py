@@ -12,9 +12,7 @@ the walk of nn/decode.py calls it and names no layer."""
 
 import ast
 import inspect
-import json
 import pathlib
-import urllib.request
 
 import jax
 import jax.numpy as jnp
@@ -263,55 +261,6 @@ def test_idle_rows_told_so_change_no_live_rows_output():
     assert np.array_equal(np.asarray(told)[live], np.asarray(untold)[live])
 
 
-def test_spans_say_how_far_the_walk_went():
-    """`kv_blocks` and `kv_blocks_cap` on every model step's span; a
-    short request with idle slots beside it stays under the cap (an idle
-    slot's scratch position no longer counts), and the `meta` event
-    carries the block length."""
-    from deeplearning4j_tpu.serving.buckets import BucketLattice
-    from deeplearning4j_tpu.serving.engine import GenerationEngine
-    from deeplearning4j_tpu.serving.server import ServingServer
-    from deeplearning4j_tpu.telemetry import Recorder
-
-    rec = Recorder(path=None, keep=10_000)
-    with autotune.override({"decode_attn": {"block_k": 8}}):
-        engine = GenerationEngine(
-            _tiny_lm(), BucketLattice(batch_sizes=(1,), seq_lens=(8, 16, 32)),
-            slots=4, max_new_tokens=16, page_size=8, prefill_chunk=8,
-            recorder=rec)
-        warm = engine.warmup()
-    server = ServingServer(engine, port=0).start()
-    try:
-        for prompt_len, new in ((5, 4), (19, 8)):
-            body = json.dumps({"tokens": list(range(1, prompt_len + 1)),
-                               "max_new_tokens": new}).encode()
-            with urllib.request.urlopen(urllib.request.Request(
-                    f"{server.url}/generate", data=body,
-                    headers={"Content-Type": "application/json"}),
-                    timeout=120) as resp:
-                resp.read()
-    finally:
-        server.stop()
-    assert engine.trace_count == warm       # one program for every fill
-    events = list(rec.events)
-    [meta] = [e for e in events if e["event"] == "meta"
-              and e.get("role") == "generation-engine"]
-    assert meta["decode_block_k"] == 8
-    spans = {name: [e for e in events if e["event"] == "span"
-                    and e["name"] == name]
-             for name in ("decode_step", "prefill_chunk")}
-    assert len(spans["decode_step"]) == 3 + 7
-    for e in spans["decode_step"] + spans["prefill_chunk"]:
-        assert 0 <= e["kv_blocks"] <= e["kv_blocks_cap"] == 48 // 8
-    # one request at a time on four slots: three idle rows a step, whose
-    # scratch position 47 would have made every walk 6 blocks long
-    assert [e["kv_blocks"] for e in spans["decode_step"]] == \
-        [1, 1, 1, 3, 3, 3, 3, 3, 4, 4]
-    # a chunk's cross-chunk half sees the keys before its start
-    assert [(e["start"], e["kv_blocks"]) for e in spans["prefill_chunk"]] \
-        == [(0, 0), (0, 0), (8, 1), (16, 2)]
-
-
 # ------------------------------------------- the layers' cached forward
 
 def _attention_layer():
@@ -439,7 +388,7 @@ def test_positional_apply_cached_equals_apply(learned):
 
 def test_the_walk_names_no_layer_and_no_layer_imports_the_walk():
     """nn/decode.py asks the impls (`apply_cached`, `apply_counted`,
-    `per_position`, `cache_arrays`, `cache_block`): no identifier in it
+    `per_position`, `cache_arrays`, `region`): no identifier in it
     is a layer class of nn/conf/layers.py, it does not import the flash
     kernels, and nothing under nn/layers/ imports nn.decode (the step
     object is passed in)."""

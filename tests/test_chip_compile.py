@@ -540,3 +540,52 @@ def test_xent_head_under_a_mesh_splits_tokens_over_every_axis(
     assert _kernels(text) == {"softmax_xent_fwd": 1, "softmax_xent_dx": 1,
                               "softmax_xent_dwdb": 1}
     assert f"bf16[{N_TOK // 4},{D_MODEL}]" in text
+
+
+def test_regions_change_no_instruction_of_the_chip_program(
+        as_on_chip, one_chip, monkeypatch):
+    """A grouped-attention net with experts, its decode step compiled for
+    the chip with the `gqa_decode` kernel: with every layer's region and
+    with `jax.named_scope` made a no-op, the same instructions once the
+    metadata is stripped (the kernel's own body included); the scoped
+    program lays the kernel in `attention` and every kind of layer in
+    its region (telemetry/costbook.hlo_regions)."""
+    import contextlib
+
+    from deeplearning4j_tpu.models.grouped_moe import grouped_moe_lm
+    from deeplearning4j_tpu.ops import decode_attention as da
+    from deeplearning4j_tpu.telemetry.costbook import hlo_regions
+
+    monkeypatch.setattr(da, "_use_kernel", lambda: True)
+    slots, capacity = 8, 1024
+    tok = _sds((slots,), jnp.int32, one_chip)
+    live = _sds((slots,), jnp.bool_, one_chip)
+    texts = {}
+    for scoped in (True, False):
+        if not scoped:
+            monkeypatch.setattr(jax, "named_scope",
+                                lambda name: contextlib.nullcontext())
+        net = grouped_moe_lm(256, 256, 4, 2, 128,
+                             ("sliding_attention", "full_attention"), 512, 1,
+                             512, 8, 2, 256, 0, 4, dtype="bfloat16",
+                             param_dtype="bfloat16")
+        net.init()
+        cache = jax.eval_shape(
+            lambda: net.init_kv_cache(slots, capacity, "f32", PAGE))
+        _, texts[scoped] = _compile(
+            net.incremental_decode_fn("f32", PAGE), _on(net.params, one_chip),
+            _on(net.state, one_chip), _on(cache, one_chip), tok, tok, live)
+
+    def instructions(text):
+        return [re.sub(r", metadata=\{[^}]*\}", "", line)
+                for line in text[text.index("\n%"):].splitlines()]
+
+    assert _kernels(texts[True]) == {"gqa_decode": 2}
+    assert instructions(texts[True]) == instructions(texts[False])
+    ops = hlo_regions(texts[True])["ops"]
+    kernels = {r for n, r in ops.items() if n.startswith("gqa_decode")}
+    assert kernels == {"attention"}
+    assert {"embed", "norm", "attention", "attention/cache_write", "ffn",
+            "moe/router", "moe/experts", "moe/shared_expert", "head"} <= \
+        set(ops.values())
+    assert not hlo_regions(texts[False])["ops"]

@@ -577,6 +577,12 @@ def run(rec):
     rec.event("telemetry_blob", x=1)          # unregistered event kind
     rec.event("span", name="custom_region",   # unregistered via name=
               ok=True, seconds=0.0)
+    with jax.named_scope("my_layer"):           # unregistered region
+        pass
+
+
+class MyImpl:
+    region = "mixer"                          # unregistered region
 """, """\
 def run(rec, m, mode):
     with rec.span("compile", what="fit_scanned"):   # registered name
@@ -590,6 +596,13 @@ def run(rec, m, mode):
     rec.span(name)                # variable names are uncheckable
     with rec.span(f"mode:{mode}"):  # f-strings parse as opaque spans
         pass
+    with jax.named_scope("attention"):             # registered region
+        with jax.named_scope(region):              # variable: uncheckable
+            pass
+
+
+class MyImpl:
+    region = "ffn"                                 # registered region
 """),
     ("G024", """\
 def sample_tokens(slots, logits_batch):
@@ -1130,7 +1143,8 @@ def test_g023_scope_and_registry():
     _, pos, neg = next(f for f in FIXTURES if f[0] == "G023")
     hits = [f for f in lint_source(_PRELUDE + pos, FIXTURE_PATH)
             if f.rule == "G023"]
-    assert len(hits) == 3  # span literal + event kind + name= kwarg
+    # span literal + event kind + name= kwarg + scope + impl region
+    assert len(hits) == 5
     # the registry itself is exempt: the same source is silent there
     assert "G023" not in rules_in(
         pos, "deeplearning4j_tpu/telemetry/recorder.py")

@@ -575,7 +575,12 @@ def test_engine_serves_the_block_over_http_in_bfloat16(W):
         assert described["windows"] == {"k_win": 24, "v_win": 24}
         assert described["bytes_per_slot"] == per_slot
         assert described["states"] == {}
-    assert meta["decode_block_k"] is None
+    # each warmed program's instructions by region (telemetry/costbook.py)
+    regions = {e["entry"]: set(e["ops"].values()) for e in rec.events
+               if e.get("event") == "regions"}
+    assert {"attention", "attention/cache_write", "moe/router",
+            "moe/experts", "moe/shared_expert", "norm", "embed", "ffn",
+            "head"} <= regions["decode"] & regions["prefill"]
     server = ServingServer(engine, port=0).start()
     asked = ((5, 16), (30, 9), (16, 3), (27, 16))
     try:

@@ -354,7 +354,13 @@ def test_engine_serves_the_block_over_http_in_bfloat16(W):
         assert described["states"] == {"s": 3 * 2 * 16 * D * 4,
                                        "z": 3 * 2 * D * 4}
         assert described["state_bytes_per_slot"] == per_slot
-    assert meta["decode_block_k"] is None
+    # each warmed program's instructions by region (telemetry/costbook.py):
+    # the state pass is attention's; a chunk writes the state, a decode
+    # step's kernel writes it in place
+    regions = {e["entry"]: set(e["ops"].values()) for e in rec.events
+               if e.get("event") == "regions"}
+    assert {"attention", "norm", "ffn", "head"} <= regions["decode"]
+    assert "attention/cache_write" in regions["prefill"]
     server = ServingServer(engine, port=0).start()
     asked = ((5, 8), (13, 6), (16, 3), (7, 8), (9, 2))
     try:

@@ -57,6 +57,19 @@ CacheStep`:
 * `kv_dtype="int8"` is refused (a ring of requantised pages is its own
   work).
 
+HOW A CHUNK ATTENDS: the same single softmax over the entry's rows as
+found and the chunk's own keys, in one of two forms that tier-1 holds
+equal. On a TPU, one Pallas kernel (`ops/prefill_attention.gqa_prefill`,
+`gqa_prefill` in the device trace): each key-value head's G queries meet
+a block of its rows in VMEM, scores, running max and sum never reach
+memory, and only the blocks some query sees are copied, each row at its
+own length and window. Elsewhere, and for a shape the kernel does not
+take (`gqa_prefill_fits`: a chunk longer than its entry, rows that no
+block of 16 divides, a chunk of no whole 128-key lanes), `chunk_walk`:
+the chunk's own keys by `masked_attention_lse`, the entry by
+`ops/decode_attention.ring_attention` a block at a time, merged by their
+log-sum-exps. `apply` and the decode step never take the kernel.
+
 The layer counts, through the `counters` road of nn/decode.py:
 `attn_rows_seen`, the cache rows some query of the step could see, and
 `attn_wrapped`, the live rows of the step whose context is past the
@@ -77,6 +90,7 @@ from deeplearning4j_tpu.nn.layers.base import (
 )
 from deeplearning4j_tpu.nn.layers.latent_attention import rotary
 from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.ops import prefill_attention
 from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.ops.decode_attention import (
     gqa_decode,
@@ -149,6 +163,25 @@ def masked_attention_lse(conf, q, k, v, qpos, kpos, kmask=None):
     o = jnp.where(l[..., None] > 0.0, o / jnp.maximum(l, 1e-30)[..., None],
                   0.0)
     return o, m + jnp.log(jnp.maximum(l, 1e-30))
+
+
+def chunk_walk(conf, q, k, v, k_entry, v_entry, pos, keep, rows):
+    """A prefill chunk's attention in `jnp`: q, k, v [b, T, H, d] of the
+    chunk at positions pos [b, T] (running on from pos[:, 0]) against its
+    own keys (`keep` [b, T] 0 hiding one) and against the cache rows
+    `rows` [b] of the entry k_entry, v_entry [B, Hk, R, d] as the chunk
+    found it (`ops/decode_attention.ring_attention`), the two halves
+    merged by their log-sum-exps. -> [b, Hk, G * T, d] float32, grouped."""
+    _, Hk, _ = _sizes(conf)
+    b, T = pos.shape
+    G, W = conf.n_heads // Hk, conf.window
+    start = pos[:, 0]
+    o, lse = masked_attention_lse(conf, q, k, v, pos, pos, keep)
+    before = jnp.tile(jnp.broadcast_to(start[:, None], (b, T)), (1, G))
+    o, _ = lse_combine(o, lse, *ring_attention(
+        group_queries(q.transpose(0, 2, 1, 3), Hk), k_entry, v_entry, before,
+        start, rows, jnp.tile(pos - W + 1, (1, G)) if W else None))
+    return o
 
 
 def _heads_out(o, conf, dtype):
@@ -257,13 +290,15 @@ class GroupedAttentionImpl(LayerImpl):
 
         if step.chunk:
             start = pos[:, 0]
-            qg = group_queries(q.transpose(0, 2, 1, 3), Hk)
-            o, lse = masked_attention_lse(conf, q, k, v, pos, pos, keep)
-            before = jnp.tile(jnp.broadcast_to(start[:, None], (b, T)),
-                              (1, G))
-            o, _ = lse_combine(o, lse, *ring_attention(
-                qg, entry[kn], entry[vn], before, start, rows,
-                jnp.tile(pos - W + 1, (1, G)) if W else None))
+            if prefill_attention.use_kernel() and \
+                    prefill_attention.gqa_prefill_fits(T, R):
+                o = prefill_attention.gqa_prefill(
+                    group_queries(q.transpose(0, 2, 1, 3), Hk), entry[kn],
+                    entry[vn], k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3), keep, rows, start, window=W)
+            else:
+                o = chunk_walk(conf, q, k, v, entry[kn], entry[vn], pos,
+                               keep, rows)
             entry = written()
             prior = jnp.minimum(start, W - 1) if W else start
             seen = jnp.sum(jnp.where(n_kept > 0, prior + n_kept, 0))

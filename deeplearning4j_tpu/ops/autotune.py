@@ -144,6 +144,18 @@ DEFAULT_PREFILL_BLOCK_K = 512
 DEFAULT_PREFILL_SUB_ROWS = 512
 DEFAULT_PREFILL_HEADS = 4
 
+# Grouped attention's prefill chunk (`ops/prefill_attention.gqa_prefill`,
+# PR 40): a program instance holds a block of a row's chunk queries for
+# all G query heads of one key-value head, and one block of that head's
+# keys and values: of the entry's rows as found (`BLOCK_K` rows of the
+# ring or the full entry), then of the chunk's own (`BLOCK_K` keys, in
+# lanes of 128). The rows of a query block are scored in sub-blocks; a
+# sub-block that sees no key of the block is skipped. Each block feeds a
+# divisor search.
+DEFAULT_GQA_PREFILL_BLOCK_Q = 1024
+DEFAULT_GQA_PREFILL_BLOCK_K = 1024
+DEFAULT_GQA_PREFILL_SUB_ROWS = 128
+
 # Kernel-proven chunk-tile lengths for the long-context loop, largest
 # first (the single home for the tiling envelope quoted in error
 # messages). 8192 is the monolithic kernels' VMEM envelope at

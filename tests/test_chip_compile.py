@@ -349,6 +349,68 @@ def test_prefill_flash_kernel_keeps_the_scores_off_the_memory(
     assert mem.temp_size_in_bytes < 2**28 * 0.6, mem
 
 
+@pytest.mark.parametrize("window, rows", [(4096, 4096), (0, 17408)],
+                         ids=["ring", "full"])
+def test_gqa_prefill_kernel_keeps_the_scores_off_the_memory(
+        no_persistent_cache, one_chip, monkeypatch, window, rows):
+    """Grouped attention's cached prefill chunk at Trinity's widths (48
+    queries on 8 key-value heads of 128, hidden 3,072, bfloat16), the
+    1,024 bucket over the cell's 32-slot entry (a window layer's ring of
+    4,096 rows, a full layer's 17,408), as the layer runs it in the
+    prefill program on a TPU: it attends through the `gqa_prefill`
+    kernel, which compiles for the chip; no float32 array of the walk's
+    score block ([8, 6144, 512]) or larger is left, no copy of the entry
+    is made, and the program's temporaries are a few MB where the walk's
+    are 246-306 MB."""
+    from deeplearning4j_tpu.nn.conf.layers import GroupedAttentionLayer
+    from deeplearning4j_tpu.nn.decode import CacheStep
+    from deeplearning4j_tpu.nn.layers.grouped_attention import (
+        GroupedAttentionImpl,
+    )
+    from deeplearning4j_tpu.ops import prefill_attention as pa
+
+    h, Hq, Hk, d, Tc, slots, cap = 3072, 48, 8, 128, 1024, 32, 17408
+    conf = GroupedAttentionLayer(n_in=h, n_out=h, n_heads=Hq, n_kv_heads=Hk,
+                                 head_dim=d, window=window,
+                                 rope_theta=10000.0 if window else 0.0)
+    impl = GroupedAttentionImpl()
+    bf16 = jnp.bfloat16
+    shapes = {"Wq": (h, Hq * d), "Wk": (h, Hk * d), "Wv": (h, Hk * d),
+              "Wg": (h, Hq * d), "Wo": (Hq * d, h), "q_norm": (d,),
+              "k_norm": (d,)}
+    params = {k: _sds(s, bf16, one_chip) for k, s in shapes.items()}
+    cache = {n: _sds((slots,) + a[0], bf16, one_chip)
+             for n, a in impl.cache_arrays(conf, cap, "f32", PAGE,
+                                           bf16).items()}
+    assert {a.shape[2] for a in cache.values()} == {rows}
+
+    def chunk(params, x, cache, row, start, keep):
+        step = CacheStep(row, start[:, None] + jnp.arange(Tc)[None, :],
+                         keep=keep, chunk=True)
+        return impl.apply_cached(conf, params, x, cache, step)
+
+    one = _sds((1,), jnp.int32, one_chip)
+    args = (params, _sds((1, Tc, h), bf16, one_chip), cache, one, one,
+            _sds((1, Tc), jnp.float32, one_chip))
+    temp = {}
+    for kernel in (True, False):
+        monkeypatch.setattr(pa, "use_kernel", lambda: kernel)
+        # a new function each time: jit would reuse the first's trace
+        compiled = jax.jit(lambda *a: chunk(*a),
+                           donate_argnums=2).lower(*args).compile()
+        text = compiled.as_text()
+        temp[kernel] = compiled.memory_analysis().temp_size_in_bytes
+        big_scores = re.search(r"f32\[(1,)?8,6144,\d{3,}\]", text)
+        if kernel:
+            assert _kernels(text) == {"gqa_prefill": 1}
+            assert not big_scores
+            assert not re.search(
+                rf"= bf16\[{slots},{Hk},{rows},{d}\]\S* copy\(", text)
+        else:
+            assert not _kernels(text) and big_scores
+    assert temp[True] < 8 * 2**20 and temp[False] > 30 * temp[True], temp
+
+
 def _matrix_shapes(params):
     return {f"[{a.shape[0]},{a.shape[1]}]"
             for a in jax.tree.leaves(params) if a.ndim == 2}

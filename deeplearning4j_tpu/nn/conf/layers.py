@@ -447,18 +447,51 @@ class GroupedAttentionLayer(BaseRecurrentLayer):
     `n_heads / n_kv_heads` queries a head. `window` keys at most are
     seen by a query, its own among them (0: every earlier key);
     `rope_theta` turns queries and keys by their position (0: no
-    position at all). Each head's query and each head's key pass an RMS
-    norm with one learned gain vector, and the heads' output is
-    multiplied by sigmoid(x Wg) before the output projection. A layer
-    with a window keeps a RING of `window` rows a sequence in a serving
-    cache, whatever the cache's capacity."""
+    position at all), and of a head only the first `rotary_dim`
+    dimensions where that is not 0 (the rest pass unturned). Each head's
+    query and each head's key pass an RMS norm with one learned gain
+    vector, and the heads' output is multiplied by sigmoid(x Wg) before
+    the output projection. A layer with a window keeps a RING of
+    `window` rows a sequence in a serving cache, whatever the cache's
+    capacity."""
 
     n_heads: int = 8
     n_kv_heads: int = 0         # defaults to n_heads
     head_dim: int = 0           # defaults to n_out / n_heads
     window: int = 0             # 0: full attention
     rope_theta: float = 0.0     # 0: no position
+    rotary_dim: int = 0         # dimensions turned; 0: the whole head
     eps: float = 1e-5           # of the query's and the key's RMS norm
+
+    def set_n_in(self, input_type: InputType) -> None:
+        if self.n_in == 0:
+            self.n_in = input_type.flat_size()
+        if self.n_out == 0:
+            self.n_out = self.n_in
+
+
+@register_config
+@dataclasses.dataclass
+class GatedDeltaNetLayer(BaseRecurrentLayer):
+    """A causal gated delta rule with a short convolution in front, no
+    biases (nn/layers/gated_deltanet.py holds the equations): `n_v_heads`
+    value heads of `v_head_dim` each keep a state [k_head_dim,
+    v_head_dim] that a per-head decay fades and the delta rule corrects
+    toward each new value; `n_k_heads` key heads, `n_v_heads /
+    n_k_heads` value heads a key head. A depthwise causal convolution of
+    width `conv_kernel` runs over the query, key and value channels
+    first. What the layer keeps of the past is that state and the
+    convolution's last `conv_kernel - 1` inputs, of fixed size a
+    sequence (`state_dtype`; float32: it is a sum over thousands of
+    tokens): a serving step cannot be unwound from it."""
+
+    n_k_heads: int = 16
+    n_v_heads: int = 32
+    k_head_dim: int = 128
+    v_head_dim: int = 128
+    conv_kernel: int = 4
+    eps: float = 1e-6           # of the output's per-head RMS norm
+    state_dtype: str = "float32"
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:

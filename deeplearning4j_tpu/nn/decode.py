@@ -11,8 +11,8 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   expert layer), or where it carries its own cached forward
   (`apply_cached`, causal where its conf has the word: attention and
   the positional encodings, nn/layers/attention.py,
-  nn/layers/latent_attention.py, nn/layers/grouped_attention.py and
-  nn/layers/power_retention.py).
+  nn/layers/latent_attention.py, nn/layers/grouped_attention.py,
+  nn/layers/power_retention.py and nn/layers/gated_deltanet.py).
   Elementwise, merge, scale and subset vertices ride along. Anything
   else (LSTMs, convolutions over time, bidirectional attention) raises
   when the plan is built, with the layer named. This module names no
@@ -29,7 +29,8 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   codes with per-(row, page, head) float32 scales. That decision lives
   here and in ops/decode_attention.py and nowhere else. A layer whose
   entry is a STATE (a running sum a slot, not a row a token: power
-  retention) uses neither; it reads the step itself, because a sum
+  retention, the gated delta rule and its convolution's window) uses
+  neither; it reads the step itself, because a sum
   forgives nothing a row does: a row whose first position is 0 starts a
   sequence and its state is zeroed first, a token with `keep` 0 adds and
   decays nothing, a row not `live` keeps its state bit for bit. A layer

@@ -15,4 +15,5 @@ import deeplearning4j_tpu.nn.layers.latent_attention  # noqa: F401
 import deeplearning4j_tpu.nn.layers.grouped_attention  # noqa: F401
 import deeplearning4j_tpu.nn.layers.moe  # noqa: F401
 import deeplearning4j_tpu.nn.layers.power_retention  # noqa: F401
+import deeplearning4j_tpu.nn.layers.gated_deltanet  # noqa: F401
 import deeplearning4j_tpu.nn.layers.nested  # noqa: F401

@@ -84,7 +84,8 @@ class LayerImpl:
         """False where a serving step cannot be taken out of this
         layer's cache entry again (a state, a ring of rows: not rows a
         later write hides): nn/decode.make_verify_fn refuses such a net
-        (nn/layers/power_retention.py, nn/layers/grouped_attention.py)."""
+        (nn/layers/power_retention.py, nn/layers/gated_deltanet.py,
+        nn/layers/grouped_attention.py)."""
         return True
 
     def init(self, conf, rng, dtype):

@@ -11,7 +11,9 @@ h // (Hq / Hk)):
     k = Nk(u Wk)   [Hk, d]        one learned gain vector each
     v = u Wv       [Hk, d]
     g = sigmoid(u Wg)   [Hq * d]
-    q, k = rot(q, t), rot(k, t)   where `rope_theta` > 0; else NO position
+    q, k = rot(q, t), rot(k, t)   where `rope_theta` > 0; else NO position.
+                                  With `rotary_dim` r > 0 only the first r
+                                  of a head's d turn, pairs (i, i + r / 2)
     o_t = softmax_j(q_t . k_j / sqrt(d)) v_j   over the keys
           t - window < j <= t  (`window` > 0: `window` keys at most, the
           query's own among them), or every j <= t (`window` 0);
@@ -124,9 +126,18 @@ def _project(conf, params, x, positions):
     q = rms_norm(q, params["q_norm"], conf.eps)
     k = rms_norm(k, params["k_norm"], conf.eps)
     if conf.rope_theta:
-        q = rotary(q, positions, conf.rope_theta)
-        k = rotary(k, positions, conf.rope_theta)
+        q, k = (_turn(conf, a, positions) for a in (q, k))
     return q, k, v
+
+
+def _turn(conf, x, positions):
+    """Rotary position on the first `rotary_dim` dimensions of each head
+    of x [b, T, H, d] (the whole head where that is 0); the rest pass."""
+    r = conf.rotary_dim
+    if not r or r == x.shape[-1]:
+        return rotary(x, positions, conf.rope_theta)
+    return jnp.concatenate(
+        [rotary(x[..., :r], positions, conf.rope_theta), x[..., r:]], -1)
 
 
 def _output(conf, params, x, o):
@@ -212,10 +223,11 @@ class GroupedAttentionImpl(LayerImpl):
 
     def init(self, conf, rng, dtype):
         Hq, Hk, d = _sizes(conf)
-        if Hq % Hk or d % 2:
+        if Hq % Hk or d % 2 or conf.rotary_dim % 2 or conf.rotary_dim > d:
             raise ValueError(
                 f"GroupedAttentionLayer needs n_heads a multiple of "
-                f"n_kv_heads and an even head_dim; got {Hq}, {Hk}, {d}")
+                f"n_kv_heads, an even head_dim and an even rotary_dim no "
+                f"larger; got {Hq}, {Hk}, {d}, {conf.rotary_dim}")
         k = jax.random.split(rng, 5)
 
         def w(key, shape):

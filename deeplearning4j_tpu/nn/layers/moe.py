@@ -29,8 +29,9 @@ With ample capacity (capacity_factor >= E/top_k) the routed path drops
 nothing and matches the dense path to float tolerance.
 
 `DroplessMoELayer` (below) is the expert layer of the served models:
-sigmoid scores, the top k normalised and scaled, gated experts, a shared
-expert, NO dropped (token, expert) pair at any imbalance, and a
+sigmoid scores, or a softmax over the top k logits, the top k normalised
+and scaled, gated experts, a shared expert (gated, where the conf says
+so), NO dropped (token, expert) pair at any imbalance, and a
 configuration that says which of the router's experts this chip holds.
 """
 
@@ -327,8 +328,12 @@ class DroplessMoELayer(FeedForwardLayer):
         s + bsel (`bsel` [n_experts] float32: a bias that CHOOSES and
         never weighs; training moves it towards a balanced load)
         w = s_top / (sum of the top_k + 1e-20) * routed_scaling
+      or, with `router` "softmax":
+        l = x_f32 Wg_f32; the top_k are the largest of l, and
+        w = softmax over those top_k logits * routed_scaling (the
+        normalised top k of the softmax over all experts)
         y = sum over the selected experts HELD HERE of w_e E_e(x)
-            + Shared(x)
+            + Shared(x), times sigmoid(x Ws_g) with `shared_gate`
         E_e(x) = (act(x Wgate_e) * (x Wup_e)) Wdown_e,  d_hidden wide
 
     The router keeps its whole width and the weights are normalised over
@@ -348,6 +353,9 @@ class DroplessMoELayer(FeedForwardLayer):
     n_shared: int = 0
     routed_scaling: float = 1.0
     selection_bias: bool = False    # hold `bsel` and select by s + bsel
+    router: str = "sigmoid"         # "sigmoid" | "softmax"
+    shared_gate: bool = False       # hold `Ws_g` [n_in, 1]: the shared
+                                    # expert times sigmoid(x Ws_g)
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:
@@ -395,6 +403,16 @@ def route_sigmoid_topk(x2d, Wg, top_k, routed_scaling, bsel=None):
     return top_i, w * routed_scaling
 
 
+def route_softmax_topk(x2d, Wg, top_k, routed_scaling):
+    """(expert ids [N, k], weights [N, k] float32): the top k of the
+    float32 logits at full precision, weighed by a softmax over those k
+    (the top k of the softmax over every expert, normalised)."""
+    logits = jnp.matmul(x2d.astype(jnp.float32), Wg.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    top_l, top_i = jax.lax.top_k(logits, top_k)
+    return top_i, jax.nn.softmax(top_l, axis=-1) * routed_scaling
+
+
 def dropless_moe(conf, params, x2d, valid=None):
     """The layer on x2d [N, D]; `valid` [N] marks real tokens (pad rows
     and idle slots select nothing). -> (y [N, O], counts).
@@ -431,8 +449,13 @@ def dropless_moe(conf, params, x2d, valid=None):
     C = round_rows(N, conf.top_k, conf.n_experts)
     O = params["We_down"].shape[-1]
     with jax.named_scope("router"):
-        top_i, w = route_sigmoid_topk(x2d, params["Wg"], conf.top_k,
-                                      conf.routed_scaling, params.get("bsel"))
+        if conf.router == "softmax":
+            top_i, w = route_softmax_topk(x2d, params["Wg"], conf.top_k,
+                                          conf.routed_scaling)
+        else:
+            top_i, w = route_sigmoid_topk(x2d, params["Wg"], conf.top_k,
+                                          conf.routed_scaling,
+                                          params.get("bsel"))
         local = top_i - conf.first_expert                    # [N, k]
         mine = (local >= 0) & (local < held)
         if valid is not None:
@@ -469,8 +492,12 @@ def dropless_moe(conf, params, x2d, valid=None):
             jnp.zeros((N, O), jnp.float32))
     if conf.n_shared:
         with jax.named_scope("shared_expert"):
-            y = y + gated_ffn(x2d, params["Ws_gate"], params["Ws_up"],
-                              params["Ws_down"], act).astype(jnp.float32)
+            shared = gated_ffn(x2d, params["Ws_gate"], params["Ws_up"],
+                               params["Ws_down"], act).astype(jnp.float32)
+            if conf.shared_gate:
+                shared = shared * jax.nn.sigmoid(
+                    (x2d @ params["Ws_g"]).astype(jnp.float32))
+            y = y + shared
     counts = {"moe_pairs": jnp.sum(sel.astype(jnp.int32)),
               "moe_rows": rounds * (held * C),
               "moe_max_load": max_load}
@@ -499,6 +526,13 @@ class DroplessMoEImpl(LayerImpl):
             raise ValueError(
                 f"experts {conf.first_expert}..{conf.first_expert + held - 1}"
                 f" are not among the router's {conf.n_experts}")
+        if conf.router not in ("sigmoid", "softmax") or (
+                conf.router == "softmax" and conf.selection_bias):
+            raise ValueError(
+                f"router {conf.router!r}: 'sigmoid' or 'softmax', and only "
+                f"a sigmoid router takes a selection bias")
+        if conf.shared_gate and not conf.n_shared:
+            raise ValueError("a shared_gate needs a shared expert")
         k = jax.random.split(rng, 7)
 
         def w(key, shape, fan_in, fan_out):
@@ -516,6 +550,8 @@ class DroplessMoEImpl(LayerImpl):
             params.update(Ws_gate=w(k[4], (D, Fs), D, Fs),
                           Ws_up=w(k[5], (D, Fs), D, Fs),
                           Ws_down=w(k[6], (Fs, O), Fs, O))
+        if conf.shared_gate:
+            params["Ws_g"] = w(jax.random.fold_in(rng, 7), (D, 1), D, 1)
         return params, {}
 
     def apply_counted(self, conf, params, x, valid=None):

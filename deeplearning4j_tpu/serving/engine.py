@@ -768,7 +768,8 @@ class _GenWorker:
     fns — shapes still lattice/page-grid points, ~4x less HBM/slot.
 
     A NET WHOSE CACHE ENTRY IS A STATE (a running sum a slot, not a row
-    a token: nn/layers/power_retention.py) is served by the same loop
+    a token: nn/layers/power_retention.py, nn/layers/gated_deltanet.py,
+    beside full-attention rows in one net) is served by the same loop
     and the worker names no layer: the layer itself zeroes a row's state
     in the chunk that starts at position 0 (a slot's new tenant: stale
     rows hide behind a key limit, a stale sum would not), adds nothing

@@ -3,7 +3,8 @@ code depends on (graftlint G007 routes callers here), spelled for the one
 installed jax (0.9.0): `jax.shard_map` with `check_vma` / `axis_names`,
 `pltpu.CompilerParams`, `lax.pcast`; and the one test by which the
 serving kernels (ops/decode_attention, ops/power_retention,
-ops/prefill_attention) choose the kernel over their `jnp` form.
+ops/gated_delta, ops/prefill_attention) choose the kernel over their
+`jnp` form.
 """
 
 from __future__ import annotations

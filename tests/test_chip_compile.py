@@ -275,6 +275,33 @@ def test_retention_decode_kernel_writes_the_state_in_place(
     assert not re.search(rf"= f32\[{B},{Hk},{d},{D}\]\S* copy\(", text)
 
 
+def test_gated_delta_decode_kernel_writes_the_state_in_place(
+        no_persistent_cache, one_chip):
+    """ops/gated_delta.py's decode kernel at Qwen3-Next's widths (64
+    slots, 32 value heads of 128 x 128 float32, the key heads already
+    repeated to the value heads): it compiles for the chip (blocks of 8
+    heads' states, 512 KB in and out, q and k as lane rows), aliases the
+    whole donated state, 134 MB a layer, and leaves nothing of the
+    state's size beside it: no temporary, no copy, and no q or k laid
+    out a place a lane row (a [..., 128, 1] column would be padded to
+    128 lanes: 134 MB written and read again every layer)."""
+    from deeplearning4j_tpu.ops import gated_delta as gd
+
+    B, H, dk, dv = 64, 32, 128, 128
+
+    def f32(*shape):
+        return _sds(shape, jnp.float32, one_chip)
+
+    compiled = jax.jit(gd.gated_delta_decode_kernel, donate_argnums=0).lower(
+        f32(B, H, dk, dv), f32(B, H, dk), f32(B, H, dk), f32(B, H, dv),
+        f32(B, H), f32(B, H), _sds((B,), jnp.bool_, one_chip)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == {"gated_delta_decode": 1}
+    assert mem.alias_size_in_bytes == 4 * B * H * dk * dv == 134_217_728
+    assert mem.temp_size_in_bytes < 16 * 2**20, mem
+    assert not re.search(rf"= f32\[{B},{H},{dk},{dv}\]\S* copy\(", text)
+
+
 @pytest.mark.parametrize("rows", [4096, 17408], ids=["ring", "full"])
 def test_gqa_decode_kernel_reads_the_cache_where_it_lies(
         no_persistent_cache, one_chip, rows):
@@ -299,6 +326,63 @@ def test_gqa_decode_kernel_reads_the_cache_where_it_lies(
     assert da.gqa_block(rows) == 512
     assert mem.temp_size_in_bytes < 2**20, mem
     assert not re.search(rf"= bf16\[{B},{Hk},{rows},{d}\]\S* copy\(", text)
+
+
+def test_grouped_kernels_take_qwen3_nexts_full_layer(
+        no_persistent_cache, one_chip, monkeypatch):
+    """The two grouped kernels at the shape of Qwen3-Next's full layers,
+    which no other cell gives them: 16 queries on 2 key-value heads of
+    256 (8 a group), rotary over the first 64, a full entry of 36,864
+    rows over 64 slots (bfloat16, 4.8 GB a layer). The decode step takes
+    `gqa_decode` and the 1,024-token chunk `gqa_prefill`; both compile for
+    the chip and read the entry where it lies, with no copy of it."""
+    from deeplearning4j_tpu.nn.conf.layers import GroupedAttentionLayer
+    from deeplearning4j_tpu.nn.decode import CacheStep
+    from deeplearning4j_tpu.nn.layers.grouped_attention import (
+        GroupedAttentionImpl,
+    )
+    from deeplearning4j_tpu.ops import decode_attention as da
+    from deeplearning4j_tpu.ops import prefill_attention as pa
+
+    h, Hq, Hk, d, Tc, slots, cap = 2048, 16, 2, 256, 1024, 64, 36864
+    conf = GroupedAttentionLayer(n_in=h, n_out=h, n_heads=Hq, n_kv_heads=Hk,
+                                 head_dim=d, rope_theta=1e7, rotary_dim=64,
+                                 eps=1e-6)
+    impl = GroupedAttentionImpl()
+    bf16 = jnp.bfloat16
+    shapes = {"Wq": (h, Hq * d), "Wk": (h, Hk * d), "Wv": (h, Hk * d),
+              "Wg": (h, Hq * d), "Wo": (Hq * d, h), "q_norm": (d,),
+              "k_norm": (d,)}
+    params = {k: _sds(s, bf16, one_chip) for k, s in shapes.items()}
+    cache = {n: _sds((slots,) + a[0], bf16, one_chip)
+             for n, a in impl.cache_arrays(conf, cap, "f32", PAGE,
+                                           bf16).items()}
+    monkeypatch.setattr(pa, "use_kernel", lambda: True)
+    monkeypatch.setattr(da, "_use_kernel", lambda: True)
+
+    def chunk(params, x, cache, row, start, keep):
+        step = CacheStep(row, start[:, None] + jnp.arange(Tc)[None, :],
+                         keep=keep, chunk=True)
+        return impl.apply_cached(conf, params, x, cache, step)
+
+    def decode(params, x, cache, pos, live):
+        step = CacheStep(None, pos[:, None], live=live)
+        return impl.apply_cached(conf, params, x, cache, step)
+
+    one = _sds((1,), jnp.int32, one_chip)
+    for fn, args, kernel in (
+            (chunk, (params, _sds((1, Tc, h), bf16, one_chip), cache, one,
+                     one, _sds((1, Tc), jnp.float32, one_chip)),
+             "gqa_prefill"),
+            (decode, (params, _sds((slots, 1, h), bf16, one_chip), cache,
+                      _sds((slots,), jnp.int32, one_chip),
+                      _sds((slots,), jnp.bool_, one_chip)), "gqa_decode")):
+        compiled = jax.jit(fn, donate_argnums=2).lower(*args).compile()
+        text = compiled.as_text()
+        assert _kernels(text) == {kernel: 1}
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+        assert not re.search(rf"= bf16\[{slots},{Hk},{cap},{d}\]\S* copy\(",
+                             text)
 
 
 def test_prefill_flash_kernel_keeps_the_scores_off_the_memory(

@@ -40,7 +40,8 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   land on a row a tenant needs), a chunk attends the ring as it found
   it and writes after, `keep` 0 writes nothing, and the position a ring
   row holds is arithmetic on `positions`, so a new tenant needs no
-  reset; it walks its entry through ops/decode_attention.py itself.
+  reset; it walks its entry through ops/decode_attention.py itself and
+  writes a chunk's rows as one run a row (``step.write_run``).
 * **The entry functions** build the `CacheStep`, walk, and pick the
   output rows. ``make_decode_fn``: ``(params, state, cache, token,
   pos[, live]) -> (probs, cache)``, one token a cache row, positions
@@ -244,10 +245,12 @@ class CacheStep:
     a prefill chunk (many queries a row), False for a decode or verify
     step, `live` [b] bool the rows the caller says hold a request (None:
     all). `write` and `attend` are the two operations on a key-value
-    entry in the cache's stored format (`kv_dtype`, `page_size`). A
-    layer whose entry is a state reads the fields alone: `positions[:,
-    0] == 0` zeroes a row's state before anything is added, `keep` 0
-    adds and decays nothing, a row not `live` keeps its state."""
+    entry in the cache's stored format (`kv_dtype`, `page_size`);
+    `write_run` writes a chunk's rows into an entry as the one run a row
+    they are. A layer whose entry is a state reads the fields
+    alone: `positions[:, 0] == 0` zeroes a row's state before anything
+    is added, `keep` 0 adds and decays nothing, a row not `live` keeps
+    its state."""
 
     __slots__ = ("rows", "positions", "keep", "chunk", "live", "kv_dtype",
                  "page_size")
@@ -265,6 +268,64 @@ class CacheStep:
                 else self.rows)
         return _cache_write(entry, k_new, v_new, rows, self.positions,
                             self.kv_dtype, self.page_size)
+
+    def write_run(self, entry, new, keep, axis):
+        """A prefill chunk's entry {name: [B, ..., R, ...]} (R rows a
+        cache row on `axis`) with the chunk's new {name: [b, ..., T, ...]}
+        (T <= R on the same axis) written where keep [b, T] is True:
+        token t of batch row i at cache row rows[i], row (positions[i, 0]
+        + t) % R. The same rows and values as a scatter of the step's
+        positions with `keep` 0 dropped, bit for bit, but written as the
+        one run a chunk's rows are (a ring's with at most one wrap), so
+        that the cost is the run's bytes and not a serial pass over its
+        rows (`_cache_write`'s scatter).
+
+        Per batch row, with s = positions[i, 0] % R and o = max(s + T -
+        R, 0) the run's tokens past the ring's end, two blended blocks of
+        T rows an array, always both: B at 0 takes the o tokens past the
+        end, A at s - o (= min(s, R - T)), read after B is written, those
+        up to it; a run that does not wrap leaves B as it found it. The
+        rows the two write do not meet, so their order is free; B first
+        is the order in which XLA keeps a chunk program's temporaries
+        where the scatter's were. Each block is a dynamic_slice of the
+        old rows, a select with the new ones and a dynamic_update_slice
+        back, so a donated entry is written in place, and no start is
+        ever clamped (A ends at R at the most). `rows` must name rows of
+        the entry: none is dropped. The batch rows are a Python loop: a
+        vmap of per-row offsets lowers to a scatter again. Plain `lax`
+        throughout: the write is traced for every layer of every prefill
+        program a server warms."""
+        R = next(iter(entry.values())).shape[axis]
+        b, T = keep.shape
+        if T > R:
+            raise ValueError(f"a run of {T} rows does not fit {R}")
+        rows = jnp.arange(b) if self.rows is None else self.rows
+        s = jax.lax.rem(self.positions[:, 0], R)
+        o = jax.lax.max(s + (T - R), 0)
+        m = jax.lax.iota(o.dtype, T)
+
+        def rolled(x, i, dim):
+            """x along `dim` turned by o[i]: row m holds x[(m - o) % T]."""
+            return jax.lax.dynamic_slice_in_dim(
+                jax.lax.concatenate([x, x], dim), T - o[i], T, dim,
+                allow_negative_indices=False)
+
+        for i in range(b):
+            kept = rolled(keep[i], i, 0)
+            blocks = ((0, kept & (m < o[i])), (s[i] - o[i], kept & (m >= o[i])))
+            for n, a in entry.items():
+                run = rolled(new[n][i].astype(a.dtype), i, axis - 1)[None]
+                for at, mine in blocks:
+                    corner = [0] * a.ndim
+                    corner[0], corner[axis] = rows[i], at
+                    old = jax.lax.dynamic_slice(a, corner, run.shape,
+                                                allow_negative_indices=False)
+                    a = jax.lax.dynamic_update_slice(
+                        a, jax.lax.select(jax.lax.broadcast_in_dim(
+                            mine, run.shape, (axis,)), run, old),
+                        corner, allow_negative_indices=False)
+                entry = {**entry, n: a}
+        return entry
 
     def attend(self, entry, qh, key_limit, rows=None):
         """qh [b, H, Tq, D] against `entry`, query t of row i seeing the

@@ -72,10 +72,18 @@ the chunk's own keys by `masked_attention_lse`, the entry by
 `ops/decode_attention.ring_attention` a block at a time, merged by their
 log-sum-exps. `apply` and the decode step never take the kernel.
 
+HOW A CHUNK WRITES: its rows are one run of each row's entry (a ring's
+with at most one wrap), written as such by `CacheStep.write_run`: two
+blended blocks of the chunk's length a row, in place, the same rows and
+values as a scatter of the positions. A chunk longer than its entry
+(the walk's case; only its last `rows` kept tokens stay) and the decode
+step (each row at its own position) write by scatter.
+
 The layer counts, through the `counters` road of nn/decode.py:
-`attn_rows_seen`, the cache rows some query of the step could see, and
+`attn_rows_seen`, the cache rows some query of the step could see,
 `attn_wrapped`, the live rows of the step whose context is past the
-window.
+window, and `attn_write_wraps`, the rows of a chunk whose written run
+passed the end of the entry (0 in a decode step).
 """
 
 from __future__ import annotations
@@ -195,6 +203,40 @@ def chunk_walk(conf, q, k, v, k_entry, v_entry, pos, keep, rows):
     return o
 
 
+def written_rows(pos, keep, R):
+    """The entry row each token of a step writes, [b, T]: position p at
+    row p % R where `keep`, of a call longer than R only the last R kept
+    tokens; R (dropped) for the rest."""
+    last = jnp.max(jnp.where(keep, pos, -1), axis=1, keepdims=True)
+    return jnp.where(keep & (pos > last - R), pos % R, R)
+
+
+def scatter_write(entry, new, rows, at):
+    """`entry` {name: [B, Hk, R, d]} with new {name: [b, T, Hk, d]}
+    scattered at cache rows `rows` [b], every head, entry rows `at`
+    [b, T] (`written_rows`; R is dropped): the decode step's write, each
+    row at its own position, and that of a chunk longer than its
+    entry."""
+    Hk = next(iter(entry.values())).shape[1]
+    idx = (rows[:, None, None], jnp.arange(Hk)[None, :, None], at[:, None, :])
+    return {n: a.at[idx].set(new[n].transpose(0, 2, 1, 3).astype(a.dtype),
+                             mode="drop")
+            for n, a in entry.items()}
+
+
+def chunk_write(step, entry, new, rows, keep):
+    """A prefill chunk's write of new {name: [b, T, Hk, d]} at the step's
+    positions (running on from positions[:, 0]) where `keep`: the same
+    rows and values as `scatter_write`, as one run a row
+    (`CacheStep.write_run`) where the chunk fits its entry."""
+    R, T = next(iter(entry.values())).shape[2], step.positions.shape[1]
+    if T > R:
+        return scatter_write(entry, new, rows,
+                             written_rows(step.positions, keep, R))
+    return step.write_run(entry, {n: x.transpose(0, 2, 1, 3)
+                                  for n, x in new.items()}, keep, axis=2)
+
+
 def _heads_out(o, conf, dtype):
     """[b, Hk, G * T, d] -> [b, T, Hq * d]."""
     Hq, _, d = _sizes(conf)
@@ -206,7 +248,7 @@ def _heads_out(o, conf, dtype):
 @register_impl(GroupedAttentionLayer)
 class GroupedAttentionImpl(LayerImpl):
     region = "attention"
-    counters = ("attn_rows_seen", "attn_wrapped")
+    counters = ("attn_rows_seen", "attn_wrapped", "attn_write_wraps")
 
     @staticmethod
     def rewindable(conf) -> bool:
@@ -215,11 +257,12 @@ class GroupedAttentionImpl(LayerImpl):
 
     @staticmethod
     def merge_counts(counts: list) -> dict:
-        """Rows seen add up over the layers; the rows past the window
-        are the same rows in every window layer (a full layer says 0)."""
+        """Rows seen add up over the layers; the rows past the window,
+        and the rows whose write wrapped, are the same rows in every
+        window layer (a full layer says 0)."""
         return {"attn_rows_seen": sum(c["attn_rows_seen"] for c in counts),
-                "attn_wrapped": jnp.max(jnp.stack(
-                    [c["attn_wrapped"] for c in counts]))}
+                **{n: jnp.max(jnp.stack([c[n] for c in counts]))
+                   for n in ("attn_wrapped", "attn_write_wraps")}}
 
     def init(self, conf, rng, dtype):
         Hq, Hk, d = _sizes(conf)
@@ -284,22 +327,9 @@ class GroupedAttentionImpl(LayerImpl):
                 else jnp.asarray(step.live, bool))
         keep = (jnp.ones((b, T), bool) if step.keep is None
                 else step.keep > 0) & live[:, None]
-        # of a call longer than the ring, the last R kept tokens stay
-        last = jnp.max(jnp.where(keep, pos, -1), axis=1, keepdims=True)
-        at = jnp.where(keep & (pos > last - R), pos % R, R)  # R: dropped
+        at = written_rows(pos, keep, R)
         n_kept = jnp.sum(keep, axis=1)
-
-        def written():
-            idx = (rows[:, None, None], jnp.arange(Hk)[None, :, None],
-                   at[:, None, :])
-            with jax.named_scope("cache_write"):
-                return {kn: entry[kn].at[idx].set(
-                            k.transpose(0, 2, 1, 3).astype(entry[kn].dtype),
-                            mode="drop"),
-                        vn: entry[vn].at[idx].set(
-                            v.transpose(0, 2, 1, 3).astype(entry[vn].dtype),
-                            mode="drop")}
-
+        new = {kn: k, vn: v}
         if step.chunk:
             start = pos[:, 0]
             if prefill_attention.use_kernel() and \
@@ -311,22 +341,32 @@ class GroupedAttentionImpl(LayerImpl):
             else:
                 o = chunk_walk(conf, q, k, v, entry[kn], entry[vn], pos,
                                keep, rows)
-            entry = written()
+            with jax.named_scope("cache_write"):
+                entry = chunk_write(step, entry, new, rows, keep)
+            # the rows whose written run passed the entry's end: a row
+            # written below the row of the first position written
+            first = jax.lax.rem(jnp.min(jax.lax.select(
+                at < R, pos, jnp.full_like(pos, jnp.iinfo(pos.dtype).max)),
+                axis=1, keepdims=True), R)
+            wraps = jnp.sum(jnp.any(at < first, axis=1))
             prior = jnp.minimum(start, W - 1) if W else start
             seen = jnp.sum(jnp.where(n_kept > 0, prior + n_kept, 0))
             ends = start + n_kept
         elif T == 1:
-            entry = written()
+            with jax.named_scope("cache_write"):
+                entry = scatter_write(entry, new, rows, at)
             o = gqa_decode(q[:, 0], entry[kn], entry[vn], pos[:, 0], live)
             o = o.reshape(b, Hk, G, d)                      # grouped, T = 1
             ends = jnp.where(live, pos[:, 0] + 1, 0)
             seen = jnp.sum(jnp.minimum(ends, R))
+            wraps = 0
         else:
             raise ValueError(
                 "a GroupedAttentionLayer decodes one token a row a step or "
                 "prefills a chunk; a window of drafts is not served")
         counts = {"attn_rows_seen": seen.astype(jnp.int32),
                   "attn_wrapped": jnp.sum(ends > W, dtype=jnp.int32)
-                  if W else jnp.int32(0)}
+                  if W else jnp.int32(0),
+                  "attn_write_wraps": jnp.int32(wraps)}
         return (_output(conf, params, x, _heads_out(o, conf, x.dtype)),
                 entry, counts)

@@ -788,9 +788,9 @@ class _GenWorker:
     found it and write after, and needs no reset for a slot's new
     tenant (a row's position is arithmetic on the step's own, and one
     that comes out negative is masked); its steps hand home
-    `attn_rows_seen` and `attn_wrapped`. speculative_k >= 2 and
-    kv_dtype="int8" are refused for such a net when the worker is
-    built, with the layer named. `plan.describe(net)` (the `meta` event,
+    `attn_rows_seen`, `attn_wrapped` and `attn_write_wraps`.
+    speculative_k >= 2 and kv_dtype="int8" are refused for such a net
+    when the worker is built, with the layer named. `plan.describe(net)` (the `meta` event,
     /stats) says which kinds of row are rings (`windows`) and what a
     slot is allocated (`bytes_per_slot`); the page pool still counts one
     kind of page, `prompt + max_new` of them a request.
@@ -982,8 +982,8 @@ class _GenWorker:
         counters behind them (a net with counting layers) become fields
         of the step's span: `moe_pairs`, `moe_rows`, `moe_max_load` of
         an expert layer, `state_resets` of a layer that keeps a state,
-        `attn_rows_seen` and `attn_wrapped` of a grouped-attention
-        layer."""
+        `attn_rows_seen`, `attn_wrapped` and `attn_write_wraps` of a
+        grouped-attention layer."""
         n = len(self.step_counters)
         if not n:
             return fetched

@@ -100,8 +100,12 @@ a `decode_step` counts 0; read on the span that `fetched` the program).
 A net with grouped-attention layers (nn/layers/grouped_attention.py)
 puts `attn_rows_seen` (the cache rows some query of the step could see,
 over all its layers: a window layer's are the `window` newest at the
-most) and `attn_wrapped` (the live rows of the step whose context is
-past the window) on the same spans, before the `moe_*` three, and its
+most), `attn_wrapped` (the live rows of the step whose context is
+past the window) and `attn_write_wraps` (the rows of a prefill chunk
+whose written run passed the end of a layer's entry, so that its write
+took the second of its two blocks: a window layer's ring wraps, a full
+layer says 0; a decode step counts 0) on the same spans, before the
+`moe_*` three, and its
 `cache` gives `windows` ({kind of row: rows a slot} for the kinds that
 are rings: "k_win", "v_win") and `bytes_per_slot` (what a slot's cache
 is allocated: every kind of row times the positions it holds, plus the
@@ -142,7 +146,7 @@ it, `fetched` = that program's number: the one batch-boundary
 `np.asarray`, which waits for the device only as long as that earlier
 program still runs; the counters behind its tokens — `moe_pairs`,
 `moe_rows`, `moe_max_load`, `state_resets`, `attn_rows_seen`,
-`attn_wrapped` — land on the span under
+`attn_wrapped`, `attn_write_wraps` — land on the span under
 which they came home, so a step's span carries the counters of program
 `fetched`, not of `program`; a step with `ahead` false has no `fetch`
 and no counters), and after the span `emit` of that earlier program

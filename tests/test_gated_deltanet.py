@@ -653,7 +653,8 @@ def test_the_other_nets_keep_their_steps(model):
     elif model == "grouped_moe_lm":
         net = grouped_moe_lm(64, 32, 4, 2, 8, ["full_attention"] * 2, 16, 1,
                              48, 4, 2, 16, rope_theta=0.0).init(seed=3)
-        counters, arrays, extra = ("attn_rows_seen", "attn_wrapped", "moe_pairs",
+        counters, arrays, extra = ("attn_rows_seen", "attn_wrapped",
+                                   "attn_write_wraps", "moe_pairs",
                                    "moe_rows", "moe_max_load"), {"k", "v"}, 0
         assert net.conf.vertices["blk0_attn"].layer.rotary_dim == 0
     else:

@@ -326,6 +326,119 @@ def test_an_idle_rows_ring_is_bit_identical_after_a_step(W, tokens):
             assert not np.array_equal(new[0], old[0])
 
 
+# a chunk's write as one run a row against the scatter it replaced: a
+# layer of 4 queries on 2 key-value heads of 8 over 3 cache rows, a ring
+# of 24 rows (window 24) or a full entry of 40; per case the window, the
+# bucket T, and per batch row its cache row, start, real tokens and live
+RUN_CASES = {
+    "start_0": (24, 16, [(1, 0, 16, True)]),
+    "ends_at_the_ring_end": (24, 16, [(1, 8, 16, True)]),
+    "wraps": (24, 16, [(2, 20, 16, True)]),
+    "pad_tail_crosses_the_wrap": (24, 16, [(0, 14, 7, True)]),
+    "a_row_not_live": (24, 16, [(0, 20, 16, True), (2, 5, 16, False)]),
+    "full_layer": (0, 16, [(1, 20, 12, True)]),
+    "two_rows": (24, 8, [(2, 19, 8, True), (0, 3, 5, True)]),
+    "longer_than_the_ring": (24, 32, [(1, 10, 30, True)]),
+    "longer_than_the_ring_kept_from_row_0": (24, 40, [(1, 8, 40, True)]),
+}
+# the batch rows whose written run passed the entry's end
+RUN_WRAPS = {"start_0": 0, "ends_at_the_ring_end": 0, "wraps": 1,
+             "pad_tail_crosses_the_wrap": 0, "a_row_not_live": 1,
+             "full_layer": 0, "two_rows": 1, "longer_than_the_ring": 1,
+             "longer_than_the_ring_kept_from_row_0": 0}
+
+
+@pytest.mark.parametrize("case", sorted(RUN_CASES))
+def test_a_chunks_run_write_is_the_scatter_bit_for_bit(case):
+    """The layer's chunk on an entry of random rows against the scatter
+    of the same projected rows (`scatter_write` at `written_rows`, called
+    in the same program): every entry array equal bit for bit, so `keep`
+    0 and a row not live write nothing, a position lands at p % R, and
+    every old row outside the run stays. A chunk longer than its ring
+    keeps the scatter. `attn_write_wraps` counts the rows that wrapped."""
+    from deeplearning4j_tpu.nn.conf.layers import GroupedAttentionLayer
+    from deeplearning4j_tpu.nn.decode import CacheStep
+    from deeplearning4j_tpu.nn.layers import grouped_attention as ga
+
+    W_, T, batch = RUN_CASES[case]
+    conf = GroupedAttentionLayer(n_in=16, n_out=16, n_heads=4, n_kv_heads=2,
+                                 head_dim=8, window=W_, rope_theta=10000.0,
+                                 weight_init="xavier")
+    impl = ga.GroupedAttentionImpl()
+    params, _ = impl.init(conf, jax.random.PRNGKey(0), jnp.float32)
+    rng = np.random.default_rng(len(case))
+    entry = {n: jnp.asarray(rng.normal(size=(3,) + a[0]), jnp.float32)
+             for n, a in impl.cache_arrays(conf, 40, "f32", 8,
+                                           jnp.float32).items()}
+    R = next(iter(entry.values())).shape[2]
+    rows, starts, n_real, live = (np.array(c) for c in zip(*batch))
+    assert (T <= R) == (not case.startswith("longer_than_the_ring"))
+    x = jnp.asarray(rng.normal(size=(len(batch), T, 16)), jnp.float32)
+    pos = jnp.asarray(starts[:, None] + np.arange(T)[None, :], jnp.int32)
+    keep = jnp.asarray(np.arange(T)[None, :] < n_real[:, None], jnp.float32)
+
+    @jax.jit
+    def both(entry):
+        step = CacheStep(jnp.asarray(rows, jnp.int32), pos, keep=keep,
+                         chunk=True, live=jnp.asarray(live))
+        _, got, counts = impl.apply_cached(conf, params, x, entry, step)
+        _, k, v = ga._project(conf, params, x, pos)
+        kept = (keep > 0) & jnp.asarray(live)[:, None]
+        want = ga.scatter_write(entry, dict(zip(ga.entry_names(conf), (k, v))),
+                                jnp.asarray(rows, jnp.int32),
+                                ga.written_rows(pos, kept, R))
+        return got, want, counts
+
+    with jax.default_matmul_precision("highest"):
+        got, want, counts = both(entry)
+    for n in entry:
+        assert np.array_equal(np.asarray(got[n]), np.asarray(want[n])), n
+        changed = np.asarray(got[n]) != np.asarray(entry[n])
+        assert changed.any() == bool((n_real * live).any()), n
+    assert int(counts["attn_write_wraps"]) == RUN_WRAPS[case]
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_a_chunk_writes_each_entry_as_a_run_and_a_step_by_scatter(W, kind):
+    """The tiny block's prefill program scatters into no whole entry and
+    writes each by two dynamic_update_slices (the run's two blocks, one
+    chunk row); its decode step keeps one scatter an entry and writes
+    none by dynamic_update_slice."""
+    net = tiny_net(W)
+    cache = net.init_kv_cache(3, CAPACITY)
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)   # noqa: E731
+    if kind == "prefill":
+        jaxpr = jax.make_jaxpr(net.prefill_fn())(
+            net.params, net.state, cache, i32(1, 16),
+            jnp.ones((1, 16), jnp.float32), i32(1), i32(1), i32(1))
+    else:
+        jaxpr = jax.make_jaxpr(net.incremental_decode_fn())(
+            net.params, net.state, cache, i32(3), i32(3), jnp.ones(3, bool))
+    shapes = {a.shape for e in cache.values() for a in e.values()}
+    assert shapes == {(3, 2, 24, 16), (3, 2, 96, 16)}
+    takes_entry = [eqn.primitive.name for eqn in _eqns(jaxpr.jaxpr)
+                   if eqn.invars and hasattr(eqn.invars[0], "aval")
+                   and getattr(eqn.invars[0].aval, "shape", None) in shapes]
+    n_entries = sum(len(e) for e in cache.values())
+    if kind == "prefill":
+        assert "scatter" not in takes_entry
+        assert takes_entry.count("dynamic_update_slice") == 2 * n_entries
+    else:
+        assert takes_entry.count("scatter") == n_entries
+        assert "dynamic_update_slice" not in takes_entry
+
+
 def test_speculative_decoding_and_int8_are_refused_with_the_layer_named(W):
     net = tiny_net(W)
     with pytest.raises(ValueError,

@@ -36,17 +36,22 @@ from deeplearning4j_tpu.nn.layers.moe import DroplessMoELayer
 
 
 def sandwich_moe_lm(attention, vocab_size: int, d_model: int, n_layers: int,
-                    *, d_ff: int, n_dense_layers: int, n_experts: int,
-                    top_k: int, d_expert: int, n_shared: int,
-                    first_expert: int, n_held: int, routed_scaling: float,
+                    *, d_ff: int, n_dense_layers: int, n_experts: int = 0,
+                    top_k: int = 0, d_expert: int = 0, n_shared: int = 0,
+                    first_expert: int = 0, n_held: int = 0,
+                    routed_scaling: float = 1.0,
                     selection_bias: bool = False, embed_scale: float = 1.0,
-                    eps: float, seed: int, learning_rate: float, dtype: str,
+                    times: int = 0, eps: float, seed: int,
+                    learning_rate: float, dtype: str,
                     param_dtype: str) -> ComputationGraph:
     """The sandwich block around any attention: `attention(i)` gives
     layer i's attention conf (vertex `blk{i}_attn`); the feed-forward
     half, the four norms, the embedding (times `embed_scale` where that
     is not 1) and the head are this function's. `latent_moe_lm` and
-    `models.grouped_moe.grouped_moe_lm` are this with their attention."""
+    `models.grouped_moe.grouped_moe_lm` are this with their attention.
+    `times` > 0 loops the stack: the blocks and the final norm (`blk0_n1`
+    through `norm_f`) run `times` times a token with one set of weights
+    (`GraphBuilder.loop`), and the head reads the last pass."""
     g = (
         NeuralNetConfiguration.builder()
         .seed(seed)
@@ -95,6 +100,8 @@ def sandwich_moe_lm(attention, vocab_size: int, d_model: int, n_layers: int,
         n_in=d_model, n_out=vocab_size, activation="softmax",
         loss_function="mcxent", has_bias=False), norm("norm_f", prev))
     g.set_outputs("out")
+    if times:
+        g.loop("blk0_n1", "norm_f", times)
     g.set_input_types(tokens=InputType.recurrent(1))
     return ComputationGraph(g.build())
 

@@ -147,6 +147,21 @@ class UnstackVertexConf(GraphVertexConf):
 
 @serde.register_config
 @dataclasses.dataclass
+class LoopConf:
+    """A span of the graph run `times` times a forward, ONE set of
+    parameters for every pass: the vertices from `first` through `last`
+    in topological order. They read the span's own vertices and one
+    vertex outside it (the entry); pass t + 1 reads `last`'s output of
+    pass t where pass 0 read the entry, and only `last` is read after
+    the span (`loop_span` checks both)."""
+
+    first: str = ""
+    last: str = ""
+    times: int = 1
+
+
+@serde.register_config
+@dataclasses.dataclass
 class ComputationGraphConfiguration:
     """The DAG config (reference nn/conf/ComputationGraphConfiguration.java)."""
 
@@ -161,6 +176,7 @@ class ComputationGraphConfiguration:
     tbptt_fwd_length: int = 20
     tbptt_back_length: int = 20
     input_types: dict = dataclasses.field(default_factory=dict)  # {input name: InputType}
+    loop: Optional[LoopConf] = None
 
     def to_json(self) -> str:
         return serde.to_json(self)
@@ -190,6 +206,38 @@ class ComputationGraphConfiguration:
         if len(order) != len(self.vertices) + len(self.network_inputs):
             raise ValueError("Graph has a cycle or disconnected vertex inputs")
         return order
+
+
+def loop_span(g: ComputationGraphConfiguration):
+    """(entry, [vertex names of the span, in order], times) of the
+    graph's loop (`LoopConf`), or None where it has none. Raises, naming
+    the vertex, where the span reads more than one vertex outside it or
+    a vertex past it reads any of the span's but `last`."""
+    lp = g.loop
+    if lp is None:
+        return None
+    order = [n for n in g.topological_order() if n not in g.network_inputs]
+    for n in (lp.first, lp.last):
+        if n not in order:
+            raise ValueError(f"the loop names {n!r}, which the graph lacks")
+    i, j = order.index(lp.first), order.index(lp.last)
+    if j < i or int(lp.times) < 1:
+        raise ValueError(f"a loop runs {lp.first!r} through {lp.last!r} at "
+                         f"least once; got them in the other order or "
+                         f"times {lp.times}")
+    span = order[i:j + 1]
+    inside = set(span)
+    entry = sorted({s for n in span for s in g.vertex_inputs[n]} - inside)
+    if len(entry) != 1:
+        raise ValueError(f"a loop's span reads one vertex outside it; "
+                         f"{lp.first!r}..{lp.last!r} reads {entry}")
+    late = [n for n in order[j + 1:] + list(g.network_outputs)
+            if n in inside - {lp.last}
+            or set(g.vertex_inputs.get(n, ())) & (inside - {lp.last})]
+    if late:
+        raise ValueError(f"only the loop's last vertex {lp.last!r} is read "
+                         f"after the span; {late} read more of it")
+    return entry[0], span, int(lp.times)
 
 
 class GraphBuilder:
@@ -243,6 +291,12 @@ class GraphBuilder:
         self._g.tbptt_back_length = n
         return self
 
+    def loop(self, first: str, last: str, times: int) -> "GraphBuilder":
+        """Run the vertices `first` through `last` `times` times a
+        forward with one set of parameters (`LoopConf`)."""
+        self._g.loop = LoopConf(first=first, last=last, times=int(times))
+        return self
+
     def set_input_types(self, **types) -> "GraphBuilder":
         self._g.input_types.update(types)
         return self
@@ -255,6 +309,7 @@ class GraphBuilder:
             raise ValueError("Graph needs setOutputs(...)")
         if g.input_types:
             _infer_graph_shapes(g)
+        loop_span(g)
         return g
 
 

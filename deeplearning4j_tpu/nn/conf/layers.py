@@ -448,12 +448,13 @@ class GroupedAttentionLayer(BaseRecurrentLayer):
     seen by a query, its own among them (0: every earlier key);
     `rope_theta` turns queries and keys by their position (0: no
     position at all), and of a head only the first `rotary_dim`
-    dimensions where that is not 0 (the rest pass unturned). Each head's
-    query and each head's key pass an RMS norm with one learned gain
-    vector, and the heads' output is multiplied by sigmoid(x Wg) before
-    the output projection. A layer with a window keeps a RING of
-    `window` rows a sequence in a serving cache, whatever the cache's
-    capacity."""
+    dimensions where that is not 0 (the rest pass unturned). Where
+    `qk_norm`, each head's query and each head's key pass an RMS norm
+    with one learned gain vector; where `gate`, the heads' output is
+    multiplied by sigmoid(x Wg) before the output projection (with
+    both off: plain multi-head attention). A layer with a window keeps
+    a RING of `window` rows a sequence in a serving cache, whatever the
+    cache's capacity."""
 
     n_heads: int = 8
     n_kv_heads: int = 0         # defaults to n_heads
@@ -462,6 +463,8 @@ class GroupedAttentionLayer(BaseRecurrentLayer):
     rope_theta: float = 0.0     # 0: no position
     rotary_dim: int = 0         # dimensions turned; 0: the whole head
     eps: float = 1e-5           # of the query's and the key's RMS norm
+    qk_norm: bool = True        # RMS norms on each head's query and key
+    gate: bool = True           # sigmoid(x Wg) on the heads' output
 
     def set_n_in(self, input_type: InputType) -> None:
         if self.n_in == 0:

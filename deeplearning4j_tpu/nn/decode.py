@@ -42,6 +42,19 @@ The module is a plan, a walk, a `CacheStep` and three entry functions.
   row holds is arithmetic on `positions`, so a new tenant needs no
   reset; it walks its entry through ops/decode_attention.py itself and
   writes a chunk's rows as one run a row (``step.write_run``).
+* **The loop** (a graph's `LoopConf`: a span of vertices run `times`
+  times a token with one set of weights, `nn/graph.py`): the walk runs
+  the span as ONE `lax.scan` over the pass, its body traced once, the
+  span's weights loop invariants and its layers' cache entries carried
+  and written in place. Every cached layer inside the span keeps one
+  block of rows a pass: its entry has a PASS AXIS after the batch axis
+  (`cache_specs`), and the `CacheStep` it is handed says which pass it
+  is in (`pass_index`); only a layer whose impl says `passes`
+  (grouped attention) is served there. What the body's layers count is
+  counted a pass at a time and merged as if each pass were more layers;
+  the loop counts `loop_passes` itself (the passes run, summed over the
+  step's live rows). The loop's own operations lie in the region
+  `loop`, the body's layers in their own. A loop of 1 is no loop.
 * **The entry functions** build the `CacheStep`, walk, and pick the
   output rows. ``make_decode_fn``: ``(params, state, cache, token,
   pos[, live]) -> (probs, cache)``, one token a cache row, positions
@@ -118,11 +131,13 @@ class _Op:
 
 
 def _plan(net):
-    """-> (input_name, output_name, [ _Op ]) for either container,
-    validating every layer/vertex is incrementally decodable."""
+    """-> (input_name, output_name, [ _Op ], loop) for either container,
+    validating every layer/vertex is incrementally decodable; `loop` is
+    (entry, first op, last op, times) of a graph's loop of more than one
+    pass, else None."""
     from deeplearning4j_tpu.nn.graph import ComputationGraph
 
-    problems, ops = [], []
+    problems, ops, loop = [], [], None
     if isinstance(net, ComputationGraph):
         from deeplearning4j_tpu.nn.conf.graph_conf import (
             ElementWiseVertexConf,
@@ -155,6 +170,16 @@ def _plan(net):
             else:
                 problems.append(f"{name} ({type(vconf).__name__})")
         in_name, out_name = ins[0], outs[0]
+        if net.loop is not None and net.loop[2] > 1:
+            entry, span, times = net.loop
+            at = [op.name for op in ops].index(span[0])
+            loop = (entry, at, at + len(span) - 1, times)
+            problems += [f"{op.name} ({type(op.conf).__name__}: its cache "
+                         f"entry has no pass axis, so it cannot be looped)"
+                         for op in ops[at:at + len(span)]
+                         if op.kind == "layer"
+                         and hasattr(op.impl, "cache_arrays")
+                         and not getattr(op.impl, "passes", False)]
     else:
         prev = "__input__"
         for i, (name, lc, impl) in enumerate(zip(
@@ -171,7 +196,7 @@ def _plan(net):
             "map each position by itself + causal attention + positional "
             "encodings); these cannot stream one token at a time: "
             + ", ".join(problems))
-    return in_name, out_name, ops
+    return in_name, out_name, ops, loop
 
 
 def _decodable_layer(lc, impl) -> bool:
@@ -191,8 +216,21 @@ def _mark_counters(fn, plan):
     fn.counting = list(dict.fromkeys(
         op.impl for op in plan[2]
         if op.kind == "layer" and hasattr(op.impl, "counters")))
+    if plan[3] is not None:
+        fn.counting.append(_LoopCount)
     fn.counters = tuple(n for impl in fn.counting for n in impl.counters)
     return fn
+
+
+class _LoopCount:
+    """The loop's own counter on the counters road: `loop_passes`, the
+    passes a step ran, summed over its live rows."""
+
+    counters = ("loop_passes",)
+
+    @staticmethod
+    def merge_counts(counts: list) -> dict:
+        return {"loop_passes": sum(c["loop_passes"] for c in counts)}
 
 
 def cache_specs(net, capacity: int, kv_dtype: str = "f32",
@@ -203,12 +241,15 @@ def cache_specs(net, capacity: int, kv_dtype: str = "f32",
     what the serving allocator bills (serving/kvcache.bytes_per_slot).
     An array that is no row a position (a state) carries a third entry,
     "slot"; an array of rows that holds fewer positions than the
-    capacity (a ring) carries their number."""
+    capacity (a ring) carries their number. A layer inside a loop keeps
+    one block of its arrays a pass: a pass axis of `times` first."""
     if kv_dtype == "int8" and capacity % page_size != 0:
         raise ValueError(
             f"int8 cache needs page-quantized capacity; {capacity} "
             f"is not a multiple of page_size {page_size}")
-    _, _, ops = _plan(net)
+    _, _, ops, loop = _plan(net)
+    looped = (set() if loop is None else
+              {op.name for op in ops[loop[1]:loop[2] + 1]})
 
     def arrays_of(op):
         try:
@@ -218,7 +259,11 @@ def cache_specs(net, capacity: int, kv_dtype: str = "f32",
             raise ValueError(
                 f"{op.name} ({type(op.conf).__name__}): {e}") from None
 
-    return {op.name: {arr: (tuple(shape), jnp.dtype(dt).name, *per)
+    def passes(op):
+        return (loop[3],) if op.name in looped else ()
+
+    return {op.name: {arr: (passes(op) + tuple(shape), jnp.dtype(dt).name,
+                            *per)
                       for arr, (shape, dt, *per) in arrays_of(op).items()}
             for op in ops
             if op.kind == "layer" and hasattr(op.impl, "cache_arrays")}
@@ -250,16 +295,26 @@ class CacheStep:
     they are. A layer whose entry is a state reads the fields
     alone: `positions[:, 0] == 0` zeroes a row's state before anything
     is added, `keep` 0 adds and decays nothing, a row not `live` keeps
-    its state."""
+    its state. Inside a loop (`in_pass`) `pass_index`, a traced scalar,
+    names the pass, and the layer's entry has a pass axis after the batch
+    axis; outside one it is None, and the entry has none."""
 
     __slots__ = ("rows", "positions", "keep", "chunk", "live", "kv_dtype",
-                 "page_size")
+                 "page_size", "pass_index")
 
     def __init__(self, rows, positions, keep=None, chunk=False, live=None,
                  kv_dtype="f32", page_size=16):
         self.rows, self.positions = rows, positions
         self.keep, self.chunk, self.live = keep, chunk, live
         self.kv_dtype, self.page_size = kv_dtype, page_size
+        self.pass_index = None
+
+    def in_pass(self, t):
+        """This step as a layer inside a loop sees it in pass `t`."""
+        step = CacheStep(self.rows, self.positions, self.keep, self.chunk,
+                         self.live, self.kv_dtype, self.page_size)
+        step.pass_index = t
+        return step
 
     def write(self, entry, k_new, v_new):
         """`entry` with k_new/v_new [b, T, H, D] written at the step's
@@ -294,8 +349,10 @@ class CacheStep:
         the entry: none is dropped. The batch rows are a Python loop: a
         vmap of per-row offsets lowers to a scatter again. Plain `lax`
         throughout: the write is traced for every layer of every prefill
-        program a server warms."""
+        program a server warms. Inside a loop the entry's axis 1 is the
+        pass axis, which `new` lacks: the run lands in the step's pass."""
         R = next(iter(entry.values())).shape[axis]
+        lead = 1 if self.pass_index is None else 2  # axes `new` lacks
         b, T = keep.shape
         if T > R:
             raise ValueError(f"a run of {T} rows does not fit {R}")
@@ -314,10 +371,14 @@ class CacheStep:
             kept = rolled(keep[i], i, 0)
             blocks = ((0, kept & (m < o[i])), (s[i] - o[i], kept & (m >= o[i])))
             for n, a in entry.items():
-                run = rolled(new[n][i].astype(a.dtype), i, axis - 1)[None]
+                run = jax.lax.expand_dims(
+                    rolled(new[n][i].astype(a.dtype), i, axis - lead),
+                    tuple(range(lead)))
                 for at, mine in blocks:
                     corner = [0] * a.ndim
                     corner[0], corner[axis] = rows[i], at
+                    if self.pass_index is not None:
+                        corner[1] = self.pass_index
                     old = jax.lax.dynamic_slice(a, corner, run.shape,
                                                 allow_negative_indices=False)
                     a = jax.lax.dynamic_update_slice(
@@ -381,26 +442,71 @@ def _walk(net, plan, params, state, cache, x0, step, valid):
     (`valid`); the counters come back as (impl, counts) pairs. Each
     layer runs under its impl's region (`region_scope`: a named scope of
     the compiled program), a vertex under the region of its latest
-    input (`input_region`). Mirrors the containers' _forward dtype
-    policy: float inputs and per-layer params cast to the compute dtype
-    where the two differ."""
-    in_name, out_name, ops = plan
+    input (`input_region`). A loop's span runs in `_loop`. Mirrors the
+    containers' _forward dtype policy: float inputs and per-layer params
+    cast to the compute dtype where the two differ."""
+    in_name, out_name, ops, loop = plan
     cache, counts = dict(cache), []
     x0 = jnp.asarray(x0)
     if jnp.issubdtype(x0.dtype, jnp.floating):
         x0 = x0.astype(net.compute_dtype)
     acts, regions = {in_name: x0}, {}
-    for at, op in enumerate(ops):
-        inputs = [acts[i] for i in op.inputs]
-        region = (op.impl.region if op.kind == "layer"
-                  else input_region(op.inputs, regions))
-        regions[op.name] = (at, region)
-        with region_scope(region):
-            acts[op.name] = (_layer(net, op, params, state, cache, counts,
-                                    inputs[0], step, valid)
-                             if op.kind == "layer"
-                             else _vertex(op.conf, inputs))
+
+    def run(first, last, acts, cache, counts, step):
+        for at in range(first, last):
+            op = ops[at]
+            inputs = [acts[i] for i in op.inputs]
+            region = (op.impl.region if op.kind == "layer"
+                      else input_region(op.inputs, regions))
+            regions[op.name] = (at, region)
+            with region_scope(region):
+                acts[op.name] = (_layer(net, op, params, state, cache,
+                                        counts, inputs[0], step, valid)
+                                 if op.kind == "layer"
+                                 else _vertex(op.conf, inputs))
+
+    if loop is None:
+        run(0, len(ops), acts, cache, counts, step)
+    else:
+        _entry, first, last, _times = loop
+        run(0, first, acts, cache, counts, step)
+        with region_scope("loop"):
+            _loop(ops, run, loop, acts, cache, counts, step, valid)
+        run(last + 1, len(ops), acts, cache, counts, step)
     return _as_seq(acts[out_name]), cache, counts
+
+
+def _loop(ops, run, loop, acts, cache, counts, step, valid):
+    """The loop's span (`_plan`'s `loop`: entry, first and last op,
+    times) as ONE `lax.scan` over the pass, its body traced once: pass t
+    reads the last op's output of pass t - 1 (pass 0 the entry), the
+    span's cache entries are carried (each with its pass axis; a layer
+    writes its own pass's rows in place), and what the body counts comes
+    out a pass at a time, into `counts` as if each pass were more layers
+    of the walk, beside the loop's own `loop_passes`."""
+    entry, first, last, times = loop
+    held = [op.name for op in ops[first:last + 1] if op.name in cache]
+    kinds = []
+
+    def body(carry, t):
+        x, mine = carry
+        inner, c, n = dict(acts, **{entry: x}), dict(cache, **mine), []
+        run(first, last + 1, inner, c, n, step.in_pass(t))
+        kinds[:] = [impl for impl, _c in n]
+        return ((inner[ops[last].name], {k: c[k] for k in held}),
+                [c_ for _impl, c_ in n])
+
+    (x, mine), per_pass = jax.lax.scan(
+        body, (acts[entry], {k: cache[k] for k in held}),
+        jnp.arange(times, dtype=jnp.int32))
+    acts[ops[last].name] = x
+    cache.update(mine)
+    counts.extend((impl, jax.tree.map(lambda a, t=t: a[t], c))
+                  for t in range(times) for impl, c in zip(kinds, per_pass))
+    rows = (x.shape[0] if valid is None
+            else jnp.sum(jnp.any(valid, axis=1), dtype=jnp.int32))
+    counts.append((_LoopCount,
+                   {"loop_passes": jnp.asarray(times * rows, jnp.int32)}))
 
 
 def _layer(net, op, params, state, cache, counts, x, step, valid):

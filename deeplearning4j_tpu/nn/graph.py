@@ -32,6 +32,7 @@ from deeplearning4j_tpu.nn.conf.graph_conf import (
     StackVertexConf,
     SubsetVertexConf,
     UnstackVertexConf,
+    loop_span,
 )
 from deeplearning4j_tpu.nn.conf.enums import BackpropType, OptimizationAlgorithm
 from deeplearning4j_tpu.nn.conf.layers import (
@@ -53,6 +54,8 @@ class ComputationGraph:
     def __init__(self, conf: ComputationGraphConfiguration):
         self.conf = conf
         self.topo = conf.topological_order()
+        # (entry, span, times) of the graph's loop, or None
+        self.loop = loop_span(conf)
         self.layer_vertices = {
             name: v for name, v in conf.vertices.items() if isinstance(v, LayerVertexConf)
         }
@@ -215,6 +218,13 @@ class ComputationGraph:
 
     def _forward(self, params, state, input_dict, *, train, rng, masks=None,
                  collect=False, carries=None):
+        """The topo-order forward. A loop (`LoopConf`) runs its span
+        `times` times in one `lax.fori_loop`, its body traced once, each
+        vertex with its one set of parameters; pass t + 1 reads the last
+        vertex's output of pass t where pass 0 read the entry, a layer's
+        state is carried from pass to pass, and of the span only the last
+        vertex's activation is left in `acts`. A loop of 1 is the
+        unlooped forward."""
         masks = dict(masks) if masks else {}
         acts = {}
         cdtype = self.compute_dtype
@@ -229,7 +239,12 @@ class ComputationGraph:
         rngs = (jax.random.split(rng, max(len(names), 1)) if rng is not None
                 else [None] * len(names))
         regions = {}
-        for at, (name, k) in enumerate(zip(names, rngs)):
+        loop = self.loop
+        looped = () if loop is None or loop[2] == 1 else loop[1]
+        if looped and carries is not None:
+            raise ValueError("a looped graph keeps no recurrent carries")
+
+        def visit(at, name, k, acts, state_in, new_state):
             vconf = self.conf.vertices[name]
             inputs = [acts[i] for i in self.conf.vertex_inputs[name]]
             # each layer's ops (and, through transpose(jvp(...)), its
@@ -270,7 +285,7 @@ class ComputationGraph:
                         # container has the same per-layer policy at
                         # multilayer.py:169)
                         run = jax.checkpoint(run)
-                    out = run(p, state.get(name, {}), x)
+                    out = run(p, state_in.get(name, {}), x)
                     if want_carry:
                         y, s, carry = out
                         new_carries[name] = carry
@@ -297,6 +312,36 @@ class ComputationGraph:
                     and y_out.shape[1] == m.shape[1]
                     and self._time_preserving(vconf, m.shape[1])):
                 masks[name] = m
+
+        def run_loop():
+            entry, span, times = loop
+            first = names.index(span[0])
+            held = [n for n in span if n in self.layer_vertices]
+
+            def body(t, carry):
+                x, st = carry
+                inner = dict(acts, **{entry: x})
+                out_state = {}
+                for at in range(first, first + len(span)):
+                    k = rngs[at]
+                    if k is not None:
+                        k = jax.random.fold_in(k, t)
+                    visit(at, names[at], k, inner, st, out_state)
+                return inner[span[-1]], {n: out_state[n] for n in held}
+
+            with region_scope("loop"):
+                x, st = jax.lax.fori_loop(
+                    0, times, body,
+                    (acts[entry], {n: state.get(n, {}) for n in held}))
+            acts[span[-1]] = x
+            new_state.update(st)
+
+        for at, (name, k) in enumerate(zip(names, rngs)):
+            if name in looped:
+                if name == looped[0]:
+                    run_loop()
+                continue
+            visit(at, name, k, acts, state, new_state)
         for n in self.layer_vertices:
             new_state.setdefault(n, state.get(n, {}))
         if collect:

@@ -8,9 +8,9 @@ and Hk key-value heads of d; query head h reads key-value head
 h // (Hq / Hk)):
 
     q = Nq(u Wq)   [Hq, d]        Nq, Nk: RMS norm over the d of a head,
-    k = Nk(u Wk)   [Hk, d]        one learned gain vector each
-    v = u Wv       [Hk, d]
-    g = sigmoid(u Wg)   [Hq * d]
+    k = Nk(u Wk)   [Hk, d]        one learned gain vector each (where
+    v = u Wv       [Hk, d]        `qk_norm`; else none)
+    g = sigmoid(u Wg)   [Hq * d]  (where `gate`; else g = 1)
     q, k = rot(q, t), rot(k, t)   where `rope_theta` > 0; else NO position.
                                   With `rotary_dim` r > 0 only the first r
                                   of a head's d turn, pairs (i, i + r / 2)
@@ -79,6 +79,14 @@ values as a scatter of the positions. A chunk longer than its entry
 (the walk's case; only its last `rows` kept tokens stay) and the decode
 step (each row at its own position) write by scatter.
 
+INSIDE A LOOP (a span of the graph run several times a token with one
+set of weights, nn/decode.py's walk): the entry has a PASS axis after
+the batch axis, [B, P, Hk, rows, d], one block of rows a pass, and the
+step's `pass_index` says which. The decode write, a chunk's run and both
+kernels (`gqa_decode`, `gqa_prefill`, told the pass as a scalar) read
+and write that pass's rows where they lie; the `jnp` forms take the
+pass's rows out first.
+
 The layer counts, through the `counters` road of nn/decode.py:
 `attn_rows_seen`, the cache rows some query of the step could see,
 `attn_wrapped`, the live rows of the step whose context is past the
@@ -105,6 +113,7 @@ from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.ops.decode_attention import (
     gqa_decode,
     group_queries,
+    pass_rows,
     ring_attention,
     ungroup_queries,
 )
@@ -131,8 +140,9 @@ def _project(conf, params, x, positions):
     q = (x @ params["Wq"]).reshape(b, T, Hq, d)
     k = (x @ params["Wk"]).reshape(b, T, Hk, d)
     v = (x @ params["Wv"]).reshape(b, T, Hk, d)
-    q = rms_norm(q, params["q_norm"], conf.eps)
-    k = rms_norm(k, params["k_norm"], conf.eps)
+    if conf.qk_norm:
+        q = rms_norm(q, params["q_norm"], conf.eps)
+        k = rms_norm(k, params["k_norm"], conf.eps)
     if conf.rope_theta:
         q, k = (_turn(conf, a, positions) for a in (q, k))
     return q, k, v
@@ -150,8 +160,9 @@ def _turn(conf, x, positions):
 
 def _output(conf, params, x, o):
     """o [b, T, Hq * d], the heads' outputs side by side -> [b, T, n_out]."""
-    gate = jax.nn.sigmoid((x @ params["Wg"]).astype(jnp.float32))
-    o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+    if conf.gate:
+        gate = jax.nn.sigmoid((x @ params["Wg"]).astype(jnp.float32))
+        o = (o.astype(jnp.float32) * gate).astype(o.dtype)
     return get_activation(conf.activation or "identity")(o @ params["Wo"])
 
 
@@ -211,14 +222,17 @@ def written_rows(pos, keep, R):
     return jnp.where(keep & (pos > last - R), pos % R, R)
 
 
-def scatter_write(entry, new, rows, at):
+def scatter_write(entry, new, rows, at, pass_index=None):
     """`entry` {name: [B, Hk, R, d]} with new {name: [b, T, Hk, d]}
     scattered at cache rows `rows` [b], every head, entry rows `at`
     [b, T] (`written_rows`; R is dropped): the decode step's write, each
     row at its own position, and that of a chunk longer than its
-    entry."""
-    Hk = next(iter(entry.values())).shape[1]
+    entry. With `pass_index`, the entry is [B, P, Hk, R, d] and the rows
+    land in that pass's."""
+    Hk = next(iter(entry.values())).shape[-3]
     idx = (rows[:, None, None], jnp.arange(Hk)[None, :, None], at[:, None, :])
+    if pass_index is not None:
+        idx = idx[:1] + (pass_index,) + idx[1:]
     return {n: a.at[idx].set(new[n].transpose(0, 2, 1, 3).astype(a.dtype),
                              mode="drop")
             for n, a in entry.items()}
@@ -228,13 +242,17 @@ def chunk_write(step, entry, new, rows, keep):
     """A prefill chunk's write of new {name: [b, T, Hk, d]} at the step's
     positions (running on from positions[:, 0]) where `keep`: the same
     rows and values as `scatter_write`, as one run a row
-    (`CacheStep.write_run`) where the chunk fits its entry."""
-    R, T = next(iter(entry.values())).shape[2], step.positions.shape[1]
+    (`CacheStep.write_run`) where the chunk fits its entry; inside a
+    loop, into the step's pass."""
+    a = next(iter(entry.values()))
+    R, T = a.shape[-2], step.positions.shape[1]
     if T > R:
         return scatter_write(entry, new, rows,
-                             written_rows(step.positions, keep, R))
+                             written_rows(step.positions, keep, R),
+                             step.pass_index)
     return step.write_run(entry, {n: x.transpose(0, 2, 1, 3)
-                                  for n, x in new.items()}, keep, axis=2)
+                                  for n, x in new.items()}, keep,
+                          axis=a.ndim - 2)
 
 
 def _heads_out(o, conf, dtype):
@@ -249,6 +267,8 @@ def _heads_out(o, conf, dtype):
 class GroupedAttentionImpl(LayerImpl):
     region = "attention"
     counters = ("attn_rows_seen", "attn_wrapped", "attn_write_wraps")
+    # served inside a loop: its entry takes a pass axis (module docstring)
+    passes = True
 
     @staticmethod
     def rewindable(conf) -> bool:
@@ -276,13 +296,16 @@ class GroupedAttentionImpl(LayerImpl):
         def w(key, shape):
             return init_weights(key, shape, conf.weight_init, conf.dist, dtype)
 
-        return {"Wq": w(k[0], (conf.n_in, Hq * d)),
-                "Wk": w(k[1], (conf.n_in, Hk * d)),
-                "Wv": w(k[2], (conf.n_in, Hk * d)),
-                "Wg": w(k[4], (conf.n_in, Hq * d)),
-                "Wo": w(k[3], (Hq * d, conf.n_out)),
-                "q_norm": jnp.ones((d,), dtype),
-                "k_norm": jnp.ones((d,), dtype)}, {}
+        params = {"Wq": w(k[0], (conf.n_in, Hq * d)),
+                  "Wk": w(k[1], (conf.n_in, Hk * d)),
+                  "Wv": w(k[2], (conf.n_in, Hk * d)),
+                  "Wo": w(k[3], (Hq * d, conf.n_out))}
+        if conf.gate:
+            params["Wg"] = w(k[4], (conf.n_in, Hq * d))
+        if conf.qk_norm:
+            params["q_norm"] = jnp.ones((d,), dtype)
+            params["k_norm"] = jnp.ones((d,), dtype)
+        return params, {}
 
     def apply(self, conf, params, state, x, *, train=False, rng=None,
               mask=None):
@@ -319,7 +342,8 @@ class GroupedAttentionImpl(LayerImpl):
         Hq, Hk, d = _sizes(conf)
         G, W = Hq // Hk, conf.window
         kn, vn = entry_names(conf)
-        R = entry[kn].shape[2]
+        R = entry[kn].shape[-2]
+        p = step.pass_index
         pos = step.positions
         q, k, v = _project(conf, params, x, pos)
         rows = jnp.arange(b) if step.rows is None else step.rows
@@ -337,10 +361,11 @@ class GroupedAttentionImpl(LayerImpl):
                 o = prefill_attention.gqa_prefill(
                     group_queries(q.transpose(0, 2, 1, 3), Hk), entry[kn],
                     entry[vn], k.transpose(0, 2, 1, 3),
-                    v.transpose(0, 2, 1, 3), keep, rows, start, window=W)
+                    v.transpose(0, 2, 1, 3), keep, rows, start, window=W,
+                    pass_index=p)
             else:
-                o = chunk_walk(conf, q, k, v, entry[kn], entry[vn], pos,
-                               keep, rows)
+                o = chunk_walk(conf, q, k, v, pass_rows(entry[kn], p),
+                               pass_rows(entry[vn], p), pos, keep, rows)
             with jax.named_scope("cache_write"):
                 entry = chunk_write(step, entry, new, rows, keep)
             # the rows whose written run passed the entry's end: a row
@@ -354,8 +379,8 @@ class GroupedAttentionImpl(LayerImpl):
             ends = start + n_kept
         elif T == 1:
             with jax.named_scope("cache_write"):
-                entry = scatter_write(entry, new, rows, at)
-            o = gqa_decode(q[:, 0], entry[kn], entry[vn], pos[:, 0], live)
+                entry = scatter_write(entry, new, rows, at, p)
+            o = gqa_decode(q[:, 0], entry[kn], entry[vn], pos[:, 0], live, p)
             o = o.reshape(b, Hk, G, d)                      # grouped, T = 1
             ends = jnp.where(live, pos[:, 0] + 1, 0)
             seen = jnp.sum(jnp.minimum(ends, R))

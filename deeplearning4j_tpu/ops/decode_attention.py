@@ -400,9 +400,19 @@ def ring_attention(q, k, v, key_limit, head, rows=None, key_floor=None):
                                    gqa_block(k.shape[2]), rows, key_floor)
 
 
-def gqa_decode_jnp(q, k, v, pos, live=None):
+def pass_rows(x, pass_index):
+    """An entry's rows of one pass: x [B, P, H, S, D] at `pass_index`
+    -> [B, H, S, D] (a slice: the `jnp` twins' form; the kernels read
+    the pass where it lies). x itself where `pass_index` is None."""
+    if pass_index is None:
+        return x
+    return jax.lax.dynamic_index_in_dim(x, pass_index, 1, keepdims=False)
+
+
+def gqa_decode_jnp(q, k, v, pos, live=None, pass_index=None):
     """`gqa_decode` in plain `jnp`: the ring walk at one query a row."""
     B, Hq, D = q.shape
+    k, v = pass_rows(k, pass_index), pass_rows(v, pass_index)
     Hk = k.shape[1]
     limit = pos + 1
     if live is not None:
@@ -457,11 +467,14 @@ def _gqa_kernel(nblk_ref, at_ref, wrapped_ref, q_ref, k_ref, v_ref, o_ref,
                              0.0).astype(o_ref.dtype)
 
 
-def gqa_decode_kernel(q, k, v, pos, live=None, *, interpret=False,
-                      block_k=GQA_BLOCK_K):
-    """`gqa_decode_jnp` as one Pallas kernel (module docstring)."""
+def gqa_decode_kernel(q, k, v, pos, live=None, pass_index=None, *,
+                      interpret=False, block_k=GQA_BLOCK_K):
+    """`gqa_decode_jnp` as one Pallas kernel (module docstring). With
+    `pass_index`, k and v are [B, P, Hk, R, D] and the pass is one more
+    prefetched scalar: the index maps read that pass's block of the
+    whole entry where it lies."""
     B, Hq, D = q.shape
-    Hk, R = k.shape[1], k.shape[2]
+    Hk, R = k.shape[-3], k.shape[-2]
     G = Hq // Hk
     G8 = -(-G // 8) * 8
     bk = gqa_block(R, block_k)
@@ -476,22 +489,35 @@ def gqa_decode_kernel(q, k, v, pos, live=None, *, interpret=False,
         qg = jnp.concatenate(
             [qg, jnp.zeros((B, Hk, G8 - G, D), q.dtype)], axis=2)
 
-    def whole(b, j, nblk, at, wrapped):
+    def whole(b, j, nblk, *refs):
         return b, 0, 0, 0
 
-    def block(b, j, nblk, at, wrapped):
+    def last_live(b, j, nblk):
         # past a slot's last live block the index repeats: no new copy
-        return b, 0, jnp.maximum(jnp.minimum(j, nblk[b] - 1), 0), 0
+        return jnp.maximum(jnp.minimum(j, nblk[b] - 1), 0)
+
+    kernel = functools.partial(_gqa_kernel, block_k=bk, scale=1.0 / D ** 0.5)
+    scalars = [nblk, pos % R, (pos >= R).astype(jnp.int32)]
+    if pass_index is None:
+        rows_spec = pl.BlockSpec(
+            (1, Hk, bk, D),
+            lambda b, j, nblk, at, wrapped: (b, 0, last_live(b, j, nblk), 0))
+    else:
+        rows_spec = pl.BlockSpec(
+            (1, pl.squeezed, Hk, bk, D),
+            lambda b, j, nblk, at, wrapped, p: (b, p[0], 0,
+                                                last_live(b, j, nblk), 0))
+        scalars.append(jnp.reshape(pass_index, (1,)).astype(jnp.int32))
+        kernel = without_pass(kernel, 3)
 
     itemsize = jnp.dtype(k.dtype).itemsize
     out = pl.pallas_call(
-        functools.partial(_gqa_kernel, block_k=bk, scale=1.0 / D ** 0.5),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(B, n_blocks),
-            in_specs=[pl.BlockSpec((1, Hk, G8, D), whole),
-                      pl.BlockSpec((1, Hk, bk, D), block),
-                      pl.BlockSpec((1, Hk, bk, D), block)],
+            in_specs=[pl.BlockSpec((1, Hk, G8, D), whole), rows_spec,
+                      rows_spec],
             out_specs=pl.BlockSpec((1, Hk, G8, D), whole),
             scratch_shapes=[pltpu.VMEM((Hk, G8, 1), jnp.float32),
                             pltpu.VMEM((Hk, G8, 1), jnp.float32),
@@ -504,17 +530,27 @@ def gqa_decode_kernel(q, k, v, pos, live=None, *, interpret=False,
             bytes_accessed=2 * B * Hk * R * D * itemsize),
         name="gqa_decode",
         interpret=interpret,
-    )(nblk, pos % R, (pos >= R).astype(jnp.int32), qg, k, v)
+    )(*scalars, qg, k, v)
     return out[:, :, :G].reshape(B, Hq, D)
 
 
-def gqa_decode(q, k, v, pos, live=None):
+def without_pass(kernel, at: int):
+    """`kernel` behind one more prefetched scalar, the pass, which only
+    the index maps read: its ref (the `at`-th argument) is dropped."""
+    def body(*refs):
+        return kernel(*refs[:at], *refs[at + 1:])
+    return body
+
+
+def gqa_decode(q, k, v, pos, live=None, pass_index=None):
     """One new token a cache row: q [B, Hq, D] at position pos [B]
     against the entry k, v [B, Hk, R, D] read as a ring of R rows, the
     token's own row already written at pos % R; query head h reads
     key-value head h // (Hq / Hk). A row not `live` [B] sees nothing and
-    gets zeros. The kernel on a TPU, its `jnp` twin elsewhere. ->
+    gets zeros. With `pass_index` (a scalar: the pass of a loop, the
+    walk's `CacheStep`), the entry is [B, P, Hk, R, D] and that pass's
+    rows are read. The kernel on a TPU, its `jnp` twin elsewhere. ->
     [B, Hq, D] in q.dtype."""
     if _use_kernel():
-        return gqa_decode_kernel(q, k, v, pos, live)
-    return gqa_decode_jnp(q, k, v, pos, live)
+        return gqa_decode_kernel(q, k, v, pos, live, pass_index)
+    return gqa_decode_jnp(q, k, v, pos, live, pass_index)

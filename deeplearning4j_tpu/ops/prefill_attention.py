@@ -45,7 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops import autotune
-from deeplearning4j_tpu.ops.decode_attention import gqa_block
+from deeplearning4j_tpu.ops.decode_attention import gqa_block, without_pass
 from deeplearning4j_tpu.util.compat import on_tpu as use_kernel  # noqa: F401
 from deeplearning4j_tpu.util.compat import tpu_compiler_params
 
@@ -432,7 +432,7 @@ def _gqa_kernel(rows_ref, start_ref, blk_ref, newest_ref, oldest_ref,
 
 
 def gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window: int,
-                interpret=False):
+                pass_index=None, interpret=False):
     """A grouped prefill chunk's attention (the section above): qg [b,
     Hk, G * T, d] the chunk's queries grouped as `ops/decode_attention.
     group_queries` groups them (rotated and normed) at positions start ..
@@ -440,8 +440,11 @@ def gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window: int,
     R, d] the layer's entry as the chunk found it (a ring of R rows where
     `window` > 0); k_own, v_own [b, Hk, T, d] the chunk's own keys and
     values, `keep` [b, T] 0 hiding a key (a bucket's pad). T <= R
-    (`gqa_prefill_fits`). -> [b, Hk, G * T, d] in qg's dtype."""
-    T, R = k_own.shape[2], k.shape[2]
+    (`gqa_prefill_fits`). With `pass_index` (a scalar: the pass of a
+    loop), k and v are [B, P, Hk, R, d] and the pass is one more
+    prefetched scalar: the index maps read that pass's blocks where they
+    lie. -> [b, Hk, G * T, d] in qg's dtype."""
+    T, R = k_own.shape[2], k.shape[-2]
     if T > R:
         raise ValueError(f"gqa_prefill takes a chunk no longer than its "
                          f"entry; got {T} queries over {R} rows")
@@ -451,7 +454,7 @@ def gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window: int,
         bk=gqa_block(R, autotune.DEFAULT_GQA_PREFILL_BLOCK_K),
         bo=_own_block(T, autotune.DEFAULT_GQA_PREFILL_BLOCK_K),
         sub=gqa_block(bq, autotune.DEFAULT_GQA_PREFILL_SUB_ROWS),
-        interpret=interpret)
+        interpret=interpret, pass_index=pass_index)
 
 
 # jitted, as `_prefill_flash`: the layers of a program that share a
@@ -459,9 +462,9 @@ def gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window: int,
 @functools.partial(jax.jit, static_argnames=("window", "bq", "bk", "bo",
                                              "sub", "interpret"))
 def _gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window, bq,
-                 bk, bo, sub, interpret):
+                 bk, bo, sub, interpret, pass_index=None):
     b, Hk, GT, d = qg.shape
-    T, R = k_own.shape[2], k.shape[2]
+    T, R = k_own.shape[2], k.shape[-2]
     G, n_ring, n_own = GT // T, R // bk, T // bo
     dtype = k.dtype
     nq = T // bq
@@ -471,11 +474,17 @@ def _gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window, bq,
     def query(i, c, qb, s, *refs):
         return i, c, 0, qb, 0
 
-    def ring(i, c, qb, s, rows, start, blk, *refs):
+    def ring_block(i, qb, s, blk):
         # past the last block the query block sees, the index repeats
         # that block's: no new copy
-        return rows[i], c, blk[(i * nq + qb) * n_ring
-                               + jnp.minimum(s, n_ring - 1)], 0
+        return blk[(i * nq + qb) * n_ring + jnp.minimum(s, n_ring - 1)]
+
+    def ring(i, c, qb, s, rows, start, blk, *refs):
+        return rows[i], c, ring_block(i, qb, s, blk), 0
+
+    def ring_of_pass(i, c, qb, s, rows, start, blk, *refs):
+        # the pass is the last prefetched scalar
+        return rows[i], refs[-1][0], c, ring_block(i, qb, s, blk), 0
 
     def own_index(s, qb):
         # `lax.div` of non-negative numbers: a floor division would cost
@@ -488,17 +497,23 @@ def _gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window, bq,
     def own_keep(i, c, qb, s, *refs):
         return i, 0, own_index(s, qb)
 
+    kernel = functools.partial(_gqa_kernel, R=R, window=window, bq=bq,
+                               bk=bk, bo=bo, sub=sub, scale=1.0 / d ** 0.5)
+    scalars = [rows.astype(jnp.int32), start.astype(jnp.int32), *plan]
+    ring_spec = pl.BlockSpec((1, 1, bk, d), ring)
+    if pass_index is not None:
+        ring_spec = pl.BlockSpec((1, pl.squeezed, 1, bk, d), ring_of_pass)
+        kernel = without_pass(kernel, len(scalars))
+        scalars.append(jnp.reshape(pass_index, (1,)).astype(jnp.int32))
     itemsize = jnp.dtype(dtype).itemsize
     pairs = b * GT * (R + T) // 2          # about half of every pair
     out = pl.pallas_call(
-        functools.partial(_gqa_kernel, R=R, window=window, bq=bq, bk=bk,
-                          bo=bo, sub=sub, scale=1.0 / d ** 0.5),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2 + len(plan),
+            num_scalar_prefetch=len(scalars),
             grid=(b, Hk, nq, n_ring + n_own),
-            in_specs=[pl.BlockSpec((1, 1, G, bq, d), query),
-                      pl.BlockSpec((1, 1, bk, d), ring),
-                      pl.BlockSpec((1, 1, bk, d), ring),
+            in_specs=[pl.BlockSpec((1, 1, G, bq, d), query), ring_spec,
+                      ring_spec,
                       pl.BlockSpec((1, 1, bo, d), own),
                       pl.BlockSpec((1, 1, bo, d), own),
                       pl.BlockSpec((1, 1, bo), own_keep)],
@@ -519,7 +534,6 @@ def _gqa_prefill(qg, k, v, k_own, v_own, keep, rows, start, *, window, bq,
             * itemsize),
         name="gqa_prefill",
         interpret=interpret,
-    )(rows.astype(jnp.int32), start.astype(jnp.int32), *plan,
-      qg.astype(dtype).reshape(b, Hk, G, T, d), k, v, k_own.astype(dtype),
-      v_own.astype(dtype), keep.reshape(b, 1, T))
+    )(*scalars, qg.astype(dtype).reshape(b, Hk, G, T, d), k, v,
+      k_own.astype(dtype), v_own.astype(dtype), keep.reshape(b, 1, T))
     return out.reshape(b, Hk, GT, d)

@@ -142,6 +142,9 @@ class PipelinePlan:
                 "pipeline parallelism supports single-input single-output "
                 f"graphs; got {len(conf.network_inputs)} inputs / "
                 f"{len(conf.network_outputs)} outputs")
+        if conf.loop is not None:
+            raise ValueError("pipeline parallelism cuts a graph into stages "
+                             "once; a looped graph runs its span again")
         self.net = net
         self.S = n_stages
         self.input_name = conf.network_inputs[0]

@@ -34,7 +34,9 @@ allocation.
   "slot": it does not grow with the capacity and is billed to the slot,
   never to its positions), a RING of rows for an attention layer with a
   window (an array whose spec carries the positions it holds, fewer
-  than the capacity: it is billed to those). `bytes_per_slot` is the single home for that
+  than the capacity: it is billed to those), and for a layer inside a
+  loop one block of its arrays a pass (a pass axis first: a slot's
+  bytes are passes x rows x layers). `bytes_per_slot` is the single home for that
   arithmetic: it bills exactly the arrays the device holds, and the
   replay artifact's ``slots_per_hbm_byte`` uplift row (gate: >= 1.8x)
   is computed from it, not re-derived ad hoc.
@@ -241,7 +243,9 @@ class CachePlan:
         """The geometry; with `net`, also what its layers keep in it:
         `rows` ({kind of row: bytes a token over all layers}) and
         `bytes_per_token`, their sum: what a token costs while every
-        layer still holds it; `windows` ({kind of row: rows a slot}) for
+        layer still holds it, every pass of a looped net's layers
+        counted (`passes`: how many times a token runs the net's loop, 1
+        without one; a layer inside it keeps a row a pass); `windows` ({kind of row: rows a slot}) for
         the kinds that are rings and hold fewer positions than the
         capacity; `states` ({kind of state: bytes a slot over all
         layers}) and `state_bytes_per_slot`, their sum; `bytes_per_slot`,
@@ -263,4 +267,12 @@ class CachePlan:
             out["states"] = states
             out["state_bytes_per_slot"] = sum(states.values())
             out["bytes_per_slot"] = bytes_per_slot(specs)
+            out["passes"] = passes_of(net)
         return out
+
+
+def passes_of(net) -> int:
+    """How many times a token runs the net's loop (a graph's `LoopConf`),
+    1 for a net without one."""
+    loop = getattr(net.conf, "loop", None)
+    return 1 if loop is None else int(loop.times)

@@ -256,7 +256,9 @@ SPAN_NAMES = frozenset({
 
 # The regions inside a compiled program: `jax.named_scope(<region>)` around
 # each layer's ops (nn/decode._walk, nn/graph._forward: the layer impl's
-# `region`), the engine's argmax and the train step's loss and update. A
+# `region`), the engine's argmax, the train step's loss and update, and a
+# loop's own operations (its pass counter and carry: `loop`; the layers
+# inside its body keep their own regions, the innermost naming an op). A
 # region is HLO metadata (`op_name`) and nothing else: the compiled
 # instructions are the same with and without it. {top-level region: its
 # child regions}; `cost`'s sibling event `regions` maps each instruction
@@ -266,7 +268,7 @@ SPAN_NAMES = frozenset({
 REGIONS = {
     "embed": (), "norm": (), "attention": ("cache_write",),
     "moe": ("router", "experts", "shared_expert"), "ffn": (), "head": (),
-    "loss": (), "optimizer": (),
+    "loss": (), "optimizer": (), "loop": (),
 }
 REGION_NAMES = frozenset(REGIONS) | frozenset(
     child for children in REGIONS.values() for child in children)

@@ -385,6 +385,114 @@ def test_grouped_kernels_take_qwen3_nexts_full_layer(
                              text)
 
 
+def test_grouped_kernels_read_a_pass_of_ouros_entry_where_it_lies(
+        no_persistent_cache, one_chip, monkeypatch):
+    """The two grouped kernels at the looped model's widths, which no
+    other cell gives them: 16 queries on 16 key-value heads of 128 (one
+    query a head), no per-head norm and no gate, a layer's entry of 4
+    passes of 1,280 rows over 4 slots (bfloat16, 168 MB a layer). The
+    decode step and the 256-token chunk of a layer inside the loop, told
+    the pass as a traced scalar, take `gqa_decode` and `gqa_prefill`;
+    both compile for the chip and read and write the pass's rows in the
+    whole entry, with no copy of it or of a pass's rows."""
+    from deeplearning4j_tpu.nn.conf.layers import GroupedAttentionLayer
+    from deeplearning4j_tpu.nn.decode import CacheStep
+    from deeplearning4j_tpu.nn.layers.grouped_attention import (
+        GroupedAttentionImpl,
+    )
+    from deeplearning4j_tpu.ops import decode_attention as da
+    from deeplearning4j_tpu.ops import prefill_attention as pa
+
+    h, H, d, Tc, slots, cap, P = 2048, 16, 128, 256, 4, 1280, 4
+    conf = GroupedAttentionLayer(n_in=h, n_out=h, n_heads=H, n_kv_heads=H,
+                                 head_dim=d, rope_theta=1e6, eps=1e-6,
+                                 qk_norm=False, gate=False)
+    impl = GroupedAttentionImpl()
+    bf16 = jnp.bfloat16
+    params = {k: _sds(s, bf16, one_chip) for k, s in (
+        ("Wq", (h, H * d)), ("Wk", (h, H * d)), ("Wv", (h, H * d)),
+        ("Wo", (H * d, h)))}
+    cache = {n: _sds((slots, P) + a[0], bf16, one_chip)
+             for n, a in impl.cache_arrays(conf, cap, "f32", PAGE,
+                                           bf16).items()}
+    monkeypatch.setattr(pa, "use_kernel", lambda: True)
+    monkeypatch.setattr(da, "_use_kernel", lambda: True)
+
+    def chunk(params, x, cache, row, start, keep, p):
+        step = CacheStep(row, start[:, None] + jnp.arange(Tc)[None, :],
+                         keep=keep, chunk=True).in_pass(p)
+        return impl.apply_cached(conf, params, x, cache, step)
+
+    def decode(params, x, cache, pos, live, p):
+        step = CacheStep(None, pos[:, None], live=live).in_pass(p)
+        return impl.apply_cached(conf, params, x, cache, step)
+
+    one, p = _sds((1,), jnp.int32, one_chip), _sds((), jnp.int32, one_chip)
+    for fn, args, kernel in (
+            (chunk, (params, _sds((1, Tc, h), bf16, one_chip), cache, one,
+                     one, _sds((1, Tc), jnp.float32, one_chip), p),
+             "gqa_prefill"),
+            (decode, (params, _sds((slots, 1, h), bf16, one_chip), cache,
+                      _sds((slots,), jnp.int32, one_chip),
+                      _sds((slots,), jnp.bool_, one_chip), p), "gqa_decode")):
+        compiled = jax.jit(fn, donate_argnums=2).lower(*args).compile()
+        text, mem = compiled.as_text(), compiled.memory_analysis()
+        assert _kernels(text) == {kernel: 1}
+        assert mem.alias_size_in_bytes == 2 * slots * P * H * cap * d * 2
+        assert mem.temp_size_in_bytes < 16 * 2**20, mem
+        for shape in ((slots, P, H, cap, d), (slots, H, cap, d)):
+            dims = ",".join(map(str, shape))
+            assert not re.search(rf"= bf16\[{dims}\]\S* copy\(", text)
+
+
+def test_looped_decode_step_traces_its_body_once_and_holds_one_cache(
+        no_persistent_cache, one_chip, monkeypatch):
+    """A looped net's decode step at the looped model's widths (hidden
+    2048, 16 heads of 128, a feed-forward of 5632, a vocabulary of
+    49,152), two of its blocks run 4 times a token, over 4 slots of
+    1,280 positions: the walk's loop over the pass holds ONE copy of the
+    body, so the program calls `gqa_decode` once a block (2), not once a
+    (block, pass) (8), and it donates the whole cache, every pass's rows
+    of every block, and copies none of it."""
+    from deeplearning4j_tpu.models.looped import looped_lm
+    from deeplearning4j_tpu.ops import decode_attention as da
+
+    monkeypatch.setattr(da, "_use_kernel", lambda: True)
+    slots, cap, P = 4, 1280, 4
+    held = {}
+
+    def build():
+        held["net"] = looped_lm(49152, 2048, 16, 2, P, d_ff=5632,
+                                head_dim=128, rope_theta=1e6,
+                                dtype="bfloat16", param_dtype="bfloat16"
+                                ).init()
+        return held["net"].params
+
+    params = jax.eval_shape(build)
+    net = held["net"]
+    net.params = None
+    cache = jax.eval_shape(lambda: net.init_kv_cache(slots, cap))
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert cache_bytes == slots * P * 2 * 2 * 16 * cap * 128 * 2
+    step_raw = net.incremental_decode_fn()
+
+    def step(params, state, cache, tok, pos, live):
+        probs, cache, counts = step_raw(params, state, cache, tok, pos, live)
+        return jnp.argmax(probs, -1), cache, counts
+
+    on = lambda t: jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), t)
+    compiled = jax.jit(step, donate_argnums=2).lower(
+        on(params), {n: {} for n in params}, on(cache),
+        _sds((slots,), jnp.int32, one_chip), _sds((slots,), jnp.int32,
+                                                  one_chip),
+        _sds((slots,), jnp.bool_, one_chip)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == {"gqa_decode": 2}
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert not re.search(rf"= bf16\[{slots},{P},16,{cap},128\]\S* copy\(",
+                         text)
+
+
 def test_prefill_flash_kernel_keeps_the_scores_off_the_memory(
         no_persistent_cache, one_chip, monkeypatch):
     """Latent attention's cached prefill chunk at openPangu's widths (128

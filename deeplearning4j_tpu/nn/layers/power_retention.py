@@ -25,7 +25,10 @@ sums are a state, `s` [Hk, d, D] and `z` [Hk, D] with D = d (d / 2 + 1):
     y_t = S_t phi2(q_t) / (z_t . phi2(q_t) + sum_eps)
 
 the same y_t, term by term. `apply` (a whole sequence) and a prefill
-chunk use the two together a sub-chunk at a time (`retention_chunk`); a
+chunk use the two together a sub-chunk at a time: `apply` through
+`retention_chunk` (plain `jnp`, differentiable), a chunk through the
+cache's rows (`retention_chunk_rows`: on a TPU the state's terms are a
+Pallas kernel that advances the rows' states in place, no gradient); a
 decode step is the recurrence, one pass over the state
 (`retention_decode`, a Pallas kernel on a TPU).
 
@@ -66,6 +69,7 @@ from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import get_activation
 from deeplearning4j_tpu.ops.power_retention import (
     retention_chunk,
+    retention_chunk_rows,
     retention_decode,
     state_dim,
 )
@@ -180,15 +184,10 @@ class PowerRetentionImpl(LayerImpl):
         if step.chunk:
             rows = (jnp.arange(x.shape[0]) if step.rows is None
                     else step.rows)
-            fresh = first[:, None, None]
-            y, s, z = retention_chunk(
-                q, k, v, log_g,
-                jnp.where(fresh[..., None], 0, entry["s"][rows]),
-                jnp.where(fresh, 0, entry["z"][rows]),
+            y, s, z = retention_chunk_rows(
+                q, k, v, log_g, entry["s"], entry["z"], rows, first,
                 keep=step.keep, eps=conf.sum_eps)
-            with jax.named_scope("cache_write"):
-                entry = {"s": entry["s"].at[rows].set(s),
-                         "z": entry["z"].at[rows].set(z)}
+            entry = {"s": s, "z": z}
         elif x.shape[1] != 1:
             raise ValueError(
                 "PowerRetentionLayer decodes one token a row a step: a "

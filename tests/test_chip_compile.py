@@ -19,6 +19,7 @@ worker imports this file. Nothing runs; a compile that passes is not a
 chip run.
 """
 
+import math
 import re
 
 import jax
@@ -273,6 +274,57 @@ def test_retention_decode_kernel_writes_the_state_in_place(
     assert mem.alias_size_in_bytes == 4 * B * Hk * (d + 1) * D == 549_519_360
     assert mem.temp_size_in_bytes < 2**20, mem
     assert not re.search(rf"= f32\[{B},{Hk},{d},{D}\]\S* copy\(", text)
+
+
+def test_retention_chunk_kernel_advances_the_state_in_place(
+        no_persistent_cache, one_chip, monkeypatch):
+    """A prefill chunk of 1,024 tokens through one power-retention layer
+    at Brumby-14B's widths (hidden 5120, 40 queries on 8 states of 128 x
+    8,320 float32, 16 slots), with the chunk kernel as on the chip: one
+    `retention_chunk` kernel, the donated state aliased whole and
+    advanced in place, and no array of phi2's width but the state's own:
+    no phi2 of the queries (40 x 128 x 8,320 bfloat16 values a sub-chunk,
+    85 MB), which the `jnp` form writes, and no gathered or relaid copy
+    of a row's state. The program's temporaries stay under 64 MiB (the
+    `jnp` form's: 188 MB)."""
+    from deeplearning4j_tpu.nn.conf.layers import PowerRetentionLayer
+    from deeplearning4j_tpu.nn.decode import CacheStep
+    from deeplearning4j_tpu.nn.layers.power_retention import PowerRetentionImpl
+    from deeplearning4j_tpu.ops import power_retention as pr
+
+    monkeypatch.setattr(pr, "_use_kernel", lambda: True)
+    S, T, n = 16, 1024, 5120
+    conf = PowerRetentionLayer(n_in=n, n_out=n, n_heads=40, n_kv_heads=8,
+                               head_dim=128, rope_theta=1e6,
+                               activation="identity", weight_init="lecun")
+    impl = PowerRetentionImpl()
+    params = jax.eval_shape(
+        lambda: impl.init(conf, jax.random.PRNGKey(0), jnp.bfloat16)[0])
+    entry = {name: jax.ShapeDtypeStruct((S,) + a[0], a[1]) for name, a
+             in impl.cache_arrays(conf, T, "f32", 16, jnp.bfloat16).items()}
+
+    def chunk(params, x, entry, row, start, keep):
+        step = CacheStep(row, start[:, None] + jnp.arange(T)[None],
+                         keep=keep, chunk=True)
+        y, entry, _ = impl.apply_cached(conf, params, x, entry, step)
+        return y, entry
+
+    def on(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    i32 = _sds((1,), jnp.int32, one_chip)
+    compiled = jax.jit(chunk, donate_argnums=2).lower(
+        on(params), _sds((1, T, n), jnp.bfloat16, one_chip), on(entry), i32,
+        i32, _sds((1, T), jnp.float32, one_chip)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == {"retention_chunk": 1}
+    assert mem.alias_size_in_bytes == 4 * S * 8 * 129 * 8320 == 549_519_360
+    # every array 8,320 wide is the state or holds no more than z
+    for m in re.finditer(r"\[([\d,]*,8320)\]", text):
+        values = math.prod(int(x) for x in m.group(1).split(","))
+        assert values == S * 8 * 128 * 8320 or values <= S * 8 * 8320, m
+    assert not re.search(r"= f32\[16,8,128,8320\]\S* copy\(", text)
+    assert mem.temp_size_in_bytes < 64 * 2**20, mem
 
 
 def test_gated_delta_decode_kernel_writes_the_state_in_place(

@@ -7,6 +7,7 @@ block, three layers. Weights are seeded here, in the reference's layout,
 and laid into the program's tree by name. The reference computes the
 ATTENTION form (every weight of every earlier key); the program the
 chunked form and, a token at a time, the recurrence over a fixed state."""
+import functools
 import importlib.util
 import json
 import os
@@ -179,6 +180,28 @@ def test_prefill_in_two_padded_chunks_then_decode_matches_the_reference(
     assert max(np.abs(got[t] - want[t]).max() for t in got) < TOL
 
 
+def test_prefill_through_the_chunk_kernel_then_decode_matches_the_reference(
+        W, tokens, monkeypatch):
+    """The same two padded chunks and 11 decode steps with both kernels
+    forced in interpret mode, as a TPU takes them (a head of 16 passes
+    `chunk_kernel_fits` only here): every layer of both chunk programs
+    takes the chunk kernel, and the log-probabilities are the
+    reference's."""
+    calls = []
+    chunk = functools.partial(op.retention_chunk_kernel, interpret=True)
+    monkeypatch.setattr(op, "_use_kernel", lambda: True)
+    monkeypatch.setattr(op, "chunk_kernel_fits", lambda T, d: True)
+    monkeypatch.setattr(op, "retention_chunk_kernel",
+                        lambda *a, **k: calls.append(1) or chunk(*a, **k))
+    monkeypatch.setattr(op, "retention_decode_kernel", functools.partial(
+        op.retention_decode_kernel, interpret=True))
+    got, _ = _through_the_state(tiny_net(W), tokens)
+    assert len(calls) == 2 * DIMS["L"]      # a trace of each chunk bucket
+    want = log_probs_ref(W, tokens)
+    assert sorted(got) == [12, 28] + list(range(29, 40))
+    assert max(np.abs(got[t] - want[t]).max() for t in got) < TOL
+
+
 def test_a_bfloat16_state_where_float32_is_stated_fails(W, tokens):
     """Weights, activations and products in float32, the state alone in
     bfloat16: every step rounds the running sums to eight bits."""
@@ -263,6 +286,94 @@ def test_kernel_in_interpret_mode_equals_its_jnp_twin(state_dtype):
         assert np.abs(a - b).max() <= tol
         assert np.array_equal(b[1], old[1])     # the idle row, bit for bit
         assert np.abs(b[2]).max() < 20 and not np.array_equal(b[2], old[2])
+
+
+def _chunk_case(seed, T, n_real, fresh, Hq, Hk, d, gate, dtype=jnp.float32,
+                slots=4):
+    """Inputs of a chunk through cache rows 2, 1, ... of `slots`: every
+    slot's state a lived-in one (48 earlier tokens), log g uniform in
+    `gate`, `n_real` kept tokens a row (None: all)."""
+    rng = np.random.default_rng(seed)
+    b, Dd = len(fresh), op.state_dim(d)
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    _, s, z = op.retention_chunk(
+        normal(slots, 48, Hq, d), normal(slots, 48, Hk, d),
+        normal(slots, 48, Hk, d),
+        jnp.asarray(rng.uniform(*gate, (slots, 48, Hk)), jnp.float32),
+        jnp.zeros((slots, Hk, d, Dd)), jnp.zeros((slots, Hk, Dd)))
+    q, k, v = (normal(b, T, H, d).astype(dtype) for H in (Hq, Hk, Hk))
+    log_g = jnp.asarray(rng.uniform(*gate, (b, T, Hk)), jnp.float32)
+    keep = None if n_real is None else jnp.asarray(
+        np.arange(T)[None] < np.asarray(n_real)[:, None], jnp.float32)
+    rows = jnp.asarray(np.arange(b)[::-1] + 1, jnp.int32)
+    return q, k, v, log_g, s, z, rows, jnp.asarray(fresh), keep
+
+
+def _twin(q, k, v, log_g, s, z, rows, fresh, keep):
+    """`retention_chunk` on the rows as the layer hands them over."""
+    f = fresh[:, None, None]
+    return op.retention_chunk(q, k, v, log_g,
+                              jnp.where(f[..., None], 0, s[rows]),
+                              jnp.where(f, 0, z[rows]), keep=keep)
+
+
+@pytest.mark.parametrize("T, n_real, fresh, Hq, Hk, d, gate", [
+    (1024, None, (False,), 10, 2, 16, (-0.01, 0.0)),       # gates near 1
+    (384, [300], (True,), 10, 2, 16, (-0.01, 0.0)),        # a pad tail
+    (130, [130, 77], (False, True), 10, 2, 16, (-6.0, -3.0)),  # near 0
+    (384, [384, 200], (False, False), 2, 2, 16, (-3.0, 0.0)),  # 1 a state
+    (130, [100], (False,), 10, 2, 128, (-0.01, 0.0)),      # a head of 128
+], ids=["1024", "384_pad_fresh", "130_two_rows_gates_near_0",
+        "one_query_a_state", "head_128"])
+def test_chunk_kernel_in_interpret_mode_equals_its_jnp_twin(
+        T, n_real, fresh, Hq, Hk, d, gate):
+    """The chunk kernel through the cache's rows against `retention_chunk`
+    on the rows gathered: y at the kept tokens, and the rows' s and z
+    after the chunk, to float32's rounding; the rows the chunk does not
+    name keep their state bit for bit."""
+    case = _chunk_case(7, T, n_real, fresh, Hq, Hk, d, gate)
+    q, k, v, log_g, s, z, rows, fresh, keep = case
+    y, s_k, z_k = op.retention_chunk_kernel(*case[:-1], keep=keep,
+                                            interpret=True)
+    y_t, s_t, z_t = _twin(*case)
+    kept = np.ones(y.shape[:2], bool) if keep is None else np.asarray(keep) > 0
+    assert y.shape == y_t.shape and y.dtype == y_t.dtype
+    y, y_t = np.asarray(y)[kept], np.asarray(y_t)[kept]
+    assert np.abs(y - y_t).max() <= 2e-5 * max(1.0, np.abs(y_t).max())
+    for got, want in ((s_k, s_t), (z_k, z_t)):
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.abs(got[rows] - want).max() \
+            <= 2e-5 * max(1.0, np.abs(want).max())
+        others = np.setdiff1d(np.arange(got.shape[0]), np.asarray(rows))
+        assert np.array_equal(got[others], np.asarray(
+            case[4] if got.ndim == 4 else case[5])[others])
+
+
+def test_chunk_kernel_in_bfloat16_errs_no_more_than_the_jnp_form():
+    """The served dtype, a head of 128: queries, keys and values in
+    bfloat16 against the same numbers in float32 at the highest
+    precision. The kernel rounds phi2 once, where the `jnp` form rounds
+    each product; its error is no larger, in y and in the state."""
+    case = _chunk_case(11, 256, [256], (False,), 10, 2, 128, (-0.01, 0.0),
+                       dtype=jnp.bfloat16)
+    keep = case[-1]
+    with jax.default_matmul_precision("highest"):
+        truth = _twin(*(a.astype(jnp.float32) for a in case[:3]), *case[3:])
+    kern = op.retention_chunk_kernel(*case[:-1], keep=keep, interpret=True)
+    twin = _twin(*case)
+    rows = np.asarray(case[6])
+
+    def err(got, want, i):
+        got = np.asarray(got, np.float32)
+        return np.abs((got if i == 0 else got[rows])
+                      - np.asarray(want, np.float32)).mean()
+
+    for i, want in enumerate(truth):
+        assert err(kern[i], want, i) <= 1.05 * err(twin[i], want, 0)
+    assert kern[1].dtype == jnp.float32 and kern[2].dtype == jnp.float32
 
 
 def test_a_slots_second_tenant_is_served_as_a_fresh_cache_would(
